@@ -30,13 +30,15 @@ fleet/router processes — importing the package must not pay (or break)
 a jax import nobody asked for.
 """
 
+from pytorch_distributed_training_tpu.utils.lazy import lazy_exports
+
 _LAZY = {
     "GuardSet": "guards",
     "GuardViolation": "guards",
     "RecompileError": "guards",
     "TransferGuardError": "guards",
     "donation_audit": "guards",
-    "guard_mode_from_env": "guards",
+    "guard_mode_from_env": "modes",
     "sharding_audit": "guards",
     "Finding": "lint",
     "LintReport": "lint",
@@ -48,17 +50,4 @@ _LAZY = {
 }
 
 __all__ = sorted(_LAZY)
-
-
-def __getattr__(name: str):
-    target = _LAZY.get(name)
-    if name not in _LAZY:
-        raise AttributeError(
-            f"module {__name__!r} has no attribute {name!r}"
-        )
-    import importlib
-
-    if target is None:
-        return importlib.import_module(f"{__name__}.{name}")
-    module = importlib.import_module(f"{__name__}.{target}")
-    return getattr(module, name)
+__getattr__ = lazy_exports(__name__, _LAZY)

@@ -4,10 +4,10 @@ The static linter (``analysis/lint.py``) catches what's visible in
 source; this module catches what only shows up live:
 
 - **Recompile detector** — ``GuardSet.wrap_jit(name, fn)`` wraps a jitted
-  callable; after its warm-up compile, any further trace (jit cache
-  growth) is a violation: a ``recompile`` telemetry record + counter, and
+  callable; after its warm-up compile, any call that builds a new
+  program is a violation: a ``recompile`` telemetry record + counter, and
   a ``RecompileError`` in strict mode. AOT-``Compiled`` objects cannot
-  retrace and pass through trivially (but still get transfer arming).
+  rebuild and pass through trivially (but still get transfer arming).
 - **Implicit-transfer detector** — warm guarded calls run under
   ``jax.transfer_guard``: ``"disallow"`` in strict mode (the classic bug
   — an un-placed host array fed to a warm step forces a per-call H2D
@@ -41,7 +41,6 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
-import os
 import re
 import threading
 from typing import Any, Optional
@@ -52,32 +51,38 @@ from pytorch_distributed_training_tpu.analysis.concurrency.locks import (
     get_lock_registry,
     held_lock_names,
 )
+from pytorch_distributed_training_tpu.analysis.modes import (
+    MODES as _MODES,
+    guard_mode_from_env,
+)
 
-_MODES = ("off", "record", "strict")
-
-# ------------------------------------------------------- trace accounting
+# ------------------------------------------------------- build accounting
 #
-# Retrace detection rides jax.monitoring: every jaxpr trace fires a
-# '/jax/core/compile/jaxpr_trace_duration' event IN THE TRACING THREAD,
-# and a warm executable fires none. A thread-local counter scoped around
-# each guarded call is therefore an exact "did THIS call trace anything"
-# probe — immune to the C++ fast-path cache adding entries without
-# retracing (observed on this jax: cache_size can grow on a warm step),
-# to other threads compiling concurrently (prefetch placement, a second
-# engine), and to persistent-cache hits that skip the backend compile.
+# Recompile detection rides jax.monitoring: every jaxpr -> MLIR lowering
+# fires a '/jax/core/compile/jaxpr_to_mlir_module_duration' event IN THE
+# CALLING THREAD, once per program jit actually builds, and a warm
+# executable fires none. A thread-local counter scoped around each guarded
+# call is therefore an exact "did THIS call build a new program" probe —
+# immune to other threads compiling concurrently (prefetch placement, a
+# second engine) and to persistent-cache hits that skip the backend
+# compile. The jaxpr-trace event is NOT that probe: jit emits it on every
+# dispatch that misses the C++ fast path, tracing-cache hit or not, and a
+# fast-path miss is legal on a warm program (a typed PRNG key fed back
+# through a jit with explicit shardings misses it once, on the third
+# call, and builds nothing).
 
-_TRACE_EVENT = "/jax/core/compile/jaxpr_trace_duration"
+_BUILD_EVENT = "/jax/core/compile/jaxpr_to_mlir_module_duration"
 _tls = threading.local()
 _listener_lock = threading.Lock()
 _listener_installed = False
 
 
 def _on_duration(name: str, *args, **kwargs) -> None:
-    if name == _TRACE_EVENT:
-        _tls.traces = getattr(_tls, "traces", 0) + 1
+    if name == _BUILD_EVENT:
+        _tls.builds = getattr(_tls, "builds", 0) + 1
 
 
-def _ensure_trace_listener() -> None:
+def _ensure_build_listener() -> None:
     global _listener_installed
     with _listener_lock:
         if not _listener_installed:
@@ -85,8 +90,8 @@ def _ensure_trace_listener() -> None:
             _listener_installed = True
 
 
-def _trace_count() -> int:
-    return getattr(_tls, "traces", 0)
+def _build_count() -> int:
+    return getattr(_tls, "builds", 0)
 
 
 class GuardViolation(RuntimeError):
@@ -94,20 +99,11 @@ class GuardViolation(RuntimeError):
 
 
 class RecompileError(GuardViolation):
-    """A jitted entry point retraced after warm-up."""
+    """A jitted entry point built a new program after warm-up."""
 
 
 class TransferGuardError(GuardViolation):
     """An implicit host<->device transfer happened in a guarded region."""
-
-
-def guard_mode_from_env(default: str = "record") -> str:
-    mode = os.environ.get("PDT_TPU_GUARDS", default)
-    if mode not in _MODES:
-        raise ValueError(
-            f"PDT_TPU_GUARDS must be one of {_MODES}, got {mode!r}"
-        )
-    return mode
 
 
 def _registry_or_default(registry):
@@ -122,7 +118,7 @@ def _registry_or_default(registry):
 
 class GuardedCall:
     """Wrapper installed by ``GuardSet.wrap_jit`` around one jitted entry
-    point. Transparent to the call contract; adds per-call retrace
+    point. Transparent to the call contract; adds per-call recompile
     accounting, transfer-guard arming once warm, a lock-across-device
     check (dispatching compiled work while holding an instrumented lock
     serializes every thread needing it behind the accelerator), and —
@@ -211,7 +207,7 @@ class GuardedCall:
         self.calls += 1
         warm = self._warm
         ctx = g._transfer_context() if warm else contextlib.nullcontext()
-        traces_before = _trace_count()
+        builds_before = _build_count()
         try:
             with ctx:
                 out = self.fn(*args, **kwargs)
@@ -219,16 +215,16 @@ class GuardedCall:
             if "Disallowed" in str(e) and "transfer" in str(e):
                 g._transfer_violation(self.name, e)
             raise
-        traced = _trace_count() - traces_before
+        built = _build_count() - builds_before
         if not warm:
             self._warm = True  # the one expected warm-up compile
             if self._audit_donation:
                 self._donation_audit_from(args, kwargs)
             if self._comm_manifest is not None:
                 self._comm_audit_from(args, kwargs)
-        elif traced:
+        elif built:
             self.recompiles += 1
-            g._recompile_violation(self, traced)
+            g._recompile_violation(self, built)
         return out
 
     def __getattr__(self, item):  # .lower/.trace/... pass through
@@ -253,7 +249,7 @@ class GuardSet:
         self.recompile_violations = 0
         self.transfer_violations = 0
         if self.mode != "off":
-            _ensure_trace_listener()
+            _ensure_build_listener()
 
     # ------------------------------------------------------------- wrapping
 
@@ -328,20 +324,20 @@ class GuardSet:
 
     # ------------------------------------------------------------ recompiles
 
-    def _recompile_violation(self, call: GuardedCall, traced: int) -> None:
+    def _recompile_violation(self, call: GuardedCall, built: int) -> None:
         self.recompile_violations += 1
         self.registry.inc("guards/recompiles")
         self.registry.emit({
             "record": "recompile",
             "name": call.name,
             "calls": call.calls,
-            "traces": traced,
+            "builds": built,
             "recompiles": call.recompiles,
         })
         if self.mode == "strict":
             raise RecompileError(
-                f"jitted entry point {call.name!r} retraced after warm-up "
-                f"(call {call.calls} traced {traced} jaxpr(s)) — a shape/"
+                f"jitted entry point {call.name!r} recompiled after warm-up "
+                f"(call {call.calls} built {built} new program(s)) — a shape/"
                 f"dtype/static-arg is varying per call"
             )
 

@@ -48,13 +48,25 @@ _DTYPES_ALT = "|".join(sorted(_DTYPE_BYTES, key=len, reverse=True))
 
 # `%name = <shape> <kind>(` — the shape is a single `f32[8,2]{1,0}` token
 # or a tuple `(f32[...], f32[...])` for async starts / multi-operand ops.
+# XLA:TPU writes tiled layouts with parentheses of their own
+# (`bf16[16,64]{1,0:T(8,128)(2,1)}`), also inside the tuples of its
+# combined all-reduces, so the tuple form runs to the `)` that the kind
+# follows, not to the first one.
 # `-done`/`-update` halves of async pairs never match (no `(` after kind).
 _INSTR_RE = re.compile(
     r"^\s*(?:ROOT\s+)?%?(?P<name>[\w.-]+)\s*=\s*"
-    r"(?P<shape>\([^)]*\)|\S+)\s+"
+    r"(?P<shape>\(.*?\)|\S+)\s+"
     r"(?P<kind>" + "|".join(COLLECTIVE_KINDS) + r")"
     r"(?P<suffix>-start)?\("
 )
+
+# `%all-reduce-scatter.6.clone (input: bf16[4096,1024]) -> bf16[1032,1024] {`
+# XLA:TPU has no reduce-scatter opcode in its final HLO: it emits a fused
+# computation named all-reduce-scatter* (an all-reduce and a dynamic-slice
+# at the partition's offset) that its AllReduceScatterFusion emitter runs as
+# ONE ring reduce-scatter — the full-size sum never exists in HBM.
+_COMPUTATION_RE = re.compile(r"^(?:ENTRY\s+)?%?(?P<name>[\w.-]+)\s+\(.*\{\s*$")
+_TPU_REDUCE_SCATTER_PREFIX = "all-reduce-scatter"
 
 _SHAPE_TOKEN_RE = re.compile(r"(" + _DTYPES_ALT + r")\[([0-9,]*)\]")
 
@@ -107,9 +119,13 @@ def extract_collectives(
     unresolvable group sizes stay 0 and cost as group-of-1 (zero moved).
     """
     out = []
+    computation = ""
     for lineno, line in enumerate(hlo_text.splitlines(), start=1):
         m = _INSTR_RE.match(line)
         if m is None:
+            header = _COMPUTATION_RE.match(line)
+            if header is not None:
+                computation = header.group("name")
             continue
         tokens = _shape_tokens(m.group("shape"))
         if not tokens:
@@ -123,12 +139,21 @@ def extract_collectives(
         else:
             dtype = tokens[0][0]
             nbytes = sum(e * _DTYPE_BYTES[dt] for dt, e in tokens)
+        kind = m.group("kind")
+        group_size = _group_size(line, world_size)
+        if kind == "all-reduce" and computation.startswith(
+            _TPU_REDUCE_SCATTER_PREFIX
+        ):
+            # the fused form above: report what runs, with the scattered
+            # shard as the payload (Collective.bytes is the RESULT buffer)
+            kind = "reduce-scatter"
+            nbytes //= max(group_size, 1)
         out.append(Collective(
             name=m.group("name"),
-            kind=m.group("kind"),
+            kind=kind,
             dtype=dtype,
             bytes=int(nbytes),
-            group_size=_group_size(line, world_size),
+            group_size=group_size,
             line=lineno,
             asynchronous=asynchronous,
         ))

@@ -109,7 +109,12 @@ def train_manifest(mesh, *, max_bytes: Optional[int] = None,
     """The kinds a train step may emit on this mesh. 1-device meshes pin
     zero collectives; a data axis earns gradient all-reduce; fsdp/model
     axes earn param all-gather + grad reduce-scatter (and all-to-all for
-    tensor-parallel layouts); a stage axis earns pipeline permutes.
+    tensor-parallel layouts); a stage axis earns pipeline permutes. An
+    fsdp axis earns permutes too: XLA:TPU's fused reduce-scatter pads each
+    shard to its ring granularity (1024 rows become 1032) and shifts the
+    few surplus rows to the neighbour afterwards — 25 permutes of 48 KiB
+    beside 167 MB of reduce-scatter in the bert-large fsdp=4 step compiled
+    for a v5e 2x2. XLA:CPU emits none.
 
     ``fsdp_sharded=True`` (the mesh has an fsdp axis AND the sharding
     policy actually shards params over it) additionally REQUIRES an
@@ -127,7 +132,7 @@ def train_manifest(mesh, *, max_bytes: Optional[int] = None,
             required += ["all-gather"]
     if shape.get("model", 1) > 1:
         allowed += ["all-to-all"]
-    if shape.get("stage", 1) > 1:
+    if shape.get("stage", 1) > 1 or shape.get("fsdp", 1) > 1:
         allowed += ["collective-permute"]
     return CommManifest(
         name, allowed=tuple(allowed), required=tuple(required),

@@ -170,7 +170,7 @@ def main(argv=None) -> dict:
     from pytorch_distributed_training_tpu.analysis.concurrency import (
         get_lock_registry,
     )
-    from pytorch_distributed_training_tpu.analysis.guards import (
+    from pytorch_distributed_training_tpu.analysis.modes import (
         guard_mode_from_env,
     )
 
@@ -180,6 +180,15 @@ def main(argv=None) -> dict:
     # the whole fleet agrees
     guard_mode = args.guards or guard_mode_from_env(default="strict")
     get_lock_registry().mode = guard_mode
+
+    # before anything starts: the autoscaler's ceiling must fit the host's
+    # chips too (the fleet re-checks the pool it is actually given), and a
+    # --tp replica cannot be given chips of its own
+    from pytorch_distributed_training_tpu.utils.chips import require_chips
+
+    require_chips(
+        "fleet_lm", max(args.replicas, args.max_replicas), args.tp
+    )
 
     registry = get_registry()
     sink = None

@@ -28,8 +28,10 @@ from __future__ import annotations
 
 import argparse
 
-import jax
 import numpy as np
+
+# jax is imported where it is used: fleet_lm shares add_model_args and must
+# stay jax-free (a chip belongs to one process — its replicas)
 
 
 def build_parser() -> argparse.ArgumentParser:
@@ -87,6 +89,8 @@ def load_model_and_params(args, tok):
     inference process never boots on a torn publish; a manifest-less
     legacy directory falls back to the raw latest step.
     """
+    import jax
+
     from pytorch_distributed_training_tpu.models.gpt2 import GPT2LMModel
     from pytorch_distributed_training_tpu.utils.config import model_preset
     from pytorch_distributed_training_tpu.utils.logging import log0
@@ -157,8 +161,14 @@ def main(argv=None):
     ``--prompt-file``."""
     args = build_parser().parse_args(argv)
 
-    from pytorch_distributed_training_tpu.models.generate import generate
+    import jax
 
+    from pytorch_distributed_training_tpu.models.generate import generate
+    from pytorch_distributed_training_tpu.train.compile import (
+        enable_compile_cache,
+    )
+
+    enable_compile_cache()
     tok = build_tokenizer(args)
     if args.prompt_file:
         with open(args.prompt_file) as f:

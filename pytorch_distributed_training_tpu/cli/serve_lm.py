@@ -225,8 +225,11 @@ def build_parser() -> argparse.ArgumentParser:
 
 def main(argv=None, in_stream=None, out_stream=None) -> dict:
     """Run the server until EOF (stdio mode) or interrupt (HTTP mode);
-    returns the engine's final stats dict (machine-checkable in tests)."""
+    returns the engine's final stats dict (machine-checkable in tests).
+    Raises ``SystemExit`` (exit code 1) when the serve loop died."""
     args = build_parser().parse_args(argv)
+
+    import jax
 
     from pytorch_distributed_training_tpu.cli.generate_lm import (
         build_tokenizer,
@@ -241,8 +244,15 @@ def main(argv=None, in_stream=None, out_stream=None) -> dict:
     from pytorch_distributed_training_tpu.telemetry.registry import (
         get_registry,
     )
+    from pytorch_distributed_training_tpu.train.compile import (
+        enable_compile_cache,
+    )
     from pytorch_distributed_training_tpu.utils.logging import log0
 
+    # first: the random init / checkpoint restore below already compiles
+    log0(f"compile cache: {enable_compile_cache()}")
+    # which chips this process holds (a fleet replica: the one it was given)
+    log0(f"devices: {[(d.platform, d.device_kind, d.id) for d in jax.devices()]}")
     tok = build_tokenizer(args)
     model, params, boot_step = load_model_and_params(args, tok)
 
@@ -379,6 +389,10 @@ def main(argv=None, in_stream=None, out_stream=None) -> dict:
         slo=slo,
         replica_name=args.replica_name,
     ).start()
+    # the engine placed its own copy; under --tp the tree that was
+    # initialized or restored whole on device 0 would otherwise stay
+    # resident there for the life of the process
+    del params, draft_params
 
     lock_summary = None
     if args.lock_summary_s > 0:
@@ -504,6 +518,9 @@ def main(argv=None, in_stream=None, out_stream=None) -> dict:
                 out_stream if out_stream is not None else sys.stdout,
             )
             log0(f"stdio stream closed after {served} requests")
+        from pytorch_distributed_training_tpu.ops import dispatch
+
+        log0(dispatch.summary())
     finally:
         if lock_summary is not None:
             lock_summary.stop()
@@ -519,6 +536,11 @@ def main(argv=None, in_stream=None, out_stream=None) -> dict:
 
             sink.emit(get_lock_registry().summary_record())
             sink.flush(fsync=True)
+    if server.loop_dead():
+        # every waiter already got its error event; the PROCESS must fail
+        # too, or a stdio client's exit code (and a supervisor's restart
+        # budget) reads a dead server as a clean run
+        raise SystemExit("serve loop died (traceback above); exiting 1")
     if preempted["signal"] is not None:
         # graceful preemption drain: exit 75 (EX_TEMPFAIL) so a fleet
         # supervisor respawns this replica without burning a restart

@@ -87,6 +87,8 @@ def initialize(
                 process_id=process_id,
             )
         _INITIALIZED = True
+        # utils/logging stamps records with this, without touching jax
+        os.environ["JAX_PROCESS_ID"] = str(jax.process_index())
 
     info = runtime_info()
     if info.is_main:

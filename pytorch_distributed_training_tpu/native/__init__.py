@@ -22,6 +22,9 @@ import os
 import subprocess
 import threading
 
+from pytorch_distributed_training_tpu.utils.logging import get_logger
+
+_log = get_logger(__name__)
 _REPO_NATIVE = os.path.join(os.path.dirname(__file__), "..", "..", "native")
 _SRC_DIR = os.path.abspath(os.path.join(_REPO_NATIVE, "src"))
 _BUILD_DIR = os.path.abspath(os.path.join(_REPO_NATIVE, "build"))
@@ -52,7 +55,13 @@ def _compile(name: str) -> str | None:
     ]
     try:
         subprocess.run(cmd, check=True, capture_output=True, timeout=120)
-    except (OSError, subprocess.SubprocessError):
+    except (OSError, subprocess.SubprocessError) as e:
+        # callers fall back to the Python paths; the log says why
+        stderr = getattr(e, "stderr", b"") or b""
+        _log.warning(
+            "native %s build failed (%r); using the Python path. %s",
+            name, e, stderr.decode(errors="replace")[-400:],
+        )
         return None
     return lib
 

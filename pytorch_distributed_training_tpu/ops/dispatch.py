@@ -46,11 +46,28 @@ from jax.sharding import Mesh
 
 _CTX = threading.local()
 
-# trace-time counters keyed by op name ("layer_norm", "dal", "mask_scale",
-# "flash") — tests assert the shard_map kernel path was actually taken
-# (the compiled HLO hides the kernel under interpret mode, so a counter at
-# trace time is the observable).
-KERNEL_DISPATCH_COUNTS: Counter = Counter()
+# every dispatch decision, counted at trace time and keyed "op:path" — op is
+# "layer_norm", "dal", "mask_scale" or "flash"; path is "direct" or
+# "shard_map" (the Pallas kernel) or "xla" (the reference math: off-TPU, an
+# impl that is not the fused one, a shape that does not tile, a mesh that
+# does not divide). The fallbacks are correct and silent by design, and the
+# compiled HLO hides the kernel under interpret mode, so this counter is
+# the observable: Trainer / serve_lm log it, chip_smoke.py and the tests
+# assert on it (``DISPATCH_PATHS["dal:shard_map"]`` etc.).
+DISPATCH_PATHS: Counter = Counter()
+
+
+def note_path(op: str, path: str) -> None:
+    DISPATCH_PATHS[f"{op}:{path}"] += 1
+
+
+def summary() -> str:
+    """One log line: the mode this thread dispatches under and every path
+    traced so far in the process."""
+    return (
+        f"kernel dispatch: mode={mode()!r}, traced paths "
+        f"{dict(DISPATCH_PATHS)}"
+    )
 
 
 def set_kernel_mesh(
@@ -97,32 +114,6 @@ def interpret_active() -> bool:
     )
 
     return getattr(_INTERPRET, "depth", 0) > 0
-
-
-try:  # single home for the shard_map import (new API first)
-    from jax import shard_map as _shard_map_impl  # type: ignore[attr-defined]
-except ImportError:  # pragma: no cover - older jax
-    from jax.experimental.shard_map import shard_map as _shard_map_impl
-
-# The check_rep -> check_vma rename is independent of WHERE shard_map is
-# importable from (jax versions exist with the top-level export and the old
-# kwarg), so gate on the actual signature, not the import location.
-import inspect as _inspect
-
-_CHECK_KW = (
-    "check_vma"
-    if "check_vma" in _inspect.signature(_shard_map_impl).parameters
-    else "check_rep"
-)
-
-
-def shard_map(f, *, mesh, in_specs, out_specs, check_rep=True):
-    """API-normalized shard_map (``check_rep`` name regardless of jax
-    version) — the single import site for every kernel/pipeline wrapper."""
-    return _shard_map_impl(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-        **{_CHECK_KW: check_rep},
-    )
 
 
 @contextlib.contextmanager

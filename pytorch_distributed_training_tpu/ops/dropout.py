@@ -182,7 +182,6 @@ def _mask_scale_sharded(x, rate: float, rng):
     from jax.sharding import PartitionSpec as P  # noqa: F401 (body spec)
 
     from pytorch_distributed_training_tpu.ops import dispatch
-    from pytorch_distributed_training_tpu.ops.dispatch import shard_map
 
     ctx = dispatch.kernel_ctx()
     if ctx is None or x.ndim < 2:
@@ -210,10 +209,10 @@ def _mask_scale_sharded(x, rate: float, rng):
                 seedl, xl.shape, rate, xl.dtype
             )
 
-    dispatch.KERNEL_DISPATCH_COUNTS["mask_scale"] += 1
-    return shard_map(
+    dispatch.note_path("mask_scale", "shard_map")
+    return jax.shard_map(
         body, mesh=mesh, in_specs=(spec, P()), out_specs=spec,
-        check_rep=False,
+        check_vma=False,
     )(x, seed)
 
 
@@ -242,12 +241,14 @@ def raw_dropout(x, rate: float, rng, impl: str = "exact"):
 
         mode = dispatch.mode()
         if mode == "direct":  # single-device TPU or interpret ctx
+            dispatch.note_path("mask_scale", "direct")
             return x * mask_scale_pallas(rng, x.shape, rate, x.dtype)
         if mode == "shard_map":
             out = _mask_scale_sharded(x, rate, rng)
             if out is not None:
                 return out
         # off-TPU / non-divisible shapes: same mask-scale form, jax stream
+        dispatch.note_path("mask_scale", "xla")
         return raw_dropout(x, rate, rng, "bits32")
     if impl == "bits8":
         thresh_i = min(max(round(rate * 256), 1), 255)

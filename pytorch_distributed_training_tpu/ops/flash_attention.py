@@ -97,6 +97,20 @@ def _block_seed(bh, qi, kj, num_qb, num_kb):
     return (bh * num_qb + qi) * num_kb + kj
 
 
+def _block_loop(lower, upper, num_blocks, body, init):
+    """``fori_loop`` over blocks, except that a ONE-block loop runs its body
+    at the static index 0. A single block may be any size (the adapter
+    uses one block for every sequence <= 512); only a static offset lets
+    Mosaic take a lane slice that is not a multiple of 128 wide — under a
+    dynamic one it "cannot statically prove that index in dimension 3 is
+    a multiple of 128" (the key-padding bias slice, at gpt2-medium's
+    8-token init forward on the chip). Causal bounds are exact here too:
+    with one block the visible range is always [0, 1)."""
+    if num_blocks == 1:
+        return body(0, init)
+    return jax.lax.fori_loop(lower, upper, body, init)
+
+
 # --------------------------------------------------------------------- fwd
 
 
@@ -160,9 +174,10 @@ def _fwd_kernel(
         if causal
         else num_kb
     )
-    m, l, acc = jax.lax.fori_loop(
+    m, l, acc = _block_loop(
         0,
         upper,
+        num_kb,
         body,
         (
             jnp.full((block_q,), _NEG_INF, jnp.float32),
@@ -245,8 +260,8 @@ def _dq_kernel(
         if causal
         else num_kb
     )
-    dq = jax.lax.fori_loop(
-        0, upper, body, jnp.zeros((block_q, head_dim), jnp.float32)
+    dq = _block_loop(
+        0, upper, num_kb, body, jnp.zeros((block_q, head_dim), jnp.float32)
     )
     dq_ref[0, 0, :, :] = (dq * scale).astype(dq_ref.dtype)
 
@@ -343,8 +358,9 @@ def _dkv_kernel(
 
     # under causality, q-blocks strictly before this k-block see nothing
     start_qb = (kj * block_k) // block_q if causal else 0
-    dk, dv = jax.lax.fori_loop(
+    dk, dv = _block_loop(
         start_qb,
+        num_qb,
         num_qb,
         body,
         (
@@ -425,8 +441,9 @@ def _dqkv_kernel(
         return dk + dk_add, dv + dv_add
 
     start_qb = (kj * block_k) // block_q if causal else 0
-    dk, dv = jax.lax.fori_loop(
+    dk, dv = _block_loop(
         start_qb,
+        num_qb,
         num_qb,
         body,
         (
@@ -917,14 +934,12 @@ def tpu_interpret_mode():
     """Run Pallas TPU kernels in interpret mode off-TPU AND tell the flash
     dispatch guard the kernel path is live.
 
-    This is the framework-owned replacement for jax's global force-interpret
-    context (``pltpu.force_tpu_interpret_mode`` — removed in the jax this
-    image ships): every ``pl.pallas_call`` in ops/ passes
-    ``interpret=_interpreting()``, so entering this context before the
-    kernel's first trace routes it through the Pallas interpreter. Tests
-    (and any CPU-host user who wants the kernel semantics) enter this
-    context; the dispatch gate (``ops.dispatch.mode``) reads the same
-    thread-local and needs no ``jax._src`` imports.
+    Framework-owned rather than ``pltpu.force_tpu_interpret_mode``: the
+    dispatch gate (``ops.dispatch.mode``) must know the kernel path is live
+    and reads this same thread-local. Every ``pl.pallas_call`` in ops/
+    passes ``interpret=_interpreting()``, so entering this context before
+    the kernel's first trace routes it through the Pallas interpreter.
+    Tests (and any CPU-host user who wants the kernel semantics) enter it.
     """
     _INTERPRET.depth = getattr(_INTERPRET, "depth", 0) + 1
     try:
@@ -936,8 +951,7 @@ def tpu_interpret_mode():
 def _interpreting() -> bool:
     """Trace-time value of the ``interpret=`` kwarg for every Pallas call
     in ops/: True inside ``tpu_interpret_mode()`` (the context must wrap
-    the kernel's FIRST trace — jit caches bake the flag in, same scoping
-    contract the removed jax global had)."""
+    the kernel's FIRST trace — jit caches bake the flag in)."""
     return getattr(_INTERPRET, "depth", 0) > 0
 
 
@@ -998,6 +1012,7 @@ def flash_attention(
         or kv_len % block_k
         or head_dim > 256
     ):
+        dispatch.note_path("flash", "xla")
         return reference_attention(
             q, k, v, bias,
             dropout_rng=dropout_rng, dropout_rate=dropout_rate,
@@ -1036,6 +1051,7 @@ def flash_attention(
     if mode == "shard_map":
         plan = _flash_shard_plan(q)
         if plan is None:
+            dispatch.note_path("flash", "xla")
             return reference_attention(
                 q, k, v, bias,
                 dropout_rng=dropout_rng, dropout_rate=dropout_rate,
@@ -1049,17 +1065,16 @@ def flash_attention(
                 sd = sd + dispatch.linear_device_index(axes_used, mesh)
                 return call_base(qh, kh, vh, bf, sd)
 
-        dispatch.KERNEL_DISPATCH_COUNTS["flash"] += 1
+        dispatch.note_path("flash", "shard_map")
         from jax.sharding import PartitionSpec as P
 
-        from pytorch_distributed_training_tpu.ops.dispatch import shard_map
-
-        return shard_map(
+        return jax.shard_map(
             body, mesh=mesh,
             in_specs=(spec, spec, spec, bias_spec, P()),
-            out_specs=spec, check_rep=False,
+            out_specs=spec, check_vma=False,
         )(q, k, v, bias_f, seed)
 
+    dispatch.note_path("flash", "direct")
     return call_base(q, k, v, bias_f, seed)
 
 

@@ -191,10 +191,7 @@ def _fused_vjp_bwd(eps, out_dtype, block_r, res, dy):
 _fused_layer_norm.defvjp(_fused_vjp_fwd, _fused_vjp_bwd)
 
 
-from pytorch_distributed_training_tpu.ops.dispatch import (
-    interpret_active,
-    shard_map as _shard_map,
-)
+from pytorch_distributed_training_tpu.ops.dispatch import interpret_active
 
 
 def _row_shard_plan(x, block_r: int):
@@ -265,18 +262,20 @@ def layer_norm(
                     )
                 return y.reshape(xl.shape[:-1] + (h,))
 
-            dispatch.KERNEL_DISPATCH_COUNTS["layer_norm"] += 1
-            return _shard_map(
+            dispatch.note_path("layer_norm", "shard_map")
+            return jax.shard_map(
                 body, mesh=mesh, in_specs=(spec, P(), P()),
-                out_specs=spec, check_rep=False,
+                out_specs=spec, check_vma=False,
             )(x, scale, bias)
         mode = "off"
     # largest power-of-2 row block <= block_r dividing rows; Mosaic's bf16
     # tile needs >= 16 sublanes, so smaller row counts use the reference
     br = pow2_row_block(rows, block_r)
     if mode != "direct" or br < 16:
+        dispatch.note_path("layer_norm", "xla")
         return reference_layer_norm(x, scale, bias, eps=eps,
                                     out_dtype=out_dtype)
+    dispatch.note_path("layer_norm", "direct")
     x2d = x.reshape(rows, h)
     y = _fused_layer_norm(x2d, scale, bias, eps, jnp.dtype(out_dtype), br)
     return y.reshape(*x.shape[:-1], h)
@@ -491,17 +490,19 @@ def dropout_add_layer_norm(
                     )
                 return y.reshape(xl.shape[:-1] + (hdim,))
 
-            dispatch.KERNEL_DISPATCH_COUNTS["dal"] += 1
-            return _shard_map(
+            dispatch.note_path("dal", "shard_map")
+            return jax.shard_map(
                 body, mesh=mesh, in_specs=(spec, spec, P(), P(), P()),
-                out_specs=spec, check_rep=False,
+                out_specs=spec, check_vma=False,
             )(h, x, scale, bias, seed)
     br = pow2_row_block(rows, block_r)
     if mode != "direct" or br < 16:
+        dispatch.note_path("dal", "xla")
         if rate > 0.0:
             h = raw_dropout(h, rate, dropout_rng, dropout_impl)
         return layer_norm(x + h, scale, bias, eps=eps, out_dtype=out_dtype,
                           block_r=block_r, impl=impl)
+    dispatch.note_path("dal", "direct")
     if rate > 0.0:
         # one int32 seed per call; the kernel folds in the block index.
         seed = derive_kernel_seed(dropout_rng)

@@ -34,7 +34,11 @@ Two implementations behind one signature:
   ``index_map`` streams exactly one page of K/V into VMEM, running
   max/denominator/accumulator rescaled per page, output written on the last
   page. ``interpret=`` falls back to the Pallas interpreter off-TPU (same
-  ``tpu_interpret_mode()`` contract as ops/flash_attention.py).
+  ``tpu_interpret_mode()`` contract as ops/flash_attention.py). The
+  single-query kernel computes in exact fp32 on the VPU; the multi-query
+  kernel's dots run on the MXU at its default precision, which rounds
+  fp32 operands to bf16 (on the chip: ~6e-3 max abs error against the
+  reference on fp32 pools, tests/test_tpu_kernels.py).
 """
 
 from __future__ import annotations
@@ -260,7 +264,9 @@ def _paged_kernel(
 
     @pl.when(w == 0)
     def _init():
-        m_ref[...] = jnp.full_like(m_ref, -jnp.inf)
+        # finfo.min, not -inf: exp(m_prev - m_new) stays NaN-free whatever
+        # the first computed page masks (same floor as the mq kernel)
+        m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
@@ -276,30 +282,25 @@ def _paged_kernel(
             k = k * ks_ref[0][..., None]
             v = v * vs_ref[0][..., None]
 
-        # [H, P]: batch over heads (q dim 0 / k dim 1), contract head_dim.
-        s = (
-            jax.lax.dot_general(
-                q, k, (((1,), (2,)), ((0,), (1,))),
-                preferred_element_type=jnp.float32,
-            )
-            * scale
-        )
-        pos = w * page_size + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        s = jnp.where(pos < length, s, _NEG_INF)
+        # One query row per head has no matrix to multiply: a dot batched
+        # over heads would have an lhs [H, D] with no free dimension, which
+        # Mosaic refuses. The scores are a lane reduction instead, kept
+        # [P, H, 1] so heads stay on sublanes from here to the [H, D]
+        # accumulator — no transpose, no relayout, exact fp32 (the MXU
+        # would round fp32 operands to bf16). A decode step is bound by
+        # the page bytes it streams, not by these P*H*D multiply-adds.
+        s = jnp.sum(k * q[None], axis=-1, keepdims=True) * scale  # [P, H, 1]
+        pos = w * page_size + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+        valid = pos < length
+        s = jnp.where(valid, s, _NEG_INF)
 
         m_prev = m_ref[...][:, :1]  # [H, 1]
         l_prev = l_ref[...][:, :1]
-        m_cur = jnp.max(s, axis=1, keepdims=True)
-        m_new = jnp.maximum(m_prev, m_cur)
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=0))
         alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new)  # [H, P]
-        l_new = alpha * l_prev + jnp.sum(p, axis=1, keepdims=True)
-        # [H, D]: batch over heads (p dim 0 / v dim 1), contract page lanes.
-        pv = jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((0,), (1,))),
-            preferred_element_type=jnp.float32,
-        )
-        acc_ref[...] = acc_ref[...] * alpha + pv
+        p = jnp.where(valid, jnp.exp(s - m_new[None]), 0.0)  # [P, H, 1]
+        l_new = alpha * l_prev + jnp.sum(p, axis=0)
+        acc_ref[...] = acc_ref[...] * alpha + jnp.sum(p * v, axis=0)  # [H, D]
         m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
         l_ref[...] = jnp.broadcast_to(l_new, l_ref.shape)
 

@@ -213,15 +213,11 @@ def ring_attention(
         dropout_impl=dropout_impl,
         axis_name=AXIS_SEQ,
     )
-    # dispatch.shard_map owns the jax.shard_map-vs-experimental import and
-    # the check_vma/check_rep kwarg rename across the jax versions in play
-    from pytorch_distributed_training_tpu.ops.dispatch import shard_map
-
-    fn = shard_map(
+    fn = jax.shard_map(
         lambda q, k, v, b, r: body(q, k, v, b, dropout_rng=r),
         mesh=mesh,
         in_specs=(qkv_spec, qkv_spec, qkv_spec, bias_spec, P()),
         out_specs=qkv_spec,
-        check_rep=False,
+        check_vma=False,
     )
     return fn(q, k, v, bias, rng)

@@ -64,8 +64,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from pytorch_distributed_training_tpu.ops.dispatch import shard_map
-
 
 def gpipe_apply(
     mesh: Mesh,
@@ -261,12 +259,12 @@ def gpipe_apply(
             out_specs,
             jax.tree.map(lambda _: P(axis), stacked_quant),
         )
-    out = shard_map(
+    out = jax.shard_map(
         inner,
         mesh=mesh,
         in_specs=tuple(in_specs),
         out_specs=out_specs,
-        check_rep=False,
+        check_vma=False,
     )(*args)
     if has_quant:
         return out[0][-1], out[1]
@@ -632,12 +630,12 @@ def one_f_one_b_grads(
         out_specs = out_specs + (
             jax.tree.map(lambda _: P(axis), stacked_quant),
         )
-    res = shard_map(
+    res = jax.shard_map(
         inner,
         mesh=mesh,
         in_specs=tuple(in_specs),
         out_specs=out_specs,
-        check_rep=False,
+        check_vma=False,
     )(*args)
     tg, hg, loss, dxs = res[:4]
     # head grads / loss are real on the LAST stage; dxs on stage 0

@@ -60,78 +60,39 @@ stretches tick time deterministically to drill deadline/backpressure
 paths. ``bench.py --serve`` is the closed-loop load generator.
 """
 
-from pytorch_distributed_training_tpu.serve.autoscale import (
-    AutoscaleConfig,
-    Autoscaler,
-)
-from pytorch_distributed_training_tpu.serve.engine import (
-    DecodeEngine,
-    EngineConfig,
-)
-from pytorch_distributed_training_tpu.serve.queue import (
-    BackpressureError,
-    BrownoutController,
-    GenRequest,
-    RequestQueue,
-)
-from pytorch_distributed_training_tpu.serve.prefix_cache import (
-    PrefixCache,
-    PrefixMatch,
-)
-from pytorch_distributed_training_tpu.serve.fleet import (
-    FleetConfig,
-    RollingSwapCoordinator,
-    ServeFleet,
-)
-from pytorch_distributed_training_tpu.serve.trace import (
-    TraceConfig,
-    TraceEvent,
-    generate_trace,
-    replay,
-)
-from pytorch_distributed_training_tpu.serve.hotswap import (
-    CheckpointWatcher,
-    HotSwapManager,
-    publish_params_checkpoint,
-)
-from pytorch_distributed_training_tpu.serve.router import (
-    CircuitBreaker,
-    Router,
-    RouterConfig,
-    make_router_http_server,
-)
-from pytorch_distributed_training_tpu.serve.server import (
-    InferenceServer,
-    make_http_server,
-    serve_stdio,
-)
+from pytorch_distributed_training_tpu.utils.lazy import lazy_exports
 
-__all__ = [
-    "AutoscaleConfig",
-    "Autoscaler",
-    "BackpressureError",
-    "BrownoutController",
-    "CheckpointWatcher",
-    "CircuitBreaker",
-    "DecodeEngine",
-    "EngineConfig",
-    "FleetConfig",
-    "GenRequest",
-    "HotSwapManager",
-    "InferenceServer",
-    "PrefixCache",
-    "PrefixMatch",
-    "RequestQueue",
-    "RollingSwapCoordinator",
-    "Router",
-    "RouterConfig",
-    "ServeFleet",
-    "TraceConfig",
-    "TraceEvent",
-    "generate_trace",
-    "make_http_server",
-    "make_router_http_server",
-    "publish_params_checkpoint",
-    "replay",
-    "serve_stdio",
-]
+# resolved on first use: the fleet coordinator imports ``serve.fleet`` /
+# ``serve.router`` and stays jax-free (utils/lazy.py)
+_LAZY = {
+    "AutoscaleConfig": "autoscale",
+    "Autoscaler": "autoscale",
+    "DecodeEngine": "engine",
+    "EngineConfig": "engine",
+    "BackpressureError": "queue",
+    "BrownoutController": "queue",
+    "GenRequest": "queue",
+    "RequestQueue": "queue",
+    "PrefixCache": "prefix_cache",
+    "PrefixMatch": "prefix_cache",
+    "FleetConfig": "fleet",
+    "RollingSwapCoordinator": "fleet",
+    "ServeFleet": "fleet",
+    "TraceConfig": "trace",
+    "TraceEvent": "trace",
+    "generate_trace": "trace",
+    "replay": "trace",
+    "CheckpointWatcher": "hotswap",
+    "HotSwapManager": "hotswap",
+    "publish_params_checkpoint": "hotswap",
+    "CircuitBreaker": "router",
+    "Router": "router",
+    "RouterConfig": "router",
+    "make_router_http_server": "router",
+    "InferenceServer": "server",
+    "make_http_server": "server",
+    "serve_stdio": "server",
+}
+
+__all__ = sorted(_LAZY)
+__getattr__ = lazy_exports(__name__, _LAZY)

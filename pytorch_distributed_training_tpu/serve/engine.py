@@ -475,15 +475,23 @@ class DecodeEngine:
         self.variant = "int8" if config.weights_dtype == "int8" else "fp32"
         if config.weights_dtype == "int8":
             params = quantize_serve_params(params)
-        # Tensor-parallel mesh (tp > 1): every jitted program below runs
-        # under pjit over a `model`-axis mesh — params shard by the serve
-        # rules (heads / MLP hidden), pools shard on the head dim, and all
-        # host-built operands are placed REPLICATED through self._put (a
-        # device-0-committed operand mixed with mesh-sharded params is a
-        # placement error, not a resharding).
+        # Placement: EVERY program input — params, KV state, host-built
+        # operands — is committed to one explicit sharding from the first
+        # call on. jit keys its executables on committed-ness: a resident
+        # tree left uncommitted (``jnp.zeros``, a bare ``device_put``)
+        # comes back committed from the first program that also took a
+        # committed input (a restored checkpoint), and the next call of
+        # every warm program then builds a second executable.
+        #
+        # tp == 1: the engine's one device. Tensor-parallel mesh (tp > 1):
+        # every jitted program below runs under pjit over a `model`-axis
+        # mesh — params shard by the serve rules (heads / MLP hidden),
+        # pools shard on the head dim, and all host-built operands are
+        # placed REPLICATED through self._put (a device-0-committed operand
+        # mixed with mesh-sharded params is a placement error, not a
+        # resharding).
         self._mesh = None
-        self._param_shardings = None
-        self._repl = None
+        self._repl = jax.sharding.SingleDeviceSharding(jax.devices()[0])
         if config.tp > 1:
             from pytorch_distributed_training_tpu.comms.mesh import (
                 MeshConfig,
@@ -585,31 +593,17 @@ class DecodeEngine:
                 draft_params = quantize_serve_params(draft_params)
             if self._mesh is not None:
                 _check_tp_divisible(dmc, config.tp, "draft")
-                from pytorch_distributed_training_tpu.parallel.sharding import (  # noqa: E501
-                    serve_param_shardings,
-                )
-
-                self._draft_params = jax.device_put(
-                    draft_params,
-                    serve_param_shardings(draft_params, self._mesh),
-                )
-            else:
-                self._draft_params = jax.device_put(draft_params)
+            self._draft_params = jax.device_put(
+                draft_params, self._shardings_for(draft_params)
+            )
         # explicit placement: restored checkpoints arrive as host arrays,
         # and a host tree reaching the warm compiled calls would be an
         # implicit per-tick H2D (a strict-mode transfer violation). Under
         # tp the placement IS the sharding: weights shard at load, and
         # every later swap re-places onto the same shardings so the warm
-        # programs never see a new input layout (no retrace).
-        if self._mesh is not None:
-            from pytorch_distributed_training_tpu.parallel.sharding import (
-                serve_param_shardings,
-            )
-
-            self._param_shardings = serve_param_shardings(params, self._mesh)
-            self._params = jax.device_put(params, self._param_shardings)
-        else:
-            self._params = jax.device_put(params)
+        # programs never see a new input layout (no recompile).
+        self._param_shardings = self._shardings_for(params)
+        self._params = jax.device_put(params, self._param_shardings)
         self._queue = queue
         # live weight-swap state: version served, one pending (validated,
         # device-placed) replacement, and the trial window's keep-alive of
@@ -654,16 +648,9 @@ class DecodeEngine:
                     position_ids=jnp.zeros((1, 1), jnp.int32),
                 )
             )["cache"]
-            self._cache = jax.tree.map(
+            self._cache = self._place_pools(jax.tree.map(
                 lambda s: jnp.zeros(s.shape, s.dtype), strip_tables(shapes)
-            )
-            if self._mesh is not None:
-                # pools split on the head dim (each shard owns its own
-                # 1/N-width page pool); the page axis stays whole so the
-                # allocator's block-table arithmetic is untouched. Per-leaf
-                # shardings: int8 pools carry rank-3 fp32 scale pools whose
-                # heads axis shards with the values they scale.
-                self._cache = self._place_pools(self._cache)
+            ))
             self._pages = PageAllocator(
                 config.total_pages, config.page_size,
                 config.pages_per_slot, config.num_slots,
@@ -682,12 +669,10 @@ class DecodeEngine:
                         position_ids=jnp.zeros((1, 1), jnp.int32),
                     )
                 )["cache"]
-                self._draft_cache = jax.tree.map(
+                self._draft_cache = self._place_pools(jax.tree.map(
                     lambda s: jnp.zeros(s.shape, s.dtype),
                     strip_tables(dshapes),
-                )
-                if self._mesh is not None:
-                    self._draft_cache = self._place_pools(self._draft_cache)
+                ))
         else:
             # Per-slot cache template comes from a batch-1 abstract init at
             # the full cache length (no params materialized); the resident
@@ -698,10 +683,10 @@ class DecodeEngine:
                     jnp.ones((1, config.cache_len), jnp.int32),
                 )
             )["cache"]
-            self._cache = jax.tree.map(
+            self._cache = self._put(jax.tree.map(
                 lambda s: jnp.zeros((config.num_slots,) + s.shape, s.dtype),
                 shapes,
-            )
+            ))
             self._pages = None
         self._slots: list[Optional[_Slot]] = [None] * config.num_slots
         self._prefill_fns: dict[int, object] = {}   # bucket -> jitted fn
@@ -805,19 +790,33 @@ class DecodeEngine:
     # -------------------------------------------------------------- compiled
 
     def _put(self, tree):
-        """ONE explicit H2D for host-built operands. Single-device: plain
-        ``device_put``. Tensor-parallel: committed REPLICATED onto the
-        mesh — every program input must live on all the mesh's devices
+        """ONE explicit H2D for host-built operands, committed to the
+        engine's device — or, tensor-parallel, REPLICATED onto the mesh:
+        every program input must live on all the mesh's devices
         (params/pools sharded, operands replicated), or dispatch would
         mix device-0-committed arrays with mesh-committed ones."""
-        if self._repl is None:
-            return jax.device_put(tree)
         return jax.device_put(tree, self._repl)
 
+    def _shardings_for(self, params):
+        """What ``device_put`` places a serving params tree onto: the
+        engine's one device, or per-leaf tp shardings over the mesh."""
+        if self._mesh is None:
+            return self._repl
+        from pytorch_distributed_training_tpu.parallel.sharding import (
+            serve_param_shardings,
+        )
+
+        return serve_param_shardings(params, self._mesh)
+
     def _place_pools(self, pools):
-        """Shard a K/V pool tree over the tp mesh: rank-4 value pools and
-        (int8 cache) rank-3 scale pools both split on their heads axis —
-        shape-aware per leaf, one placement."""
+        """Commit a K/V pool tree to the engine's device — or shard it over
+        the tp mesh: pools split on the head dim (each shard owns its own
+        1/N-width page pool) while the page axis stays whole, so the
+        allocator's block-table arithmetic is untouched. Rank-4 value
+        pools and (int8 cache) rank-3 scale pools both split on their
+        heads axis — shape-aware per leaf, one placement."""
+        if self._mesh is None:
+            return self._put(pools)
         from pytorch_distributed_training_tpu.parallel.sharding import (
             serve_pool_shardings,
         )
@@ -828,10 +827,11 @@ class DecodeEngine:
 
     @property
     def param_shardings(self):
-        """Per-leaf NamedShardings of the serving params (None when
-        tp == 1): hot-swap loaders ``device_put`` replacement trees onto
-        exactly these so a live swap keeps the compiled programs' input
-        layouts (no retrace, no implicit reshard)."""
+        """Where the serving params live (one device's sharding, or
+        per-leaf NamedShardings under tp): hot-swap loaders ``device_put``
+        replacement trees onto exactly this so a live swap keeps the
+        compiled programs' input layouts (no recompile, no implicit
+        reshard)."""
         return self._param_shardings
 
     def _serve_manifest(self, name: str):
@@ -1500,14 +1500,11 @@ class DecodeEngine:
         transition, recorded by name — not a shape/dtype rejection."""
         params, variant = self._coerce_variant(params)
         self._validate_swap(params)
-        # tp: re-place onto the SAME per-leaf shardings the warm programs
-        # were compiled against — a replicated (or device-0) replacement
-        # tree would change the compiled input layouts and retrace
-        placed = (
-            jax.device_put(params, self._param_shardings)
-            if self._param_shardings is not None
-            else jax.device_put(params)
-        )
+        # re-place onto the SAME shardings the warm programs were compiled
+        # against — a tree committed anywhere else (under tp: replicated,
+        # or on device 0) would change the compiled input layouts and
+        # build new programs
+        placed = jax.device_put(params, self._param_shardings)
         with self._swap_lock:
             if self._pending_swap is not None:
                 raise RuntimeError(
@@ -1532,11 +1529,7 @@ class DecodeEngine:
             params, variant = self._coerce_variant(params)
         self._validate_swap(params)
         prev_params, prev_version = self._params, self.weights_step
-        self._params = (
-            jax.device_put(params, self._param_shardings)
-            if self._param_shardings is not None
-            else jax.device_put(params)
-        )
+        self._params = jax.device_put(params, self._param_shardings)
         self.weights_step = version
         self._trial = (prev_params, prev_version, ticket)
         self._last_swap_variant = variant

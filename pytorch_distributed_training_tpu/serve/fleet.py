@@ -61,6 +61,10 @@ from pytorch_distributed_training_tpu.serve.router import (
     Router,
     RouterConfig,
 )
+from pytorch_distributed_training_tpu.utils.chips import (
+    chip_env,
+    require_chips,
+)
 from pytorch_distributed_training_tpu.utils.logging import get_logger
 
 logger = get_logger(__name__)
@@ -117,7 +121,8 @@ class FleetConfig:
     ``replica_extra_args`` maps replica index -> extra argv for that
     replica only (e.g. its own --metrics-dir); ``replica_env`` overlays
     the inherited environment; ``fault_env`` maps replica index -> a
-    PDT_TPU_FAULT value for that replica only."""
+    PDT_TPU_FAULT value for that replica only. On a TPU host each replica
+    is handed one chip of its own (utils/chips.py)."""
 
     num_replicas: int = 2
     replica_args: tuple = ()
@@ -150,10 +155,14 @@ class ReplicaProcess:
     """One supervised serving subprocess on a fixed port."""
 
     def __init__(self, index: int, port: int, fleet_cfg: FleetConfig,
-                 registry):
+                 registry, chip_slot: Optional[int] = None):
         self.index = index
         self.name = f"r{index}"
         self.port = port
+        # TPU host: which chip is this replica's alone (None elsewhere).
+        # A slot, not the index: indices only grow, chips are reused when
+        # a retired replica has let go of them.
+        self.chip_slot = chip_slot
         self._cfg = fleet_cfg
         self._registry = registry
         self.proc: Optional[subprocess.Popen] = None
@@ -209,6 +218,8 @@ class ReplicaProcess:
 
     def _env(self) -> dict:
         env = dict(os.environ)
+        if self.chip_slot is not None:
+            env.update(chip_env(self.chip_slot))
         env.update(self._cfg.replica_env)
         # fault routing: only THIS replica's serve-scoped specs survive
         env.pop("PDT_TPU_FAULT", None)
@@ -227,9 +238,11 @@ class ReplicaProcess:
         the crash path."""
         port_tries = 0
         while True:
+            # a replica that dies or goes unhealthy says why on the
+            # fleet's own stderr: its stderr is inherited and its stdout
+            # (in HTTP mode only the framework log) joins it
             proc = subprocess.Popen(
-                self._argv(), env=self._env(),
-                stdout=subprocess.DEVNULL, stderr=subprocess.DEVNULL,
+                self._argv(), env=self._env(), stdout=sys.stderr.fileno(),
             )
             with self._lock:
                 self.spawns += 1
@@ -606,9 +619,14 @@ class ServeFleet:
             fleet_config.fault_env = split_fault_specs(
                 os.environ.get("PDT_TPU_FAULT")
             )
+        # refuses a pool the host's chips cannot hold, one process per chip
+        self._tpu_host = require_chips(
+            "serve fleet", fleet_config.num_replicas
+        ) > 0
         self.replicas = [
             ReplicaProcess(
-                i, find_free_port(fleet_config.host), fleet_config, registry
+                i, find_free_port(fleet_config.host), fleet_config, registry,
+                chip_slot=i if self._tpu_host else None,
             )
             for i in range(fleet_config.num_replicas)
         ]
@@ -687,11 +705,16 @@ class ServeFleet:
         traffic only after the router's health poll qualifies it (the
         add_endpoint readiness gate), so callers can fire-and-forget."""
         with self._pool_lock:
+            slot = None
+            if self._tpu_host:
+                require_chips("serve fleet scale-up", len(self.replicas) + 1)
+                taken = {r.chip_slot for r in self.replicas}
+                slot = next(i for i in range(len(taken) + 1) if i not in taken)
             index = self._next_index
             self._next_index += 1
             replica = ReplicaProcess(
                 index, find_free_port(self.config.host), self.config,
-                self._registry,
+                self._registry, chip_slot=slot,
             )
             replica.on_port_change = self._port_changed
             self.replicas = self.replicas + [replica]

@@ -26,44 +26,25 @@ The serving observability plane layers on top of the same sink:
 ``scripts/trace_view.py`` renders one trace's waterfall + a fleet timeline.
 """
 
-from pytorch_distributed_training_tpu.telemetry.flight import (
-    FlightRecorder,
-)
-from pytorch_distributed_training_tpu.telemetry.registry import (
-    MetricsRegistry,
-    TimerStat,
-    get_registry,
-    set_registry,
-)
-from pytorch_distributed_training_tpu.telemetry.sink import (
-    JsonlSink,
-    run_metadata,
-)
-from pytorch_distributed_training_tpu.telemetry.slo import (
-    BurnRateMonitor,
-    SloConfig,
-)
-from pytorch_distributed_training_tpu.telemetry.spans import (
-    Span,
-    Tracer,
-    trace_coverage,
-)
-from pytorch_distributed_training_tpu.telemetry.straggler import (
-    epoch_straggler_stats,
-)
+from pytorch_distributed_training_tpu.utils.lazy import lazy_exports
 
-__all__ = [
-    "MetricsRegistry",
-    "TimerStat",
-    "JsonlSink",
-    "run_metadata",
-    "epoch_straggler_stats",
-    "get_registry",
-    "set_registry",
-    "Tracer",
-    "Span",
-    "trace_coverage",
-    "FlightRecorder",
-    "BurnRateMonitor",
-    "SloConfig",
-]
+# resolved on first use: registry/slo/spans/flight are jax-free and imported
+# by the fleet coordinator; sink and straggler pull in jax (utils/lazy.py)
+_LAZY = {
+    "FlightRecorder": "flight",
+    "MetricsRegistry": "registry",
+    "TimerStat": "registry",
+    "get_registry": "registry",
+    "set_registry": "registry",
+    "JsonlSink": "sink",
+    "run_metadata": "sink",
+    "BurnRateMonitor": "slo",
+    "SloConfig": "slo",
+    "Span": "spans",
+    "Tracer": "spans",
+    "trace_coverage": "spans",
+    "epoch_straggler_stats": "straggler",
+}
+
+__all__ = sorted(_LAZY)
+__getattr__ = lazy_exports(__name__, _LAZY)

@@ -27,8 +27,6 @@ import threading
 import time
 from typing import Any
 
-import jax
-
 from pytorch_distributed_training_tpu.analysis import concurrency
 
 
@@ -60,7 +58,12 @@ class JsonlSink:
         filename: str = "metrics.jsonl",
         process_index: int | None = None,
     ):
-        pidx = jax.process_index() if process_index is None else process_index
+        if process_index is None:
+            # jax only when asked to resolve the rank: the fleet coordinator
+            # passes its own and stays jax-free
+            import jax
+
+            process_index = jax.process_index()
         self._file = None
         # serving emits from many threads at once (router request handlers,
         # the health loop, fleet monitors); a lock keeps each JSONL line
@@ -71,7 +74,7 @@ class JsonlSink:
         # recurse into emit)
         self._lock = concurrency.lock("telemetry.sink")
         self.path = os.path.join(os.path.abspath(metrics_dir), filename)
-        if pidx == 0:
+        if process_index == 0:
             os.makedirs(os.path.dirname(self.path), exist_ok=True)
             self._file = open(self.path, "a")
 
@@ -113,6 +116,8 @@ def run_metadata(mesh, model_config=None, train_config=None, **extra) -> dict:
     """The ``run_meta`` header record: everything needed to interpret the
     stream without the launching shell — mesh shape, chip count, resolved
     configs, jax version."""
+    import jax
+
     rec = {
         "record": "run_meta",
         "mesh_shape": {k: int(v) for k, v in dict(mesh.shape).items()},

@@ -1,22 +1,17 @@
-from pytorch_distributed_training_tpu.train.optim import (
-    adamw_with_schedule,
-    linear_warmup_schedule,
-)
-from pytorch_distributed_training_tpu.train.state import TrainState, create_train_state
-from pytorch_distributed_training_tpu.train.step import (
-    calibrate_quant,
-    make_eval_step,
-    make_train_step,
-)
-from pytorch_distributed_training_tpu.train.metrics import MetricAccumulator
+from pytorch_distributed_training_tpu.utils.lazy import lazy_exports
 
-__all__ = [
-    "adamw_with_schedule",
-    "linear_warmup_schedule",
-    "TrainState",
-    "create_train_state",
-    "make_train_step",
-    "make_eval_step",
-    "calibrate_quant",
-    "MetricAccumulator",
-]
+# resolved on first use: ``train.manifest`` is jax-free and imported by the
+# fleet coordinator and scripts/verify_checkpoint.py (utils/lazy.py)
+_LAZY = {
+    "adamw_with_schedule": "optim",
+    "linear_warmup_schedule": "optim",
+    "TrainState": "state",
+    "create_train_state": "state",
+    "make_train_step": "step",
+    "make_eval_step": "step",
+    "calibrate_quant": "step",
+    "MetricAccumulator": "metrics",
+}
+
+__all__ = sorted(_LAZY)
+__getattr__ = lazy_exports(__name__, _LAZY)

@@ -1,20 +1,25 @@
 """Warm-start compilation: persistent XLA cache + AOT step compiles.
 
-Two independent levers against cold-start latency, both wired through the
-Trainer (train/loop.py) and all three train CLIs via ``TrainConfig``:
+Two independent levers against cold-start latency:
 
-- ``enable_persistent_cache(dir)`` (``--compile-cache-dir``) points JAX's
-  persistent compilation cache at ``dir`` so a second run of the same
-  recipe loads compiled executables instead of re-invoking XLA. The
-  thresholds are dropped to zero so even sub-second CPU smoke compiles
-  persist — warm start must cover the tiny configs tests exercise, not
-  just hour-long TPU compiles.
+- ``enable_compile_cache()`` — called first by every entry point that
+  compiles (the Trainer for the three train CLIs, ``serve_lm``,
+  ``generate_lm``, ``bench.py``, ``chip_smoke.py``) — turns on JAX's
+  persistent compilation cache so a second run of the same program loads
+  compiled executables instead of re-invoking XLA. WHERE the cache lives
+  is decided outside the program: ``JAX_COMPILATION_CACHE_DIR`` when the
+  environment sets it, else — on the TPU backend — one fixed directory in
+  the checkout (``<repo>/.jax_cache``). The directory must not move: a
+  machine that keeps its cache between runs only finds it again under the
+  same path. The thresholds are dropped to zero so even sub-second
+  compiles persist — warm start must cover the tiny configs tests
+  exercise, not just minute-long TPU compiles.
 - ``aot_warm_start(...)`` lowers and compiles the train/eval steps against
   the loaders' ``batch_spec()`` BEFORE epoch 0, so the first step of the
   run is a normal steady-state step: compile wall time moves out of the
   step stream into its own ``compile`` telemetry record (with a cache-hit
-  flag when a cache dir is configured), the per-step ``compile_inclusive``
-  flag disappears, and the watchdog can arm from step 1.
+  flag), the per-step ``compile_inclusive`` flag disappears, and the
+  watchdog can arm from step 1.
 
 The compiled executables keep the jitted functions' donation and sharding
 contracts (AOT lowering carries ``donate_argnums``/``in_shardings``), so
@@ -30,27 +35,43 @@ import time
 import jax
 from jax.sharding import NamedSharding
 
+#: the cache's home when the environment names none: fixed, inside the
+#: checkout, git-ignored — never a temp name, a pid or a timestamp
+REPO_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))),
+    ".jax_cache",
+)
 
-def enable_persistent_cache(cache_dir: str | None) -> str | None:
-    """Enable JAX's persistent compilation cache rooted at ``cache_dir``.
 
-    Returns the absolute cache path (None when disabled). Process-global:
-    every jit compile from here on — state init, calibration, train/eval
-    steps — reads/writes the cache.
-    """
-    if not cache_dir:
-        return None
-    path = os.path.abspath(cache_dir)
-    os.makedirs(path, exist_ok=True)
-    jax.config.update("jax_compilation_cache_dir", path)
+def enable_compile_cache() -> str | None:
+    """Enable JAX's persistent compilation cache; returns its directory
+    (None when this run stays uncached).
+
+    WHERE is never a flag: ``JAX_COMPILATION_CACHE_DIR`` when the
+    environment sets it — JAX already reads it, so no directory is set in
+    code — else, on the TPU backend, ``REPO_CACHE_DIR``. Any other backend
+    stays uncached by default: XLA:CPU compiles in seconds and answers
+    every reload of a cached executable with kilobytes of "machine
+    features don't match ... could lead to SIGILL" (it aborted outright on
+    older builds). Process-global and idempotent: every jit compile from
+    here on — state init, calibration, train/eval/serve programs — reads
+    and writes the cache. Call it after ``comms.initialize()``: asking for
+    the backend starts it."""
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not path:
+        if jax.default_backend() != "tpu":
+            return None
+        path = REPO_CACHE_DIR
+        jax.config.update("jax_compilation_cache_dir", path)
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
     return path
 
 
 def cache_entry_count(cache_dir: str | None) -> int | None:
-    """Number of cache entries currently on disk (None when no dir)."""
-    if not cache_dir or not os.path.isdir(cache_dir):
+    """Number of cache files currently on disk (None when the run is
+    uncached; 0 before JAX has created the directory)."""
+    if not cache_dir:
         return None
     n = 0
     for _, _, files in os.walk(cache_dir):

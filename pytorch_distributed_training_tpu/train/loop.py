@@ -108,12 +108,10 @@ class Trainer:
         # Persistent compilation cache FIRST: every compile below (state
         # init, quant calibration, the AOT warm start) should read/write it
         from pytorch_distributed_training_tpu.train.compile import (
-            enable_persistent_cache,
+            enable_compile_cache,
         )
 
-        self.compile_cache_dir = enable_persistent_cache(
-            train_config.compile_cache_dir
-        )
+        self.compile_cache_dir = enable_compile_cache()
         self.registry = MetricsRegistry()
         set_registry(self.registry)
         # Runtime correctness guards (analysis/guards.py): recompile
@@ -434,6 +432,7 @@ class Trainer:
                     "(no toolchain?)"
                 )
         if loader is None:
+            log0(f"{what} loader: Python ShardedLoader")
             loader = ShardedLoader(
                 data, self.mesh,
                 global_batch_size=batch, grad_accum_steps=accum,
@@ -504,6 +503,11 @@ class Trainer:
         )
         try:
             self._run_epochs(cfg, n_chips, start_epoch, skip_in_first_epoch)
+            from pytorch_distributed_training_tpu.ops import dispatch
+
+            # the XLA fallbacks are silent by design; this is where a run
+            # says whether its fused ops took the kernels
+            log0(dispatch.summary())
         finally:
             if self._shutdown is not None:
                 self._shutdown.uninstall()
@@ -538,7 +542,8 @@ class Trainer:
         schedules (they own their batch contract), ``chain_steps > 1``
         (the chain stack's device-side layout is XLA's choice), and
         seq-sharded meshes (batch shardings are inherited per-leaf from
-        the loader). Failure is non-fatal: the lazy path still works."""
+        the loader). A failure raises on the TPU backend; elsewhere it is
+        logged and the lazy path takes over."""
         cfg = self.tcfg
         if not cfg.aot_warmup or self._first_step_done:
             return
@@ -594,11 +599,13 @@ class Trainer:
                     self.mesh, fsdp_sharded=fsdp_sharded
                 ),
             )
-        except GuardViolation:
-            # a strict donation-audit failure is a finding, not a compile
-            # hiccup — don't swallow it into the lazy-jit fallback
-            raise
-        except Exception as e:  # noqa: BLE001 — warm start is best-effort
+        except Exception as e:  # noqa: BLE001 — off-chip warm start is best-effort
+            # a strict audit failure is a finding, not a compile hiccup; and
+            # on the chip whatever the compiler refused (a Mosaic kernel, a
+            # VMEM limit, an OOM) IS the error — the lazy path would pay the
+            # same compile again to fail one step later
+            if isinstance(e, GuardViolation) or jax.default_backend() == "tpu":
+                raise
             log0(f"AOT warm start failed ({e!r}); first step compiles lazily")
             return
         self.train_step = compiled_train

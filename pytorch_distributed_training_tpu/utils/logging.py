@@ -28,17 +28,17 @@ _configured: set[str] = set()  # logger names whose handlers we own
 
 
 def _process_index() -> int:
-    try:
-        import jax
-
-        return jax.process_index()
-    except Exception:  # pragma: no cover - jax always importable in practice
-        return 0
+    """This process's rank from the launcher contract (``JAX_PROCESS_ID``;
+    ``comms.bootstrap.initialize`` exports it after a cluster auto-detect).
+    Never ``jax.process_index()``: that initializes the default backend,
+    and a log line from the jax-free fleet coordinator would then claim
+    the chip its replicas need."""
+    return int(os.environ.get("JAX_PROCESS_ID", "0"))
 
 
 class _ProcessIndexFilter(logging.Filter):
     """Stamp the emitting host's process index on every record (resolved at
-    emit time — jax.distributed may initialize after the logger exists)."""
+    emit time — the rendezvous may complete after the logger exists)."""
 
     def filter(self, record: logging.LogRecord) -> bool:
         record.pindex = _process_index()

@@ -65,8 +65,8 @@ def test_recompile_strict_raises_and_records():
     f(jnp.ones((2,)))              # warm, same shape: fine
     assert gs.violations == 0 and not sink.of("recompile")
 
-    with pytest.raises(RecompileError, match="retraced after warm-up"):
-        f(jnp.ones((3,)))          # new shape -> retrace -> violation
+    with pytest.raises(RecompileError, match="recompiled after warm-up"):
+        f(jnp.ones((3,)))          # new shape -> new program -> violation
     (rec,) = sink.of("recompile")
     assert rec["name"] == "f" and rec["calls"] == 3
     assert gs.recompile_violations == 1

@@ -39,7 +39,7 @@ def mesh(eight_devices):
 
 
 def _counts(op):
-    return dispatch.KERNEL_DISPATCH_COUNTS[op]
+    return dispatch.DISPATCH_PATHS[f"{op}:shard_map"]
 
 
 def test_mode_off_without_registered_mesh(eight_devices):

@@ -308,6 +308,50 @@ def test_extract_collectives_synthetic_dump():
     assert all("done" not in c.name and c.kind != "add" for c in cs)
 
 
+# what XLA:TPU writes (bert-large fsdp=4 step compiled for a v5e 2x2, PR 21):
+# tiled layouts with parentheses of their own, combined all-reduces with
+# tuple shapes, async permutes, and reduce-scatter as a fused computation
+_TPU_HLO = """\
+HloModule jit_train_step
+
+%all-reduce-scatter.6.clone.clone (input.245: bf16[4096,1024]) -> bf16[1032,1024] {
+  %input.245 = bf16[4096,1024]{1,0:T(8,128)(2,1)S(1)} parameter(0)
+  %pad.1095 = bf16[4128,1024]{1,0:T(8,128)(2,1)} pad(%input.245, %constant.48184), padding=0_32x0_0
+  %all-reduce.1695 = bf16[4128,1024]{1,0:T(8,128)(2,1)} all-reduce(%pad.1095), channel_id=848, replica_groups={{0,1,2,3}}, use_global_device_ids=true, to_apply=%add.4.clone
+  ROOT %dynamic-slice.1442 = bf16[1032,1024]{1,0:T(8,128)(2,1)} dynamic-slice(%all-reduce.1695, %multiply.5524, %constant.48186), dynamic_slice_sizes={1032,1024}
+}
+
+ENTRY %main.1039_spmd (param.1: f32[1024]) -> f32[1024] {
+  %fusion.9135 = bf16[1032,1024]{1,0:T(8,128)(2,1)} fusion(%custom-call.1241), kind=kCustom, calls=%all-reduce-scatter.6.clone.clone
+  %all-reduce.1499 = (f32[1024]{0:T(1024)S(1)}, f32[2,1024]{1,0:T(2,128)S(1)}, /*index=2*/f32[16,64]{1,0:T(8,128)S(1)}) all-reduce(%a, %b, %c), channel_id=7, replica_groups={{0,1,2,3}}, to_apply=%add
+  %collective-permute-start.13 = (bf16[24,1024]{1,0:T(8,128)(2,1)}, bf16[24,1024]{1,0:T(8,128)(2,1)}, u32[]{:S(2)}, u32[]{:S(2)}) collective-permute-start(%slice.1268), channel_id=933, source_target_pairs={{0,1},{1,2},{2,3}}
+  %collective-permute-done.13 = bf16[24,1024]{1,0:T(8,128)(2,1)} collective-permute-done(%collective-permute-start.13)
+  ROOT %all-gather.3 = bf16[1024,4096]{1,0:T(8,128)(2,1)} all-gather(%p), channel_id=9, replica_groups=[1,4]<=[4], dimensions={0}, use_global_device_ids=true
+}
+"""
+
+
+def test_extract_collectives_reads_tpu_layouts_and_fused_reduce_scatter():
+    rs, ar, cp, ag = extract_collectives(_TPU_HLO, world_size=4)
+    # the all-reduce INSIDE an all-reduce-scatter fusion is the TPU's
+    # reduce-scatter: reported as one, payload = the scattered shard
+    assert (rs.kind, rs.bytes, rs.group_size) == (
+        "reduce-scatter", 4128 * 1024 * 2 // 4, 4)
+    # a combined all-reduce: tuple shape whose layouts carry parentheses
+    assert (ar.kind, ar.bytes) == (
+        "all-reduce", (1024 + 2 * 1024 + 16 * 64) * 4)
+    # async permute: the result buffer once, not the alias and the flags
+    assert (cp.kind, cp.bytes, cp.asynchronous) == (
+        "collective-permute", 24 * 1024 * 2, True)
+    assert (ag.kind, ag.bytes, ag.group_size) == (
+        "all-gather", 1024 * 4096 * 2, 4)
+    # and this footprint is what the fsdp manifest states
+    fsdp = train_manifest(_Shape(data=1, fsdp=4), fsdp_sharded=True)
+    summary = summarize_collectives([rs, ar, cp, ag])
+    assert fsdp.check(summary) == []
+    assert train_manifest(_Shape(data=4)).check(summary) != []
+
+
 def test_cost_model_ring_bytes_and_links():
     cm = CostModel(ici_gbps=90.0, dcn_gbps=12.5, devices_per_host=8)
     ag, ar, rs, cp = extract_collectives(_HLO, world_size=8)
@@ -373,8 +417,10 @@ def test_train_manifest_shapes_by_mesh_axes():
     assert train_manifest(_Shape(data=1)).allowed == ()
     assert train_manifest(_Shape(data=8)).allowed == ("all-reduce",)
     fsdp = train_manifest(_Shape(data=2, fsdp=4), fsdp_sharded=True)
+    # collective-permute: the halo exchange after XLA:TPU's padded
+    # reduce-scatter (see train_manifest)
     assert set(fsdp.allowed) == {"all-reduce", "all-gather",
-                                "reduce-scatter"}
+                                "reduce-scatter", "collective-permute"}
     assert fsdp.required == ("all-gather",)
     # fsdp axis present but nothing actually sharded: no gather required
     assert train_manifest(_Shape(data=2, fsdp=4)).required == ()

@@ -316,7 +316,7 @@ def test_stream_compile_record_from_aot_warm_start(metrics_run):
     assert c["compile_s"] == pytest.approx(
         c["train_compile_s"] + c["eval_compile_s"]
     )
-    assert c["cache_hit"] is None  # no --compile-cache-dir in this run
+    assert c["cache_hit"] is None  # CPU backend, no JAX_COMPILATION_CACHE_DIR
 
 
 def test_lazy_compile_path_flags_first_step(eight_devices, tmp_path):
