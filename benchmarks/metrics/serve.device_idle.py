@@ -1,0 +1,3 @@
+"""Share of the traced window in which no operation ran on the device."""
+
+from harness.trace_reduce import idle_share as read  # noqa: F401

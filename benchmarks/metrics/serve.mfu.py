@@ -1,0 +1,14 @@
+"""The whole model step's share of the chip's bf16 peak over the window:
+operations of the prompts whose first token arrived in it (causal prefill,
+head on the last token) and of every token decoded in it over its own
+context (`flops.py`), over window seconds times chips times peak."""
+
+
+def read(obs):
+    if not obs.get("peaks") or not obs.get("window_s"):
+        return None
+    work = obs["prefill_flops"] + obs["decode_flops"]
+    if not work:
+        return None
+    return 100.0 * work / (
+        obs["window_s"] * obs["chips"] * obs["peaks"]["bf16_flops_per_s"])
