@@ -244,155 +244,161 @@ def main(argv=None, in_stream=None, out_stream=None) -> dict:
     from pytorch_distributed_training_tpu.telemetry.registry import (
         get_registry,
     )
+    from pytorch_distributed_training_tpu.telemetry.spans import setup_phase
     from pytorch_distributed_training_tpu.train.compile import (
         enable_compile_cache,
     )
     from pytorch_distributed_training_tpu.utils.logging import log0
 
-    # first: the random init / checkpoint restore below already compiles
-    log0(f"compile cache: {enable_compile_cache()}")
-    # which chips this process holds (a fleet replica: the one it was given)
-    log0(f"devices: {[(d.platform, d.device_kind, d.id) for d in jax.devices()]}")
-    tok = build_tokenizer(args)
-    model, params, boot_step = load_model_and_params(args, tok)
+    # entry to ready: everything up to the started server is set-up
+    with setup_phase("serve_setup"):
+        # first: the random init / checkpoint restore below already compiles
+        log0(f"compile cache: {enable_compile_cache()}")
+        # which chips this process holds (a fleet replica: the one it was given)
+        log0(f"devices: {[(d.platform, d.device_kind, d.id) for d in jax.devices()]}")
+        registry = get_registry()
+        sink = None
+        if args.metrics_dir:
+            from pytorch_distributed_training_tpu.telemetry.sink import JsonlSink
 
-    draft_model = draft_params = None
-    spec_draft = "ngram"
-    if args.spec_k > 0 and args.draft_checkpoint:
-        # the draft lane reuses the full checkpoint-loading machinery on a
-        # cloned namespace: verified-step resolution, scanned-trunk probes
-        # and vocab checks all apply to the draft exactly as to the base
-        draft_args = argparse.Namespace(**{
-            **vars(args),
-            "model": args.draft_model,
-            "checkpoint_dir": args.draft_checkpoint,
-            "hf_checkpoint": None,
-        })
-        draft_model, draft_params, _ = load_model_and_params(draft_args, tok)
-        spec_draft = "model"
+            # before the load, so that its span is written too
+            sink = JsonlSink(args.metrics_dir)
+            registry.attach_sink(sink)
+        tok = build_tokenizer(args)
+        with setup_phase("serve_setup.load"):
+            model, params, boot_step = load_model_and_params(args, tok)
 
-    registry = get_registry()
-    sink = None
-    if args.metrics_dir:
-        from pytorch_distributed_training_tpu.telemetry.sink import JsonlSink
+        draft_model = draft_params = None
+        spec_draft = "ngram"
+        if args.spec_k > 0 and args.draft_checkpoint:
+            # the draft lane reuses the full checkpoint-loading machinery on a
+            # cloned namespace: verified-step resolution, scanned-trunk probes
+            # and vocab checks all apply to the draft exactly as to the base
+            draft_args = argparse.Namespace(**{
+                **vars(args),
+                "model": args.draft_model,
+                "checkpoint_dir": args.draft_checkpoint,
+                "hf_checkpoint": None,
+            })
+            draft_model, draft_params, _ = load_model_and_params(draft_args, tok)
+            spec_draft = "model"
 
-        sink = JsonlSink(args.metrics_dir)
-        registry.attach_sink(sink)
-        sink.emit({
-            "record": "serve_meta",
-            "model": args.model,
-            "num_slots": args.num_slots,
-            "prompt_buckets": args.prompt_buckets,
-            "max_new_tokens_cap": args.max_new_tokens_cap,
-            "queue_depth": args.queue_depth,
-            "kv_layout": args.kv_layout,
-            "page_size": args.page_size,
-            "num_pages": args.num_pages,
-            "sampling": args.sampling,
-            "spec_k": args.spec_k,
-            "spec_draft": spec_draft if args.spec_k > 0 else None,
-            "prefill_chunk": args.prefill_chunk,
-            "tp": args.tp,
-            "weights_dtype": args.weights_dtype,
-            "kv_dtype": args.kv_dtype,
-            "prefix_cache": args.prefix_cache,
-            "tenant_page_quota": args.tenant_page_quota,
-        })
+        if sink is not None:
+            sink.emit({
+                "record": "serve_meta",
+                "model": args.model,
+                "num_slots": args.num_slots,
+                "prompt_buckets": args.prompt_buckets,
+                "max_new_tokens_cap": args.max_new_tokens_cap,
+                "queue_depth": args.queue_depth,
+                "kv_layout": args.kv_layout,
+                "page_size": args.page_size,
+                "num_pages": args.num_pages,
+                "sampling": args.sampling,
+                "spec_k": args.spec_k,
+                "spec_draft": spec_draft if args.spec_k > 0 else None,
+                "prefill_chunk": args.prefill_chunk,
+                "tp": args.tp,
+                "weights_dtype": args.weights_dtype,
+                "kv_dtype": args.kv_dtype,
+                "prefix_cache": args.prefix_cache,
+                "tenant_page_quota": args.tenant_page_quota,
+            })
 
-    config = EngineConfig(
-        num_slots=args.num_slots,
-        prompt_buckets=tuple(
-            int(b) for b in args.prompt_buckets.split(",") if b.strip()
-        ),
-        max_new_tokens=args.max_new_tokens_cap,
-        kv_layout=args.kv_layout,
-        page_size=args.page_size,
-        num_pages=args.num_pages,
-        sampling=args.sampling,
-        warmup=args.warmup,
-        spec_k=args.spec_k,
-        spec_draft=spec_draft,
-        prefill_chunk=args.prefill_chunk,
-        tp=args.tp,
-        weights_dtype=args.weights_dtype,
-        kv_dtype=args.kv_dtype,
-        prefix_cache=args.prefix_cache,
-        tenant_page_quota=args.tenant_page_quota,
-        flight_capacity=args.flight_capacity,
-    )
-    from pytorch_distributed_training_tpu.analysis.concurrency import (
-        get_lock_registry,
-    )
-    from pytorch_distributed_training_tpu.analysis.guards import (
-        GuardSet,
-        guard_mode_from_env,
-    )
-
-    # the serve CLI runs strict by default (PR 11): violations fail the
-    # loop instead of just logging; --guards record is the opt-out. Lock
-    # discipline follows the same mode — set before any server/engine
-    # lock is created so off-mode skips instrumentation entirely.
-    guard_mode = args.guards or guard_mode_from_env(default="strict")
-    get_lock_registry().mode = guard_mode
-
-    # per-tier burn-rate monitor: always on (one throttled slo_burn record
-    # per emit interval); the brownout coupling below stays opt-in
-    from pytorch_distributed_training_tpu.telemetry.slo import (
-        BurnRateMonitor,
-        SloConfig,
-    )
-
-    slo = BurnRateMonitor(
-        SloConfig(
-            windows_s=tuple(
-                float(w) for w in args.slo_windows.split(",") if w.strip()
+        config = EngineConfig(
+            num_slots=args.num_slots,
+            prompt_buckets=tuple(
+                int(b) for b in args.prompt_buckets.split(",") if b.strip()
             ),
-            emit_interval_s=args.slo_emit_s,
-        ),
-        registry=registry,
-    )
-
-    brownout = None
-    if args.brownout_high > 0:
-        from pytorch_distributed_training_tpu.serve.queue import (
-            BrownoutController,
+            max_new_tokens=args.max_new_tokens_cap,
+            kv_layout=args.kv_layout,
+            page_size=args.page_size,
+            num_pages=args.num_pages,
+            sampling=args.sampling,
+            warmup=args.warmup,
+            spec_k=args.spec_k,
+            spec_draft=spec_draft,
+            prefill_chunk=args.prefill_chunk,
+            tp=args.tp,
+            weights_dtype=args.weights_dtype,
+            kv_dtype=args.kv_dtype,
+            prefix_cache=args.prefix_cache,
+            tenant_page_quota=args.tenant_page_quota,
+            flight_capacity=args.flight_capacity,
+        )
+        from pytorch_distributed_training_tpu.analysis.concurrency import (
+            get_lock_registry,
+        )
+        from pytorch_distributed_training_tpu.analysis.guards import (
+            GuardSet,
+            guard_mode_from_env,
         )
 
-        brownout = BrownoutController(
-            high_watermark=args.brownout_high,
-            low_watermark=args.brownout_low,
-            escalate_hold_s=args.brownout_escalate_hold_s,
-            deescalate_hold_s=args.brownout_deescalate_hold_s,
-            clamp_max_new=args.brownout_clamp,
+        # the serve CLI runs strict by default (PR 11): violations fail the
+        # loop instead of just logging; --guards record is the opt-out. Lock
+        # discipline follows the same mode — set before any server/engine
+        # lock is created so off-mode skips instrumentation entirely.
+        guard_mode = args.guards or guard_mode_from_env(default="strict")
+        get_lock_registry().mode = guard_mode
+
+        # per-tier burn-rate monitor: always on (one throttled slo_burn record
+        # per emit interval); the brownout coupling below stays opt-in
+        from pytorch_distributed_training_tpu.telemetry.slo import (
+            BurnRateMonitor,
+            SloConfig,
+        )
+
+        slo = BurnRateMonitor(
+            SloConfig(
+                windows_s=tuple(
+                    float(w) for w in args.slo_windows.split(",") if w.strip()
+                ),
+                emit_interval_s=args.slo_emit_s,
+            ),
             registry=registry,
-            slo_monitor=slo if args.slo_burn_high > 0 else None,
-            slo_burn_high=args.slo_burn_high,
         )
-    tier_deadlines = {}
-    if args.interactive_deadline_s > 0:
-        tier_deadlines["interactive"] = args.interactive_deadline_s
-    if args.batch_deadline_s > 0:
-        tier_deadlines["batch"] = args.batch_deadline_s
 
-    server = InferenceServer(
-        model, params, config,
-        queue_depth=args.queue_depth,
-        default_deadline_s=args.deadline_s or None,
-        tier_deadlines=tier_deadlines or None,
-        brownout=brownout,
-        registry=registry,
-        guards=GuardSet(mode=guard_mode, registry=registry),
-        stall_timeout_s=args.stall_timeout_s,
-        weights_step=boot_step,
-        draft_model=draft_model,
-        draft_params=draft_params,
-        slo=slo,
-        replica_name=args.replica_name,
-    ).start()
-    # the engine placed its own copy; under --tp the tree that was
-    # initialized or restored whole on device 0 would otherwise stay
-    # resident there for the life of the process
-    del params, draft_params
+        brownout = None
+        if args.brownout_high > 0:
+            from pytorch_distributed_training_tpu.serve.queue import (
+                BrownoutController,
+            )
+
+            brownout = BrownoutController(
+                high_watermark=args.brownout_high,
+                low_watermark=args.brownout_low,
+                escalate_hold_s=args.brownout_escalate_hold_s,
+                deescalate_hold_s=args.brownout_deescalate_hold_s,
+                clamp_max_new=args.brownout_clamp,
+                registry=registry,
+                slo_monitor=slo if args.slo_burn_high > 0 else None,
+                slo_burn_high=args.slo_burn_high,
+            )
+        tier_deadlines = {}
+        if args.interactive_deadline_s > 0:
+            tier_deadlines["interactive"] = args.interactive_deadline_s
+        if args.batch_deadline_s > 0:
+            tier_deadlines["batch"] = args.batch_deadline_s
+
+        server = InferenceServer(
+            model, params, config,
+            queue_depth=args.queue_depth,
+            default_deadline_s=args.deadline_s or None,
+            tier_deadlines=tier_deadlines or None,
+            brownout=brownout,
+            registry=registry,
+            guards=GuardSet(mode=guard_mode, registry=registry),
+            stall_timeout_s=args.stall_timeout_s,
+            weights_step=boot_step,
+            draft_model=draft_model,
+            draft_params=draft_params,
+            slo=slo,
+            replica_name=args.replica_name,
+        ).start()
+        # the engine placed its own copy; under --tp the tree that was
+        # initialized or restored whole on device 0 would otherwise stay
+        # resident there for the life of the process
+        del params, draft_params
 
     lock_summary = None
     if args.lock_summary_s > 0:

@@ -1,7 +1,7 @@
 """Pallas (Mosaic) fused LayerNorm for TPU — fwd + custom-VJP bwd.
 
-Why this kernel exists: on the bert-large MRPC recipe the xprof trace
-(scripts/trace_step.py) shows XLA lowering every ``nn.LayerNorm`` to kLoop
+Why this kernel exists: on the bert-large MRPC recipe a profiler trace of
+the train step shows XLA lowering every ``nn.LayerNorm`` to kLoop
 reduce fusions costing ~0.2 ms per execution — ~37 ms of a ~167 ms step
 across the 49 norms/microbatch (fwd ``convert_reduce_fusion`` ~19 ms + bwd
 ``multiply_reduce_fusion`` ~18 ms), an order of magnitude above the HBM

@@ -134,9 +134,17 @@ from pytorch_distributed_training_tpu.serve.sampling import (
     device_sample,
     spec_accept,
 )
+from pytorch_distributed_training_tpu.telemetry.spans import (
+    Phase,
+    Tracer,
+    setup_phase,
+)
 from pytorch_distributed_training_tpu.utils.logging import get_logger
 
 logger = get_logger(__name__)
+
+#: prefix of the tick's phase names; records and flight entries drop it
+_TICK = "serve_tick."
 
 
 @dataclasses.dataclass
@@ -425,7 +433,17 @@ class DecodeEngine:
     loop thread (serve/server.py); construction may happen anywhere.
     """
 
-    def __init__(
+    def __init__(self, model, params, config: EngineConfig,
+                 queue: RequestQueue, *, registry=None, **parts):
+        """``parts``: the keyword arguments of ``_build``."""
+        with setup_phase("serve_setup.engine", registry=registry):
+            self._build(model, params, config, queue, registry=registry,
+                        **parts)
+        if config.warmup:
+            with setup_phase("serve_setup.warmup", registry=registry):
+                self._warmup()
+
+    def _build(
         self,
         model,
         params,
@@ -443,6 +461,8 @@ class DecodeEngine:
         slo=None,
         replica_name: Optional[str] = None,
     ):
+        """Pools, placement, guards and the observability plane: all of
+        construction but the warm-up compiles."""
         cfg = model.config
         if not cfg.causal:
             raise ValueError("DecodeEngine needs a causal model")
@@ -754,10 +774,6 @@ class DecodeEngine:
         # adds counters, not emits.
         self.replica_name = replica_name
         if tracer is None:
-            from pytorch_distributed_training_tpu.telemetry.spans import (
-                Tracer,
-            )
-
             tracer = Tracer(registry=registry, component=replica_name or "engine")
         self.tracer = tracer
         if flight is None:
@@ -784,8 +800,13 @@ class DecodeEngine:
         # recorder entry (swap applied/committed/rollback, brownout moves)
         self._tick_events: list = []
         self._prev_brownout_level = 0
-        if config.warmup:
-            self._warmup()
+        # the current tick's closed phases, in closing order (engine thread)
+        self._tick_phases: list = []
+
+    def _phase(self, name: str, rid: Optional[str] = None) -> Phase:
+        """One phase of the current tick, ``serve_tick.<name>``: closed, it
+        joins ``_tick_phases``. ``rid`` names the request it served."""
+        return Phase(_TICK + name, self._tick_phases.append, ident=rid)
 
     # -------------------------------------------------------------- compiled
 
@@ -1309,102 +1330,117 @@ class DecodeEngine:
         block tables); dense warm-up prefills slot 0 and decodes with
         every slot inactive — both leave no state a real admit would see.
         Also the precondition for strict tick-wide transfer scoping: after
-        warm-up, ``_scope_ready()`` holds from the first real tick."""
+        warm-up, ``_scope_ready()`` holds from the first real tick.
+        One ``serve_setup.warmup.<program>`` phase per compiled program
+        (compiles are synchronous at dispatch) and ``.drain`` for the null
+        executions."""
         cfg = self.config
         paged = self._pages is not None
         W = cfg.pages_per_slot
         draft = self._draft_model is not None
         outs = []
+
+        def warm(program: str):
+            return setup_phase(
+                "serve_setup.warmup." + program, registry=self._registry)
+
         if paged and cfg.prefill_chunk > 0:
             # ONE chunk program replaces the whole per-bucket prefill set
-            outs.append(self._warm_chunk(draft))
+            with warm("chunk"):
+                outs.append(self._warm_chunk(draft))
         else:
             for bucket in cfg.prompt_buckets:
-                if paged:
-                    ops = self._put((
-                        np.zeros((1, bucket), np.int32),
-                        np.int32(1),
-                        np.zeros((1, W), np.int32),
-                        np.int32(0), np.float32(0.0), np.int32(0),
-                    ))
-                else:
-                    ops = self._put((
-                        np.int32(0),
-                        np.zeros((1, bucket), np.int32),
-                        np.int32(1),
-                        np.int32(0), np.float32(0.0), np.int32(0),
-                    ))
-                out, self._cache = self._prefill_fn(bucket)(
+                with warm(f"prefill_{bucket}"):
+                    if paged:
+                        ops = self._put((
+                            np.zeros((1, bucket), np.int32),
+                            np.int32(1),
+                            np.zeros((1, W), np.int32),
+                            np.int32(0), np.float32(0.0), np.int32(0),
+                        ))
+                    else:
+                        ops = self._put((
+                            np.int32(0),
+                            np.zeros((1, bucket), np.int32),
+                            np.int32(1),
+                            np.int32(0), np.float32(0.0), np.int32(0),
+                        ))
+                    out, self._cache = self._prefill_fn(bucket)(
+                        self._params, self._cache, *ops
+                    )
+                    outs.append(out)
+                    if draft:
+                        dops = self._put((
+                            np.zeros((1, bucket), np.int32),
+                            np.zeros((1, W), np.int32),
+                        ))
+                        self._draft_cache = self._draft_prefill_fn(bucket)(
+                            self._draft_params, self._draft_cache, *dops
+                        )
+        if paged and cfg.prefix_cache:
+            if cfg.prefill_chunk == 0:
+                # cold prefills stay monolithic, but cache-hit TAILS stream
+                # through the chunk program — warm it too
+                with warm("chunk"):
+                    outs.append(self._warm_chunk(draft))
+            # COW copy program: a null-page self-copy leaves no state
+            with warm("copy"):
+                pg = self._put((np.int32(0), np.int32(0)))
+                self._cache = self._copy_fn()(self._cache, *pg)
+                if draft:
+                    self._draft_cache = self._draft_copy_fn()(
+                        self._draft_cache, *pg
+                    )
+        S = cfg.num_slots
+        if paged and cfg.spec_k > 0:
+            # verify replaces the single-token decode step entirely
+            with warm("verify"):
+                ops = self._put((
+                    np.zeros((S, cfg.spec_k + 1), np.int32),
+                    np.zeros((S, W), np.int32),
+                    np.zeros((S,), np.int32),
+                    np.zeros((S,), np.int32), np.zeros((S,), np.int32),
+                    np.zeros((S,), np.float32), np.zeros((S,), np.int32),
+                ))
+                out, self._cache = self._verify_fn()(
                     self._params, self._cache, *ops
                 )
                 outs.append(out)
                 if draft:
                     dops = self._put((
-                        np.zeros((1, bucket), np.int32),
-                        np.zeros((1, W), np.int32),
+                        np.zeros((S,), np.int32),
+                        np.zeros((S, W), np.int32),
+                        np.zeros((S,), np.int32),
                     ))
-                    self._draft_cache = self._draft_prefill_fn(bucket)(
+                    dout, self._draft_cache = self._draft_decode_fn()(
                         self._draft_params, self._draft_cache, *dops
                     )
-        if paged and cfg.prefix_cache:
-            if cfg.prefill_chunk == 0:
-                # cold prefills stay monolithic, but cache-hit TAILS stream
-                # through the chunk program — warm it too
-                outs.append(self._warm_chunk(draft))
-            # COW copy program: a null-page self-copy leaves no state
-            pg = self._put((np.int32(0), np.int32(0)))
-            self._cache = self._copy_fn()(self._cache, *pg)
-            if draft:
-                self._draft_cache = self._draft_copy_fn()(
-                    self._draft_cache, *pg
-                )
-        S = cfg.num_slots
-        if paged and cfg.spec_k > 0:
-            # verify replaces the single-token decode step entirely
-            ops = self._put((
-                np.zeros((S, cfg.spec_k + 1), np.int32),
-                np.zeros((S, W), np.int32),
-                np.zeros((S,), np.int32),
-                np.zeros((S,), np.int32), np.zeros((S,), np.int32),
-                np.zeros((S,), np.float32), np.zeros((S,), np.int32),
-            ))
-            out, self._cache = self._verify_fn()(
-                self._params, self._cache, *ops
-            )
-            outs.append(out)
-            if draft:
-                dops = self._put((
-                    np.zeros((S,), np.int32),
-                    np.zeros((S, W), np.int32),
-                    np.zeros((S,), np.int32),
-                ))
-                dout, self._draft_cache = self._draft_decode_fn()(
-                    self._draft_params, self._draft_cache, *dops
-                )
-                outs.append(dout)
+                    outs.append(dout)
         else:
-            if paged:
-                ops = self._put((
-                    np.zeros((S,), np.int32),
-                    np.zeros((S, W), np.int32),
-                    np.zeros((S,), np.int32),
-                    np.zeros((S,), np.int32), np.zeros((S,), np.int32),
-                    np.zeros((S,), np.float32), np.zeros((S,), np.int32),
-                ))
-            else:
-                ops = self._put((
-                    np.zeros((S,), np.int32),
-                    np.zeros((S,), bool),
-                    np.zeros((S,), np.int32), np.zeros((S,), np.int32),
-                    np.zeros((S,), np.float32), np.zeros((S,), np.int32),
-                ))
-            out, self._cache = self._decode_step_fn()(
-                self._params, self._cache, *ops
-            )
-            outs.append(out)
+            with warm("decode"):
+                if paged:
+                    ops = self._put((
+                        np.zeros((S,), np.int32),
+                        np.zeros((S, W), np.int32),
+                        np.zeros((S,), np.int32),
+                        np.zeros((S,), np.int32), np.zeros((S,), np.int32),
+                        np.zeros((S,), np.float32), np.zeros((S,), np.int32),
+                    ))
+                else:
+                    ops = self._put((
+                        np.zeros((S,), np.int32),
+                        np.zeros((S,), bool),
+                        np.zeros((S,), np.int32), np.zeros((S,), np.int32),
+                        np.zeros((S,), np.float32), np.zeros((S,), np.int32),
+                    ))
+                out, self._cache = self._decode_step_fn()(
+                    self._params, self._cache, *ops
+                )
+                outs.append(out)
         # ONE sync for the whole warm-up batch (compiles are synchronous at
         # dispatch; this only drains the null executions)
-        jax.block_until_ready(outs)
+        with warm("drain"):
+            jax.block_until_ready(outs)
 
     def _scope_ready(self) -> bool:
         """True when the whole tick can run under the strict transfer
@@ -1715,7 +1751,8 @@ class DecodeEngine:
             prefill_end = first if first is not None else req.finish_t
             p = tr.begin(
                 trace, "prefill", parent=serve.span, t0=admit,
-                attrs={"bucket": req.bucket, "chunks": req.chunks},
+                attrs={"bucket": req.bucket, "chunks": req.chunks,
+                       "tick": req.admit_tick},
             )
             if req.reserve_t is not None:
                 attrs = {"pages": self._pages_for(req)
@@ -1969,14 +2006,7 @@ class DecodeEngine:
         loop stream the prompt in ``prefill_chunk`` tokens at a time (the
         first dispatch happens on the SAME tick via ``_advance_prefills``
         order — admission itself is pure bookkeeping)."""
-        req.status = "running"
-        req.admit_t = time.monotonic()
-        self.admitted += 1
-        self._registry.inc("serve/admitted")
-        n = self._pages_for(req)
-        self._pages.admit(slot, n)
-        self._charge_tenant(slot, req.tenant, n)
-        req.reserve_t = time.monotonic()
+        self._reserve(req, slot, self._pages_for(req))
         self._slots[slot] = _Slot(
             request=req, pending_token=-1, phase="prefill",
             prefill_pos=0, spec=self._slot_spec(req),
@@ -1991,10 +2021,7 @@ class DecodeEngine:
         program. Reservation draws only ``reserved - full`` pages from the
         free list; the request's worst case is still fully covered, so
         ``page_exhausted`` can never fire mid-flight."""
-        req.status = "running"
-        req.admit_t = time.monotonic()
-        self.admitted += 1
-        self._registry.inc("serve/admitted")
+        self._mark_admitted(req)
         reserved = self._pages_for(req)
         shared = list(match.pages)
         cow = match.cow_src is not None
@@ -2011,7 +2038,8 @@ class DecodeEngine:
                 # context_len and overwritten by the tail prefill.
                 old, new = self._pages.cow(slot, len(match.pages))
                 ops = self._put((np.int32(old), np.int32(new)))
-                with watchdog_guard("serve_prefill"):
+                with watchdog_guard("serve_prefill"), \
+                        self._phase("dispatch", req.id):
                     self._cache = self._copy_fn()(self._cache, *ops)
                     if self._draft_model is not None:
                         self._draft_cache = self._draft_copy_fn()(
@@ -2047,82 +2075,98 @@ class DecodeEngine:
             self._pages.slot_pages(slot)[:full],
         )
 
-    def _admit(self, req: GenRequest, slot: int) -> None:
-        """Prefill ``req`` into ``slot`` and take its first token."""
+    def _mark_admitted(self, req: GenRequest) -> None:
         req.status = "running"
         req.admit_t = time.monotonic()
+        # the tick that admitted it: the request's ``prefill`` span names
+        # it, as that tick's ``prefill`` phase names the request
+        req.admit_tick = self.ticks + 1
         self.admitted += 1
         self._registry.inc("serve/admitted")
-        bucket = req.bucket
-        padded = np.zeros((1, bucket), np.int32)
-        padded[0, : req.prompt_len] = req.prompt_ids
-        paged = self._pages is not None
-        if paged:
-            n = self._pages_for(req)
-            self._pages.admit(slot, n)
-            self._charge_tenant(slot, req.tenant, n)
+
+    def _reserve(self, req: GenRequest, slot: int, pages: int) -> None:
+        """The bookkeeping of an admission: the request is running, its
+        worst-case pages are the slot's and charged to its tenant."""
+        self._mark_admitted(req)
+        if self._pages is not None:
+            self._pages.admit(slot, pages)
+            self._charge_tenant(slot, req.tenant, pages)
             req.reserve_t = time.monotonic()
-        try:
-            # ONE explicit H2D for all host-built operands (np → device);
-            # under the strict tick-wide transfer scope, explicit
-            # device_put/device_get are the only transfers a tick makes
-            sample_ops = (
-                np.int32(req.seed),
-                np.float32(req.temperature),
-                np.int32(min(req.top_k, np.iinfo(np.int32).max)),
-            )
-            if paged:
-                ops = self._put((
-                    padded,
-                    np.int32(req.prompt_len),
-                    self._pages.block_table[slot : slot + 1],
-                ) + sample_ops)
-            else:
-                ops = self._put((
-                    np.int32(slot),
-                    padded,
-                    np.int32(req.prompt_len),
-                ) + sample_ops)
-            with watchdog_guard("serve_prefill"):
-                out, self._cache = self._prefill_fn(bucket)(
-                    self._params, self._cache, *ops
+
+    def _admit(self, req: GenRequest, slot: int) -> None:
+        """Prefill ``req`` into ``slot`` (reserved) and take its first
+        token: one ``prefill`` phase, the wait for the token inside it."""
+        with self._phase("prefill", req.id) as phase:
+            bucket = req.bucket
+            phase.attrs = {"bucket": bucket, "prompt_len": req.prompt_len}
+            padded = np.zeros((1, bucket), np.int32)
+            padded[0, : req.prompt_len] = req.prompt_ids
+            paged = self._pages is not None
+            try:
+                # ONE explicit H2D for all host-built operands (np →
+                # device); under the strict tick-wide transfer scope,
+                # explicit device_put/device_get are the only transfers a
+                # tick makes
+                sample_ops = (
+                    np.int32(req.seed),
+                    np.float32(req.temperature),
+                    np.int32(min(req.top_k, np.iinfo(np.int32).max)),
                 )
-                if paged and self._draft_model is not None:
-                    # mirror the prompt into the draft pools (same block-
-                    # table row, draft-side K/V) so the draft lane shares
-                    # the slot's committed context from its first tick
-                    dops = self._put((
+                if paged:
+                    ops = self._put((
                         padded,
+                        np.int32(req.prompt_len),
                         self._pages.block_table[slot : slot + 1],
-                    ))
-                    self._draft_cache = self._draft_prefill_fn(bucket)(
-                        self._draft_params, self._draft_cache, *dops
+                    ) + sample_ops)
+                else:
+                    ops = self._put((
+                        np.int32(slot),
+                        padded,
+                        np.int32(req.prompt_len),
+                    ) + sample_ops)
+                with watchdog_guard("serve_prefill"):
+                    out, self._cache = self._prefill_fn(bucket)(
+                        self._params, self._cache, *ops
                     )
-                # explicit d2h (np.asarray would be an implicit transfer —
-                # the exact pattern the transfer guard disallows on chips)
-                fetched = jax.device_get(out)
-        except BaseException:
-            # failed admissions must not leak the pages just reserved
+                    if paged and self._draft_model is not None:
+                        # mirror the prompt into the draft pools (same
+                        # block-table row, draft-side K/V) so the draft
+                        # lane shares the slot's committed context from its
+                        # first tick
+                        dops = self._put((
+                            padded,
+                            self._pages.block_table[slot : slot + 1],
+                        ))
+                        self._draft_cache = self._draft_prefill_fn(bucket)(
+                            self._draft_params, self._draft_cache, *dops
+                        )
+                    # explicit d2h (np.asarray would be an implicit
+                    # transfer — the exact pattern the transfer guard
+                    # disallows on chips)
+                    with self._phase("prefill_wait", req.id):
+                        fetched = jax.device_get(out)
+            except BaseException:
+                # failed admissions must not leak the pages just reserved
+                if paged:
+                    self._release_pages(slot)
+                raise
+            self.prefill_tokens += req.prompt_len
             if paged:
-                self._release_pages(slot)
-            raise
-        self.prefill_tokens += req.prompt_len
-        if paged:
-            # index the prompt's full pages BEFORE any release below: the
-            # cache's own reference keeps them alive past the slot
-            self._insert_prefix(slot, req)
-        if self.config.sampling == "device":
-            token = int(fetched)
-        else:
-            token = self._sample(req, fetched)
-        self._emit_token(req, token)
-        if self._is_terminal(req, token):
-            if paged:
-                self._release_pages(slot)
-            return
-        self._slots[slot] = _Slot(
-            request=req, pending_token=token, spec=self._slot_spec(req)
-        )
+                # index the prompt's full pages BEFORE any release below:
+                # the cache's own reference keeps them alive past the slot
+                self._insert_prefix(slot, req)
+            if self.config.sampling == "device":
+                token = int(fetched)
+            else:
+                token = self._sample(req, fetched)
+            self._emit_token(req, token)
+            if self._is_terminal(req, token):
+                if paged:
+                    self._release_pages(slot)
+                return
+            self._slots[slot] = _Slot(
+                request=req, pending_token=token, spec=self._slot_spec(req)
+            )
 
     def _is_terminal(self, req: GenRequest, token: int) -> bool:
         """Finish ``req`` if ``token`` completed it; True when finished."""
@@ -2161,16 +2205,17 @@ class DecodeEngine:
                 np.int32(req.prompt_len - 1 - start) if is_last
                 else np.int32(0)
             )
-            ops = self._put((
-                ids,
-                np.asarray([start], np.int32),
-                sample_idx,
-                self._pages.block_table[i : i + 1],
-                np.int32(req.seed),
-                np.float32(req.temperature),
-                np.int32(min(req.top_k, np.iinfo(np.int32).max)),
-            ))
-            with watchdog_guard("serve_prefill"):
+            with watchdog_guard("serve_prefill"), \
+                    self._phase("prefill", req.id):
+                ops = self._put((
+                    ids,
+                    np.asarray([start], np.int32),
+                    sample_idx,
+                    self._pages.block_table[i : i + 1],
+                    np.int32(req.seed),
+                    np.float32(req.temperature),
+                    np.int32(min(req.top_k, np.iinfo(np.int32).max)),
+                ))
                 out, self._cache = self._chunk_fn()(
                     self._params, self._cache, *ops
                 )
@@ -2183,7 +2228,10 @@ class DecodeEngine:
                     self._draft_cache = self._draft_chunk_fn()(
                         self._draft_params, self._draft_cache, *dops
                     )
-                fetched = jax.device_get(out) if is_last else None
+                fetched = None
+                if is_last:
+                    with self._phase("prefill_wait", req.id):
+                        fetched = jax.device_get(out)
             self.prefill_chunks += 1
             req.chunks += 1
             chunks += 1
@@ -2246,22 +2294,23 @@ class DecodeEngine:
         a fully-accepted previous tick never fed the draft — then the
         pending token and each proposal feed forward. Only spec slots get
         real block-table rows; everyone else parks on the null page."""
-        cfg = self.config
-        S, k = cfg.num_slots, cfg.spec_k
-        drafts = np.zeros((S, k), np.int32)
-        toks = np.zeros((S,), np.int32)
-        ctx = np.zeros((S,), np.int32)
-        bt = np.zeros_like(self._pages.block_table)
-        for i in spec_slots:
-            s = self._slots[i]
-            toks[i] = self._last_committed_token(s)
-            ctx[i] = s.request.prompt_len + s.steps_done - 1
-            bt[i] = self._pages.block_table[i]
-        pending = np.zeros((S,), np.int32)
-        inc = np.zeros((S,), np.int32)
-        for i in spec_slots:
-            pending[i] = self._slots[i].pending_token
-            inc[i] = 1
+        with self._phase("operands"):
+            cfg = self.config
+            S, k = cfg.num_slots, cfg.spec_k
+            drafts = np.zeros((S, k), np.int32)
+            toks = np.zeros((S,), np.int32)
+            ctx = np.zeros((S,), np.int32)
+            bt = np.zeros_like(self._pages.block_table)
+            for i in spec_slots:
+                s = self._slots[i]
+                toks[i] = self._last_committed_token(s)
+                ctx[i] = s.request.prompt_len + s.steps_done - 1
+                bt[i] = self._pages.block_table[i]
+            pending = np.zeros((S,), np.int32)
+            inc = np.zeros((S,), np.int32)
+            for i in spec_slots:
+                pending[i] = self._slots[i].pending_token
+                inc[i] = 1
         fn = self._draft_decode_fn()
         outs = []
         with watchdog_guard("serve_decode"):
@@ -2270,17 +2319,19 @@ class DecodeEngine:
             # the loop), and the k proposals come back in ONE device_get.
             # Dispatch 0's output is discarded — it only resyncs the
             # draft cache at ctx-1; dispatch 1 feeds the pending token.
-            bt_d = self._put(bt)
-            feed = self._put(toks)
-            for j in range(k + 1):
-                out, self._draft_cache = fn(
-                    self._draft_params, self._draft_cache, feed,
-                    bt_d, self._put(ctx),
-                )
-                outs.append(out)
-                feed = self._put(pending) if j == 0 else out
-                ctx = ctx + inc
-            proposals = np.stack(jax.device_get(outs[1:]), axis=1)
+            with self._phase("dispatch"):
+                bt_d = self._put(bt)
+                feed = self._put(toks)
+                for j in range(k + 1):
+                    out, self._draft_cache = fn(
+                        self._draft_params, self._draft_cache, feed,
+                        bt_d, self._put(ctx),
+                    )
+                    outs.append(out)
+                    feed = self._put(pending) if j == 0 else out
+                    ctx = ctx + inc
+            with self._phase("decode_wait"):
+                proposals = np.stack(jax.device_get(outs[1:]), axis=1)
         for i in spec_slots:
             drafts[i] = proposals[i]
         return drafts
@@ -2303,80 +2354,87 @@ class DecodeEngine:
         Q = k + 1
         spec_slots = [i for i in active if self._slots[i].spec]
         if self._draft_model is not None and spec_slots:
+            # the draft lane has a dispatch and a wait of its own
             drafts = self._model_drafts(spec_slots)
         else:
-            drafts = np.zeros((S, k), np.int32)
-            for i in spec_slots:
+            with self._phase("operands"):
+                drafts = np.zeros((S, k), np.int32)
+                for i in spec_slots:
+                    s = self._slots[i]
+                    r = s.request
+                    hist = [int(t) for t in r.prompt_ids[: r.prompt_len]]
+                    hist.extend(int(t) for t in r.tokens)
+                    drafts[i] = self._ngram_draft(hist, k)
+        with self._phase("operands"):
+            tokens = np.zeros((S, Q), np.int32)
+            ctx = np.zeros((S,), np.int32)
+            seeds = np.zeros((S,), np.int32)
+            steps0 = np.zeros((S,), np.int32)
+            temps = np.zeros((S,), np.float32)
+            top_ks = np.zeros((S,), np.int32)
+            # sanitized block table: mid-prefill slots hold REAL pages but
+            # are not in this dispatch — their rows must read as the null
+            # page or the verify scatter would stomp their streamed prompt
+            # K/V
+            bt = np.zeros_like(self._pages.block_table)
+            for i in active:
                 s = self._slots[i]
                 r = s.request
-                hist = [int(t) for t in r.prompt_ids[: r.prompt_len]]
-                hist.extend(int(t) for t in r.tokens)
-                drafts[i] = self._ngram_draft(hist, k)
-        tokens = np.zeros((S, Q), np.int32)
-        ctx = np.zeros((S,), np.int32)
-        seeds = np.zeros((S,), np.int32)
-        steps0 = np.zeros((S,), np.int32)
-        temps = np.zeros((S,), np.float32)
-        top_ks = np.zeros((S,), np.int32)
-        # sanitized block table: mid-prefill slots hold REAL pages but are
-        # not in this dispatch — their rows must read as the null page or
-        # the verify scatter would stomp their streamed prompt K/V
-        bt = np.zeros_like(self._pages.block_table)
-        for i in active:
-            s = self._slots[i]
-            r = s.request
-            tokens[i, 0] = s.pending_token
-            tokens[i, 1:] = drafts[i] if s.spec else s.pending_token
-            ctx[i] = r.prompt_len + s.steps_done
-            seeds[i] = np.int32(r.seed)
-            steps0[i] = s.steps_done + 1   # == len(r.tokens) at sample
-            temps[i] = r.temperature
-            top_ks[i] = min(r.top_k, np.iinfo(np.int32).max)
-            bt[i] = self._pages.block_table[i]
-        ops = self._put(
-            (tokens, bt, ctx, seeds, steps0, temps, top_ks)
-        )
+                tokens[i, 0] = s.pending_token
+                tokens[i, 1:] = drafts[i] if s.spec else s.pending_token
+                ctx[i] = r.prompt_len + s.steps_done
+                seeds[i] = np.int32(r.seed)
+                steps0[i] = s.steps_done + 1   # == len(r.tokens) at sample
+                temps[i] = r.temperature
+                top_ks[i] = min(r.top_k, np.iinfo(np.int32).max)
+                bt[i] = self._pages.block_table[i]
+            ops = self._put(
+                (tokens, bt, ctx, seeds, steps0, temps, top_ks)
+            )
         with watchdog_guard("serve_decode"):
-            out, self._cache = self._verify_fn()(
-                self._params, self._cache, *ops
-            )
+            with self._phase("dispatch"):
+                out, self._cache = self._verify_fn()(
+                    self._params, self._cache, *ops
+                )
             # the tick's D2H: per-position stream samples + accept counts
-            target, accept = jax.device_get(out)
-        self.spec_dispatches += 1
-        self.decode_dispatches += 1
-        emitted = 0
-        accepted = 0
-        for i in active:
-            s = self._slots[i]
-            r = s.request
-            a = int(accept[i]) if s.spec else 0
-            r.decode_ticks += 1
-            if s.spec:
-                self.spec_drafted += k
-                self.spec_accepted += a
-                r.drafted += k
-                r.accepted += a
-                accepted += a
-            finished = False
-            for j in range(a + 1):
-                token = int(target[i, j])
-                s.steps_done += 1
-                self._emit_token(r, token)
-                emitted += 1
-                if self._is_terminal(r, token):
-                    self._evict(i)
-                    finished = True
-                    break
-            if not finished:
-                s.pending_token = int(target[i, a])
-        self.decode_tokens += emitted
-        if spec_slots:
+            with self._phase("decode_wait"):
+                target, accept = jax.device_get(out)
+        with self._phase("emit"):
+            self.spec_dispatches += 1
+            self.decode_dispatches += 1
+            emitted = 0
+            accepted = 0
+            for i in active:
+                s = self._slots[i]
+                r = s.request
+                a = int(accept[i]) if s.spec else 0
+                r.decode_ticks += 1
+                if s.spec:
+                    self.spec_drafted += k
+                    self.spec_accepted += a
+                    r.drafted += k
+                    r.accepted += a
+                    accepted += a
+                finished = False
+                for j in range(a + 1):
+                    token = int(target[i, j])
+                    s.steps_done += 1
+                    self._emit_token(r, token)
+                    emitted += 1
+                    if self._is_terminal(r, token):
+                        self._evict(i)
+                        finished = True
+                        break
+                if not finished:
+                    s.pending_token = int(target[i, a])
+            self.decode_tokens += emitted
+            if spec_slots:
+                self._registry.gauge(
+                    "serve/spec_accept_rate", accepted / (k * len(spec_slots))
+                )
             self._registry.gauge(
-                "serve/spec_accept_rate", accepted / (k * len(spec_slots))
+                "serve/tokens_per_dispatch", emitted / len(active)
             )
-        self._registry.gauge(
-            "serve/tokens_per_dispatch", emitted / len(active)
-        )
 
     # ------------------------------------------------------------------ tick
 
@@ -2434,22 +2492,56 @@ class DecodeEngine:
         return worked
 
     def _tick_body(self) -> bool:
-        t0 = time.monotonic()
+        """One tick as a ``serve_tick`` phase whose children (``expire``,
+        ``admit`` and ``prefill`` per admission, ``chunks``, ``operands``,
+        ``dispatch``, ``decode_wait``, ``emit``, ``publish``) tile it.
+        Every program a tick puts on the device sits inside a ``prefill``
+        (to the end of its ``prefill_wait``) or between a ``dispatch`` and
+        the end of the ``decode_wait`` after it: the rest is the host's.
+        A busy tick is written out once, after its last phase, as ONE
+        ``serve_tick`` record — where a sink is attached."""
+        self._tick_phases = []
+        with Phase("serve_tick", ident=self.ticks + 1) as tick:
+            worked = self._run_tick(tick)
+        if worked and self._registry.sink is not None:
+            phases = sorted(self._tick_phases, key=lambda p: p.t0)
+            self._registry.emit({
+                "record": "serve_tick",
+                "component": self.replica_name or "engine",
+                "tick": tick.ident,
+                "busy_tick": self.busy_ticks,
+                "t0_s": tick.t0,
+                "t1_s": tick.t1,
+                **tick.attrs,
+                # (name, start, end, request id or null[, attributes])
+                "phases": [
+                    [p.name[len(_TICK):], p.t0, p.t1, p.ident]
+                    + ([p.attrs] if p.attrs else [])
+                    for p in phases
+                ],
+            })
+        return worked
+
+    def _run_tick(self, tick: Phase) -> bool:
+        t0 = tick.t0
         worked = False
+        admitted0, prefill_tokens0 = self.admitted, self.prefill_tokens
 
-        for req in self._queue.expire_overdue():
-            emit_expiry(self._registry, req, "queued")
-            self._finish(req, "expired", "deadline")
-            worked = True
-
-        # running-slot deadlines: stop spending decode on an abandoned answer
-        now = time.monotonic()
-        for i, s in enumerate(self._slots):
-            if s is not None and s.request.overdue(now):
-                self._evict(i)
-                emit_expiry(self._registry, s.request, "running")
-                self._finish(s.request, "expired", "deadline")
+        with self._phase("expire"):
+            for req in self._queue.expire_overdue():
+                emit_expiry(self._registry, req, "queued")
+                self._finish(req, "expired", "deadline")
                 worked = True
+
+            # running-slot deadlines: stop spending decode on an abandoned
+            # answer
+            now = time.monotonic()
+            for i, s in enumerate(self._slots):
+                if s is not None and s.request.overdue(now):
+                    self._evict(i)
+                    emit_expiry(self._registry, s.request, "running")
+                    self._finish(s.request, "expired", "deadline")
+                    worked = True
 
         # admissions: fill free slots in scheduler order; under the paged
         # layout the FIFO head must also fit the page budget (a blocked
@@ -2463,36 +2555,50 @@ class DecodeEngine:
         chunked = self._pages is not None and self.config.prefill_chunk > 0
         streaming = chunked or self._prefix is not None
         while True:
-            slot = self._free_slot()
-            if slot is None:
-                break
-            # the residency hold only guards CHUNKED engines (long prompts
-            # streaming in over many ticks); a prefix-only engine's hit
-            # tails span at most two chunks, so holding admissions behind
-            # them would just serialize the queue
-            req = self._queue.pop_ready(
-                accept=self._admission_fits,
-                defer=self._admission_defer if chunked else None,
-            )
-            if req is None:
-                break
+            req = None
             try:
-                match = self._take_match(req)
-                if match is not None:
-                    self._prefix.note(match.hit)
-                if match is not None and match.hit:
-                    self._admit_hit(req, slot, match)
-                elif chunked:
-                    self._admit_chunked(req, slot)
-                else:
+                # one ``admit`` phase per pass, up to the prefill (the last
+                # pass finds no slot or no request)
+                with self._phase("admit"):
+                    slot = self._free_slot()
+                    if slot is not None:
+                        # the residency hold only guards CHUNKED engines
+                        # (long prompts streaming in over many ticks); a
+                        # prefix-only engine's hit tails span at most two
+                        # chunks, so holding admissions behind them would
+                        # just serialize the queue
+                        req = self._queue.pop_ready(
+                            accept=self._admission_fits,
+                            defer=self._admission_defer if chunked else None,
+                        )
+                    if req is None:
+                        break
+                    match = self._take_match(req)
+                    if match is not None:
+                        self._prefix.note(match.hit)
+                    monolithic = False
+                    if match is not None and match.hit:
+                        self._admit_hit(req, slot, match)
+                    elif chunked:
+                        self._admit_chunked(req, slot)
+                    else:
+                        self._reserve(
+                            req, slot,
+                            self._pages_for(req)
+                            if self._pages is not None else 0,
+                        )
+                        monolithic = True
+                if monolithic:
                     self._admit(req, slot)
             except Exception:
-                # the request is already popped and not yet slotted: an
-                # admission failure (guard violation, wedged prefill, OOM)
-                # must not orphan it — its waiter would hang forever while
-                # the loop's failure path cancels only queued+slotted work
-                self._registry.inc("serve/admit_failures")
-                self._finish(req, "error", "admit_failure")
+                if req is not None:
+                    # the request is already popped and not yet slotted: an
+                    # admission failure (guard violation, wedged prefill,
+                    # OOM) must not orphan it — its waiter would hang
+                    # forever while the loop's failure path cancels only
+                    # queued+slotted work
+                    self._registry.inc("serve/admit_failures")
+                    self._finish(req, "error", "admit_failure")
                 raise
             worked = True
         if self._page_blocked:
@@ -2503,162 +2609,196 @@ class DecodeEngine:
         # just-admitted slot gets its first chunk this very tick) and
         # BEFORE decode (its pages must be committed before the verify
         # scatter could reach them)
-        if streaming:
-            worked = self._advance_prefills() or worked
-
-        active = [
-            i for i, s in enumerate(self._slots)
-            if s is not None and s.phase == "decode"
-        ]
+        with self._phase("chunks"):
+            if streaming:
+                worked = self._advance_prefills() or worked
+            active = [
+                i for i, s in enumerate(self._slots)
+                if s is not None and s.phase == "decode"
+            ]
         if active and self._pages is not None and self.config.spec_k > 0:
             self._verify_tick(active)
             worked = True
         elif active:
-            S = self.config.num_slots
-            tokens = np.zeros((S,), np.int32)
-            mask = np.zeros((S,), bool)
-            ctx = np.zeros((S,), np.int32)
-            seeds = np.zeros((S,), np.int32)
-            steps = np.zeros((S,), np.int32)
-            temps = np.zeros((S,), np.float32)
-            top_ks = np.zeros((S,), np.int32)
-            for i in active:
-                s = self._slots[i]
-                r = s.request
-                tokens[i] = s.pending_token
-                mask[i] = True
-                ctx[i] = r.prompt_len + s.steps_done
-                seeds[i] = np.int32(r.seed)
-                steps[i] = s.steps_done + 1   # == len(r.tokens) at sample
-                temps[i] = r.temperature
-                top_ks[i] = min(r.top_k, np.iinfo(np.int32).max)
-            sample_ops = (seeds, steps, temps, top_ks)
-            if self._pages is not None:
-                if streaming:
-                    # mid-prefill slots hold real pages but are not in
-                    # this dispatch — null their rows so the decode
-                    # scatter can't stomp a streaming prompt's K/V
-                    bt = np.zeros_like(self._pages.block_table)
-                    for i in active:
-                        bt[i] = self._pages.block_table[i]
+            with self._phase("operands"):
+                S = self.config.num_slots
+                tokens = np.zeros((S,), np.int32)
+                mask = np.zeros((S,), bool)
+                ctx = np.zeros((S,), np.int32)
+                seeds = np.zeros((S,), np.int32)
+                steps = np.zeros((S,), np.int32)
+                temps = np.zeros((S,), np.float32)
+                top_ks = np.zeros((S,), np.int32)
+                for i in active:
+                    s = self._slots[i]
+                    r = s.request
+                    tokens[i] = s.pending_token
+                    mask[i] = True
+                    ctx[i] = r.prompt_len + s.steps_done
+                    seeds[i] = np.int32(r.seed)
+                    steps[i] = s.steps_done + 1   # == len(r.tokens) at sample
+                    temps[i] = r.temperature
+                    top_ks[i] = min(r.top_k, np.iinfo(np.int32).max)
+                sample_ops = (seeds, steps, temps, top_ks)
+                if self._pages is not None:
+                    if streaming:
+                        # mid-prefill slots hold real pages but are not in
+                        # this dispatch — null their rows so the decode
+                        # scatter can't stomp a streaming prompt's K/V
+                        bt = np.zeros_like(self._pages.block_table)
+                        for i in active:
+                            bt[i] = self._pages.block_table[i]
+                    else:
+                        bt = self._pages.block_table
+                    ops = self._put((tokens, bt, ctx) + sample_ops)
                 else:
-                    bt = self._pages.block_table
-                ops = self._put((tokens, bt, ctx) + sample_ops)
-            else:
-                ops = self._put((tokens, mask) + sample_ops)
+                    ops = self._put((tokens, mask) + sample_ops)
             with watchdog_guard("serve_decode"):
-                out, self._cache = self._decode_step_fn()(
-                    self._params, self._cache, *ops
-                )
+                with self._phase("dispatch"):
+                    out, self._cache = self._decode_step_fn()(
+                        self._params, self._cache, *ops
+                    )
                 # the tick's single D2H: [slots] int32 ids (device
                 # sampling) or [slots, vocab] fp32 logits (host sampling)
-                fetched = jax.device_get(out)
-            if self.config.sampling == "device":
-                sampled = fetched
-            else:
-                self._last_logits = fetched
-                sampled = None
-            for i in active:
-                s = self._slots[i]
-                s.steps_done += 1
-                s.request.decode_ticks += 1
-                if sampled is not None:
-                    token = int(sampled[i])
+                with self._phase("decode_wait"):
+                    fetched = jax.device_get(out)
+            with self._phase("emit"):
+                # the device operands and the output are done with: freed
+                # here, inside a phase, not at the tick's return
+                del ops, out
+                if self.config.sampling == "device":
+                    sampled = fetched
                 else:
-                    token = self._sample(s.request, self._last_logits[i])
-                self._emit_token(s.request, token)
-                if self._is_terminal(s.request, token):
-                    self._evict(i)          # slot + pages free for reuse
-                else:
-                    s.pending_token = token
-            self.decode_dispatches += 1
-            self.decode_tokens += len(active)
-            self._registry.gauge("serve/tokens_per_dispatch", 1.0)
+                    self._last_logits = fetched
+                    sampled = None
+                for i in active:
+                    s = self._slots[i]
+                    s.steps_done += 1
+                    s.request.decode_ticks += 1
+                    if sampled is not None:
+                        token = int(sampled[i])
+                    else:
+                        token = self._sample(
+                            s.request, self._last_logits[i])
+                    self._emit_token(s.request, token)
+                    if self._is_terminal(s.request, token):
+                        self._evict(i)      # slot + pages free for reuse
+                    else:
+                        s.pending_token = token
+                self.decode_dispatches += 1
+                self.decode_tokens += len(active)
+                self._registry.gauge("serve/tokens_per_dispatch", 1.0)
             worked = True
 
-        self.ticks += 1
-        depth = self._queue.depth()
-        self._registry.gauge("serve/queue_depth", depth)
-        self._registry.gauge("serve/slot_occupancy", self.slot_occupancy())
-        if self._pages is not None:
-            self._registry.gauge("serve/kv_pages_used", self._pages.pages_used)
-            self._registry.gauge("serve/kv_pages_free", self._pages.pages_free)
-        if self._prefix is not None:
-            lookups = self._prefix.hits + self._prefix.misses
+        with self._phase("publish") as publish:
+            self.ticks += 1
+            tick.attrs = {
+                "decode_active": len(active),
+                "admitted": self.admitted - admitted0,
+                "prefill_tokens": self.prefill_tokens - prefill_tokens0,
+            }
+            depth = self._queue.depth()
+            self._registry.gauge("serve/queue_depth", depth)
             self._registry.gauge(
-                "serve/prefix_hit_rate",
-                self._prefix.hits / lookups if lookups else 0.0,
-            )
-            self._registry.gauge(
-                "serve/pages_shared", self._pages.pages_shared
-            )
-            self._registry.gauge("serve/cow_copies", self.cow_copies)
-        if self.brownout is not None:
-            level = self.brownout.observe(depth / self._queue.max_depth)
-            self._registry.gauge("serve/brownout_level", level)
-            if level != self._prev_brownout_level:
-                self._tick_events.append(
-                    f"brownout:{self._prev_brownout_level}->{level}"
+                "serve/slot_occupancy", self.slot_occupancy())
+            if self._pages is not None:
+                self._registry.gauge(
+                    "serve/kv_pages_used", self._pages.pages_used)
+                self._registry.gauge(
+                    "serve/kv_pages_free", self._pages.pages_free)
+            if self._prefix is not None:
+                lookups = self._prefix.hits + self._prefix.misses
+                self._registry.gauge(
+                    "serve/prefix_hit_rate",
+                    self._prefix.hits / lookups if lookups else 0.0,
                 )
-                self._prev_brownout_level = level
-            if level >= 1 and self._prefix is not None:
-                # brownout pressure: idle cached runs are the cheapest
-                # capacity to give back — drop every cache-only page (they
-                # rebuild from traffic once the ladder steps down)
-                dropped = self._prefix.evict_idle()
-                if dropped:
-                    self._tick_events.append(f"prefix_evict_idle:{dropped}")
-        now = time.monotonic()
-        window = now - self._drain_window_t
-        if window >= 1.0:
-            rate = (self.finished - self._drain_window_finished) / window
-            # EWMA so one quiet window doesn't zero the estimate mid-storm
-            self.drain_rate = (
-                rate if self.drain_rate == 0.0
-                else 0.5 * self.drain_rate + 0.5 * rate
-            )
-            self._drain_window_t = now
-            self._drain_window_finished = self.finished
-            self._registry.gauge("serve/drain_rate_rps", self.drain_rate)
-        if worked:
-            self.busy_ticks += 1
-            self._registry.observe("serve/tick", time.monotonic() - t0)
-        # flight-recorder entry for every busy or eventful tick — appended
-        # BEFORE the chaos hooks below, so a hang injected at this tick
-        # dumps a ring whose LAST entry is the stalled tick itself
-        events, self._tick_events = self._tick_events, []
-        if worked or events:
-            self.flight.record(
-                tick=self.ticks,
-                busy_tick=self.busy_ticks,
-                dur_ms=round((time.monotonic() - t0) * 1e3, 3),
-                queue_depth=depth,
-                slots_active=sum(1 for s in self._slots if s is not None),
-                prefill_resident=self._prefill_resident(),
-                decode_active=len(active),
-                pages_used=(
-                    self._pages.pages_used if self._pages is not None else 0
-                ),
-                brownout=(
-                    self.brownout.level if self.brownout is not None else 0
-                ),
-                weights_step=self.weights_step,
-                finished=self.finished,
-                events=events,
-            )
-        if worked:
-            # deterministic chaos hooks: slow_host:Nx stretches serving time
-            # (deadline/backpressure drills); the replica_* kinds crash,
-            # hang or slow THIS replica at an exact busy tick (router
-            # failover / breaker / drain drills). Both fire before the
-            # heartbeat stamp below, so an injected hang reads as a stale
-            # heartbeat — exactly like a wedged device would.
-            from pytorch_distributed_training_tpu.faults.inject import get_plan
+                self._registry.gauge(
+                    "serve/pages_shared", self._pages.pages_shared
+                )
+                self._registry.gauge("serve/cow_copies", self.cow_copies)
+            if self.brownout is not None:
+                level = self.brownout.observe(depth / self._queue.max_depth)
+                self._registry.gauge("serve/brownout_level", level)
+                if level != self._prev_brownout_level:
+                    self._tick_events.append(
+                        f"brownout:{self._prev_brownout_level}->{level}"
+                    )
+                    self._prev_brownout_level = level
+                if level >= 1 and self._prefix is not None:
+                    # brownout pressure: idle cached runs are the cheapest
+                    # capacity to give back — drop every cache-only page
+                    # (they rebuild from traffic once the ladder steps
+                    # down)
+                    dropped = self._prefix.evict_idle()
+                    if dropped:
+                        self._tick_events.append(
+                            f"prefix_evict_idle:{dropped}")
+            now = time.monotonic()
+            window = now - self._drain_window_t
+            if window >= 1.0:
+                rate = (self.finished - self._drain_window_finished) / window
+                # EWMA so one quiet window doesn't zero the estimate
+                # mid-storm
+                self.drain_rate = (
+                    rate if self.drain_rate == 0.0
+                    else 0.5 * self.drain_rate + 0.5 * rate
+                )
+                self._drain_window_t = now
+                self._drain_window_finished = self.finished
+                self._registry.gauge("serve/drain_rate_rps", self.drain_rate)
+            if worked:
+                self.busy_ticks += 1
+                self._registry.observe("serve/tick", time.monotonic() - t0)
+            # flight-recorder entry for every busy or eventful tick —
+            # appended BEFORE the chaos hooks below, so a hang injected at
+            # this tick dumps a ring whose LAST entry is the stalled tick
+            # itself (its ``publish`` therefore ends where ``dur_ms`` does)
+            events, self._tick_events = self._tick_events, []
+            if worked or events:
+                now = time.monotonic()
+                phases = {"publish": now - publish.t0}
+                for p in self._tick_phases:
+                    name = p.name[len(_TICK):]
+                    phases[name] = phases.get(name, 0.0) + p.t1 - p.t0
+                self.flight.record(
+                    tick=self.ticks,
+                    busy_tick=self.busy_ticks,
+                    dur_ms=round((now - t0) * 1e3, 3),
+                    # milliseconds by phase name; a wait lies inside its
+                    # ``prefill`` and is counted under both names
+                    phases={k: round(v * 1e3, 3) for k, v in phases.items()},
+                    queue_depth=depth,
+                    slots_active=sum(1 for s in self._slots if s is not None),
+                    prefill_resident=self._prefill_resident(),
+                    decode_active=len(active),
+                    pages_used=(
+                        self._pages.pages_used
+                        if self._pages is not None else 0
+                    ),
+                    brownout=(
+                        self.brownout.level
+                        if self.brownout is not None else 0
+                    ),
+                    weights_step=self.weights_step,
+                    finished=self.finished,
+                    events=events,
+                )
+            if worked:
+                # deterministic chaos hooks: slow_host:Nx stretches serving
+                # time (deadline/backpressure drills); the replica_* kinds
+                # crash, hang or slow THIS replica at an exact busy tick
+                # (router failover / breaker / drain drills). Both fire
+                # before the heartbeat stamp below, so an injected hang
+                # reads as a stale heartbeat — exactly like a wedged device
+                # would.
+                from pytorch_distributed_training_tpu.faults.inject import (
+                    get_plan,
+                )
 
-            plan = get_plan()
-            plan.slow_host_delay(time.monotonic() - t0)
-            plan.fire_serve_tick(self.busy_ticks, time.monotonic() - t0)
-        self.last_tick_t = time.monotonic()
+                plan = get_plan()
+                plan.slow_host_delay(time.monotonic() - t0)
+                plan.fire_serve_tick(self.busy_ticks, time.monotonic() - t0)
+            self.last_tick_t = time.monotonic()
         return worked
 
     # -------------------------------------------------------------- shutdown

@@ -127,6 +127,8 @@ class GenRequest:
     bucket: int = 0
     submit_t: float = 0.0
     admit_t: Optional[float] = None
+    # number of the engine tick that admitted it (its ``serve_tick``)
+    admit_tick: Optional[int] = None
     # KV-page reservation stamp (just after pages.admit succeeds) — the
     # ``admission`` span is admit_t -> reserve_t.
     reserve_t: Optional[float] = None

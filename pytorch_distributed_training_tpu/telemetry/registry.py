@@ -18,6 +18,7 @@ own registry as the default for the duration of its run.
 from __future__ import annotations
 
 import contextlib
+import random
 import threading
 import time
 
@@ -25,26 +26,49 @@ import numpy as np
 
 
 class TimerStat:
-    """Observations of one timed quantity (seconds); summarizes on demand."""
+    """Observations of one timed quantity (seconds); summarizes on demand.
 
-    __slots__ = ("values",)
+    Bounded: count, total, min and max are exact; the percentiles come
+    from a uniform sample of at most ``SAMPLE`` observations (reservoir
+    sampling from a fixed seed), exact while the window holds no more
+    than that. A replica observes ``serve/tick`` for the life of its
+    process and never resets."""
+
+    SAMPLE = 1024
+
+    __slots__ = ("count", "total", "min", "max", "values", "_rng")
 
     def __init__(self):
-        self.values: list[float] = []
+        self.count = 0
+        self.total = 0.0
+        self.min = float("inf")
+        self.max = float("-inf")
+        self.values: list[float] = []   # the sample
+        self._rng = random.Random(0)
 
     def observe(self, seconds: float) -> None:
-        self.values.append(float(seconds))
+        x = float(seconds)
+        self.count += 1
+        self.total += x
+        self.min = min(self.min, x)
+        self.max = max(self.max, x)
+        if len(self.values) < self.SAMPLE:
+            self.values.append(x)
+        else:
+            j = self._rng.randrange(self.count)
+            if j < self.SAMPLE:
+                self.values[j] = x
 
     def summary(self) -> dict:
-        v = np.asarray(self.values, np.float64)
-        if v.size == 0:
+        if self.count == 0:
             return {"count": 0, "total_s": 0.0}
+        v = np.asarray(self.values, np.float64)
         return {
-            "count": int(v.size),
-            "total_s": float(v.sum()),
-            "mean_s": float(v.mean()),
-            "min_s": float(v.min()),
-            "max_s": float(v.max()),
+            "count": self.count,
+            "total_s": self.total,
+            "mean_s": self.total / self.count,
+            "min_s": self.min,
+            "max_s": self.max,
             "p50_s": float(np.percentile(v, 50)),
             "p95_s": float(np.percentile(v, 95)),
         }

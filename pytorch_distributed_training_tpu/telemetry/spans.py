@@ -27,15 +27,28 @@ ends); its one mutable counter sits behind the PR-8 named-lock registry
 (``concurrency.lock``), never a raw ``threading.Lock``. The module is
 deliberately jax-free: routers and fleet coordinators import it in
 processes that never touch an accelerator.
+
+``Phase`` is the one context manager every timed region of the program
+goes through (``utils.profiling.annotate``, the engine tick's
+``serve_tick*`` phases, the set-up path's ``setup_phase``): for the span
+of its body it holds a ``jax.profiler.TraceAnnotation`` of its name — so a
+profiler trace carries the phase on the clock the device's operations are
+on — and stamps both ends with ``time.monotonic()``, the clock requests
+and ``Tracer`` stamp; closed, it goes to its collector. The annotation
+class is looked up in ``sys.modules``: a process that never imported jax
+(a router) has no profiler to write to and gets the stamps alone.
 """
 
 from __future__ import annotations
 
-import contextlib
+import collections
 import dataclasses
+import itertools
+import sys
+import threading
 import time
 import uuid
-from typing import Optional
+from typing import Callable, Optional
 
 from pytorch_distributed_training_tpu.analysis import concurrency
 
@@ -43,7 +56,7 @@ from pytorch_distributed_training_tpu.analysis import concurrency
 #: summarize/bench reconciliation sums exactly these against the root span
 REQUEST_PHASES = ("queue", "prefill", "decode")
 
-#: every span name any instrumentation site emits (trace_view legend)
+#: every request-span name any instrumentation site emits
 SPAN_NAMES = (
     "request", "attempt", "hedge",              # router side
     "serve", "queue", "admission", "prefill",   # replica side
@@ -75,8 +88,8 @@ class Tracer:
     ``begin``/``end`` take explicit ``t0``/``t1`` overrides so loop-
     structured phases (the engine's tick loop stamps phase boundaries on
     the request as it goes) can emit their spans retroactively with exact
-    monotonic bounds; ``span()`` is the context-manager form for linear
-    code (the router). Span ids are unique across processes (random
+    monotonic bounds; linear code takes a ``Phase``. Span ids are unique
+    across processes (random
     per-tracer prefix + a counter), which is what lets a replica parent
     its ``serve`` span under a router-generated ``attempt`` span id
     carried over HTTP.
@@ -123,25 +136,13 @@ class Tracer:
         span.t1 = self._now() if t1 is None else float(t1)
         if attrs:
             span.attrs.update(attrs)
-        # wall-clock bounds derived from the monotonic offsets at emit
-        # time: cross-process waterfall alignment, never duration math
-        mono, wall = self._now(), self._wall()
         with self._lock:
             self.emitted += 1
-        self._registry.emit({
-            "record": "span",
-            "trace": span.trace,
-            "span": span.span,
-            "parent": span.parent,
-            "name": span.name,
-            "component": self.component or None,
-            "t0_s": span.t0,
-            "t1_s": span.t1,
-            "dur_s": span.dur_s,
-            "wall_t0": wall - (mono - span.t0),
-            "wall_t1": wall - (mono - span.t1),
-            "attrs": span.attrs,
-        })
+        self._registry.emit(_span_record(
+            trace=span.trace, span=span.span, parent=span.parent,
+            name=span.name, component=self.component or None,
+            t0=span.t0, t1=span.t1, attrs=span.attrs,
+            mono=self._now(), wall=self._wall()))
         return span
 
     def event(self, trace: str, name: str, *, parent: Optional[str] = None,
@@ -152,14 +153,146 @@ class Tracer:
         s = self.begin(trace, name, parent=parent, t0=t, attrs=attrs)
         return self.end(s, t1=s.t0)
 
-    @contextlib.contextmanager
-    def span(self, trace: str, name: str, *, parent: Optional[str] = None,
-             attrs: Optional[dict] = None):
-        s = self.begin(trace, name, parent=parent, attrs=attrs)
+
+# ------------------------------------------------------------------ phases
+
+_ANNOTATION = None          # jax.profiler.TraceAnnotation, once jax is loaded
+_open = threading.local()   # .stack: this thread's open phases, outermost first
+
+
+def _annotation(name: str):
+    global _ANNOTATION
+    if _ANNOTATION is None:
+        jax = sys.modules.get("jax")
+        _ANNOTATION = getattr(
+            getattr(jax, "profiler", None), "TraceAnnotation", None)
+        if _ANNOTATION is None:
+            return None
+    return _ANNOTATION(name)
+
+
+class Phase:
+    """``with Phase(name, collector):`` — one timed region of linear code.
+
+    ``parent`` is the phase that was open on this thread when this one
+    was entered (None for a root), ``ident`` and ``attrs`` are the
+    caller's; ``collector(phase)`` runs once, after ``t1`` is stamped, on
+    the thread that ran the body. No lock, no I/O: what a collector does
+    with a closed phase is its own cost."""
+
+    __slots__ = ("name", "ident", "attrs", "parent", "t0", "t1",
+                 "_collector", "_annotation")
+
+    def __init__(self, name: str, collector: Optional[Callable] = None, *,
+                 ident=None, attrs: Optional[dict] = None):
+        self.name = name
+        self.ident = ident
+        self.attrs = attrs
+        self.parent = None
+        self.t0 = self.t1 = None
+        self._collector = collector
+
+    def __enter__(self) -> "Phase":
         try:
-            yield s
-        finally:
-            self.end(s)
+            stack = _open.stack
+        except AttributeError:
+            stack = _open.stack = []
+        if stack:
+            self.parent = stack[-1]
+        stack.append(self)
+        self._annotation = ann = _annotation(self.name)
+        if ann is not None:
+            ann.__enter__()
+        self.t0 = time.monotonic()
+        return self
+
+    def __exit__(self, exc_type, exc, tb) -> bool:
+        self.t1 = time.monotonic()
+        if self._annotation is not None:
+            self._annotation.__exit__(exc_type, exc, tb)
+        _open.stack.pop()
+        # let go of the collector: one that keeps the phase (a list's
+        # ``append``) would otherwise close a reference cycle a tick, left
+        # to the cyclic collector
+        collector, self._collector = self._collector, None
+        if collector is not None:
+            collector(self)
+        return False
+
+    @property
+    def dur_s(self) -> Optional[float]:
+        return None if self.t1 is None else self.t1 - self.t0
+
+
+#: the set-up path's closed spans (``span`` records, component ``setup``),
+#: oldest first: what a reader in the same process has where no sink is
+#: attached. Bounded: a process sets up once, a test suite many times.
+SETUP_SPANS: collections.deque = collections.deque(maxlen=256)
+
+_SETUP_PREFIX = f"setup-{uuid.uuid4().hex[:6]}"
+_setup_seq = itertools.count(1)
+
+
+class _SetupPhase(Phase):
+    __slots__ = ("registry",)
+
+    def __init__(self, name: str, registry, attrs: Optional[dict]):
+        super().__init__(
+            name, _collect_setup,
+            ident=f"{_SETUP_PREFIX}-{next(_setup_seq)}", attrs=attrs)
+        self.registry = registry
+
+
+def setup_phase(name: str, *, registry=None,
+                attrs: Optional[dict] = None) -> Phase:
+    """A phase of a linear set-up path (``serve_setup*``, ``warm_start*``):
+    closed, it is kept in ``SETUP_SPANS`` and emitted through ``registry``
+    (the process default when None) as a ``span`` record. Set-up phases
+    opened inside one another on a thread form one trace, named after
+    its root."""
+    return _SetupPhase(name, registry, attrs)
+
+
+def _collect_setup(p: _SetupPhase) -> None:
+    parent = p.parent if isinstance(p.parent, _SetupPhase) else None
+    root = p
+    while isinstance(root.parent, _SetupPhase):
+        root = root.parent
+    rec = _span_record(
+        trace=root.ident, span=p.ident,
+        parent=parent.ident if parent is not None else None,
+        name=p.name, component="setup", t0=p.t0, t1=p.t1,
+        attrs=p.attrs or {}, mono=time.monotonic(), wall=time.time())
+    SETUP_SPANS.append(rec)
+    registry = p.registry
+    if registry is None:
+        from pytorch_distributed_training_tpu.telemetry.registry import (
+            get_registry,
+        )
+
+        registry = get_registry()
+    registry.emit(rec)
+
+
+def _span_record(*, trace, span, parent, name, component, t0, t1, attrs,
+                 mono, wall) -> dict:
+    """The ``span`` record. Wall-clock bounds are derived from the
+    monotonic offsets at emit time (``mono``/``wall`` read together):
+    cross-process waterfall alignment, never duration math."""
+    return {
+        "record": "span",
+        "trace": trace,
+        "span": span,
+        "parent": parent,
+        "name": name,
+        "component": component,
+        "t0_s": t0,
+        "t1_s": t1,
+        "dur_s": max(0.0, t1 - t0),
+        "wall_t0": wall - (mono - t0),
+        "wall_t1": wall - (mono - t1),
+        "attrs": attrs,
+    }
 
 
 # --------------------------------------------------------- trace analysis

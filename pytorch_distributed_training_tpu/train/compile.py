@@ -18,7 +18,7 @@ Two independent levers against cold-start latency:
   the loaders' ``batch_spec()`` BEFORE epoch 0, so the first step of the
   run is a normal steady-state step: compile wall time moves out of the
   step stream into its own ``compile`` telemetry record (with a cache-hit
-  flag), the per-step ``compile_inclusive`` flag disappears, and the
+  flag and the share that was tracing and lowering), the per-step ``compile_inclusive`` flag disappears, and the
   watchdog can arm from step 1.
 
 The compiled executables keep the jitted functions' donation and sharding
@@ -30,10 +30,11 @@ unchanged.
 from __future__ import annotations
 
 import os
-import time
 
 import jax
 from jax.sharding import NamedSharding
+
+from pytorch_distributed_training_tpu.telemetry.spans import setup_phase
 
 #: the cache's home when the environment names none: fixed, inside the
 #: checkout, git-ignored — never a temp name, a pid or a timestamp
@@ -126,34 +127,42 @@ def aot_warm_start(
     extra compile.
     """
     entries_before = cache_entry_count(cache_dir)
-    t0 = time.perf_counter()
-    compiled_train = train_step.lower(
-        state, _attach_shardings(train_spec, mesh, train_pspec)
-    ).compile()
-    train_s = time.perf_counter() - t0
-    if guard_mode != "off":
-        from pytorch_distributed_training_tpu.analysis.guards import (
-            donation_audit,
-        )
 
-        donation_audit(
-            "train_step", compiled_train,
-            registry=registry, mode=guard_mode,
-        )
-        if comm_manifest is not None:
-            from pytorch_distributed_training_tpu.analysis.spmd.manifest import (
-                comm_audit,
+    def lower_and_compile(step, name, spec, pspec):
+        """(compiled, seconds lowering, seconds in all): tracing and
+        lowering are the program's own work on the host; compiling is
+        XLA's, or a load from the cache."""
+        batch = _attach_shardings(spec, mesh, pspec)
+        with setup_phase(f"warm_start.{name}.lower", registry=registry) as lo:
+            lowered = step.lower(state, batch)
+        with setup_phase(
+                f"warm_start.{name}.compile", registry=registry) as co:
+            compiled = lowered.compile()
+        return compiled, lo.dur_s, lo.dur_s + co.dur_s
+
+    with setup_phase("warm_start", registry=registry):
+        compiled_train, train_lower_s, train_s = lower_and_compile(
+            train_step, "train", train_spec, train_pspec)
+        if guard_mode != "off":
+            from pytorch_distributed_training_tpu.analysis.guards import (
+                donation_audit,
             )
 
-            comm_audit(
-                "train_step", compiled_train, comm_manifest,
+            donation_audit(
+                "train_step", compiled_train,
                 registry=registry, mode=guard_mode,
             )
-    t0 = time.perf_counter()
-    compiled_eval = eval_step.lower(
-        state, _attach_shardings(eval_spec, mesh, eval_pspec)
-    ).compile()
-    eval_s = time.perf_counter() - t0
+            if comm_manifest is not None:
+                from pytorch_distributed_training_tpu.analysis.spmd.manifest import (  # noqa: E501
+                    comm_audit,
+                )
+
+                comm_audit(
+                    "train_step", compiled_train, comm_manifest,
+                    registry=registry, mode=guard_mode,
+                )
+        compiled_eval, eval_lower_s, eval_s = lower_and_compile(
+            eval_step, "eval", eval_spec, eval_pspec)
     entries_after = cache_entry_count(cache_dir)
     cache_hit = None
     if entries_before is not None:
@@ -163,8 +172,11 @@ def aot_warm_start(
     record = {
         "record": "compile",
         "aot": True,
+        # lowering plus compiling; the ``*_lower_s`` are the first part
         "train_compile_s": train_s,
         "eval_compile_s": eval_s,
+        "train_lower_s": train_lower_s,
+        "eval_lower_s": eval_lower_s,
         "compile_s": train_s + eval_s,
         "cache_dir": cache_dir,
         "cache_hit": cache_hit,

@@ -8,7 +8,7 @@ kind, payload/moved bytes, group sizes, ICI vs DCN split — through
 
 Usage:
   python scripts/audit_hlo.py [micro] [--model NAME] [--seq N]
-      [--global-batch N]      # compile the production step (trace_step)
+      [--global-batch N]      # compile the production step (build_step)
   python scripts/audit_hlo.py --hlo-file /tmp/step_hlo.txt
       [--world-size N]        # audit an existing dump, jax-free
   --json                      # machine-readable summary on stdout
@@ -49,6 +49,104 @@ from pytorch_distributed_training_tpu.analysis.spmd.manifest import (  # noqa: E
     CommManifest,
     train_manifest,
 )
+
+
+GLOBAL, SEQ = 96, 128
+
+
+def build_step(micro, model_name="bert-large-cased", seq=None, global_batch=None):
+    """(jitted train step, sharded state, one global batch) of the
+    production recipe on the current mesh; ATTN / MATMUL / QUANT_DELAYED
+    in the environment pick the variants ``bench.py`` ships."""
+    import os as _os
+
+    import jax
+    import jax.numpy as jnp
+
+    from pytorch_distributed_training_tpu.comms.mesh import build_mesh
+    from pytorch_distributed_training_tpu.models import (
+        BertForSequenceClassification,
+    )
+    from pytorch_distributed_training_tpu.parallel import (
+        ShardingPolicy,
+        state_shardings,
+    )
+    from pytorch_distributed_training_tpu.parallel.sharding import shard_state
+    from pytorch_distributed_training_tpu.train.optim import (
+        adamw_with_schedule,
+    )
+    from pytorch_distributed_training_tpu.train.state import create_train_state
+    from pytorch_distributed_training_tpu.train.step import make_train_step
+    from pytorch_distributed_training_tpu.utils.config import (
+        TrainConfig,
+        model_preset,
+    )
+
+    _attn = {"attention_impl": _os.environ["ATTN"]} if _os.environ.get("ATTN") else {}
+    if _os.environ.get("MATMUL"):
+        _attn["matmul_impl"] = _os.environ["MATMUL"]
+    if _os.environ.get("QUANT_DELAYED") == "1":
+        # the shipping bench config: delayed int8 activation scaling
+        if not str(_attn.get("matmul_impl", "")).startswith("int8"):
+            # same contract as train_dp's CLI guard: a silently-bf16 trace
+            # labeled "delayed int8" is worse than an error
+            raise SystemExit("QUANT_DELAYED=1 requires MATMUL=int8|int8_full")
+        _attn["quant_delayed"] = True
+    global_batch = global_batch or GLOBAL
+    seq = seq or SEQ
+    mesh = build_mesh()
+    from pytorch_distributed_training_tpu.ops.dispatch import set_kernel_mesh
+
+    set_kernel_mesh(mesh)  # multi-chip: keep the Pallas kernel path active
+    mcfg = model_preset(model_name, dropout_impl="kernel", **_attn)
+    if mcfg.causal:
+        from pytorch_distributed_training_tpu.models.gpt2 import GPT2LMModel
+
+        model = GPT2LMModel(mcfg)
+        objective = "causal_lm"
+    else:
+        model = BertForSequenceClassification(mcfg)
+        objective = "classification"
+    tcfg = TrainConfig(
+        global_batch_size=global_batch, micro_batch_size=micro,
+        max_seq_length=seq,
+        grad_accum_dtype="bfloat16", adam_mu_dtype="bfloat16",
+        adam_nu_dtype="bfloat16",
+    )
+    tx, _ = adamw_with_schedule(tcfg, total_steps=1000)
+    example = {
+        "input_ids": jnp.ones((2, seq), jnp.int32),
+        "attention_mask": jnp.ones((2, seq), jnp.int32),
+        "token_type_ids": jnp.zeros((2, seq), jnp.int32),
+    }
+    state = create_train_state(model, tx, jax.random.key(42, impl="rbg"), example)
+    shardings = state_shardings(state, ShardingPolicy(), mesh)
+    state = shard_state(state, shardings)
+    step = make_train_step(
+        grad_accum_steps=tcfg.grad_accum_steps, mesh=mesh,
+        state_shardings=shardings, objective=objective,
+        accum_dtype=tcfg.grad_accum_dtype,
+    )
+    import numpy as np
+    from pytorch_distributed_training_tpu.comms.ingest import make_global_batch
+    from pytorch_distributed_training_tpu.comms.mesh import TRAIN_BATCH_PSPEC
+
+    rng = np.random.default_rng(0)
+    accum = tcfg.grad_accum_steps
+    b = {
+        "input_ids": rng.integers(
+            0, mcfg.vocab_size, (accum, micro, seq)
+        ).astype(np.int32),
+        "attention_mask": np.ones((accum, micro, seq), np.int32),
+        "token_type_ids": np.zeros((accum, micro, seq), np.int32),
+        "labels": rng.integers(0, 2, (accum, micro)).astype(np.int32),
+    }
+    batch = make_global_batch(mesh, b, pspec=TRAIN_BATCH_PSPEC)
+    from pytorch_distributed_training_tpu.train.step import calibrate_quant
+
+    # no-op unless the config carries delayed-quant state
+    state = calibrate_quant(state, jax.tree.map(lambda x: x[0], batch))
+    return step, state, batch
 
 
 def _parse_args(argv):
@@ -206,8 +304,6 @@ def main(argv=None):
             txt = f.read()
         world_size = args.world_size
     else:
-        from trace_step import build_step  # noqa: E402  (same dir)
-
         import jax
 
         step, state, batch = build_step(

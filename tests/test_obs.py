@@ -930,3 +930,229 @@ def test_fleet_trace_merges_across_processes_and_sigterm_dumps(tmp_path):
     ]
     assert dumps, [r.get("record") for r in replica_records][-20:]
     assert dumps[0]["entries"], "drain dump carried an empty ring"
+
+
+# =====================================================================
+# phases: the engine tick, the set-up path, the profiler's clock
+# =====================================================================
+
+
+def _paged_server(lm, reg, **cfg):
+    model, params = lm
+    cfg.setdefault("num_slots", 2)
+    return InferenceServer(
+        model, params,
+        EngineConfig(
+            prompt_buckets=(8, 16), max_new_tokens=6, kv_layout="paged",
+            sampling="device", **cfg,
+        ),
+        queue_depth=16, registry=reg,
+    ).start()
+
+
+def _serve_all(server, prompts, new_tokens=6):
+    try:
+        reqs = [server.submit(p, max_new_tokens=new_tokens) for p in prompts]
+        assert wait_until(
+            lambda: all(r.done.is_set() for r in reqs), timeout=120
+        ), [r.status for r in reqs]
+    finally:
+        server.close()
+    assert all(r.status == "done" for r in reqs), [r.status for r in reqs]
+    return reqs
+
+
+def _children(phases):
+    """A tick's own children: the phases no other phase encloses."""
+    return [
+        p for p in phases
+        if not any(q is not p and q[1] <= p[1] and p[2] <= q[2]
+                   for q in phases)
+    ]
+
+
+def test_tick_phases_tile_every_busy_tick_and_name_their_requests(lm):
+    """Every busy tick is ONE ``serve_tick`` record whose children are in
+    order, inside the tick and tile it: what they leave uncovered is the
+    phase boundaries' own cost (1% of a tick at the chip's 138 ms; on the
+    CPU's few milliseconds, 30 us a boundary). A request's ``prefill``
+    span names the tick whose ``prefill`` phase names the request."""
+    model, _ = lm
+    reg, sink = _registry()
+    server = _paged_server(lm, reg)
+    reqs = _serve_all(server, _prompts(model, [4, 6, 5, 12, 7], seed=4))
+    ticks = sink.of("serve_tick")
+    assert len(ticks) == server.engine.busy_ticks > 5
+    assert [t["tick"] for t in ticks] == sorted({t["tick"] for t in ticks})
+    order = ["expire", "admit", "prefill", "chunks", "operands", "dispatch",
+             "decode_wait", "emit", "publish"]
+    tiled = []
+    for t in ticks:
+        kids = _children(t["phases"])
+        assert [p[1] for p in kids] == sorted(p[1] for p in kids)
+        names = [p[0] for p in kids]
+        assert names[0] == "expire" and names[-1] == "publish"
+        # in order, but for admit/prefill, which alternate per admission
+        ranks = [order.index(n) for n in names if n not in ("admit", "prefill")]
+        assert ranks == sorted(ranks), names
+        cursor = t["t0_s"]
+        for name, t0, t1, *_ in kids:
+            assert cursor <= t0 <= t1 <= t["t1_s"], (name, t)
+            cursor = t1
+        dur = t["t1_s"] - t["t0_s"]
+        uncovered = dur - sum(p[2] - p[1] for p in kids)
+        tiled.append(
+            uncovered <= max(0.01 * dur, 30e-6 * (len(kids) + 1)))
+        if t["decode_active"]:
+            assert {"operands", "dispatch", "decode_wait", "emit"} <= set(names)
+        assert t["admitted"] == names.count("prefill")
+    # all but a tick or two: another thread may take the interpreter for a
+    # moment between two phases, and that moment is nobody's
+    assert sum(tiled) >= 0.75 * len(ticks), tiled
+    assert sum(t["admitted"] for t in ticks) == len(reqs)
+    assert sum(t["prefill_tokens"] for t in ticks) == sum(
+        r.prompt_len for r in reqs)
+
+    # both directions: request span -> tick, tick phase -> request
+    traces = spans_by_trace(sink.records)
+    by_tick = {t["tick"]: t for t in ticks}
+    for r in reqs:
+        span = next(s for s in traces[r.id] if s["name"] == "prefill")
+        tick = by_tick[span["attrs"]["tick"]]
+        mine = [p for p in tick["phases"] if p[0] == "prefill" and p[3] == r.id]
+        assert len(mine) == 1
+        assert mine[0][4] == {"bucket": r.bucket, "prompt_len": r.prompt_len}
+        waits = [p for p in tick["phases"]
+                 if p[0] == "prefill_wait" and p[3] == r.id]
+        assert len(waits) == 1
+        assert mine[0][1] <= waits[0][1] <= waits[0][2] <= mine[0][2]
+        # the request's own prefill span (admit -> first token) encloses it
+        assert span["t0_s"] <= mine[0][1] and waits[0][2] <= span["t1_s"]
+
+    # the flight entries carry the same phases, in milliseconds by name
+    for e in server.engine.flight.snapshot():
+        assert e["phases"]["publish"] >= 0
+        own = sum(v for k, v in e["phases"].items() if k != "prefill_wait")
+        assert own <= e["dur_ms"] + 0.01
+        assert own >= 0.9 * e["dur_ms"] - 0.3
+
+
+def test_no_sink_builds_no_tick_record_and_the_ring_stays_bounded(lm):
+    from pytorch_distributed_training_tpu.telemetry.registry import (
+        MetricsRegistry,
+    )
+
+    model, _ = lm
+    reg = MetricsRegistry()
+    seen = []
+    emit = reg.emit
+    reg.emit = lambda rec: (seen.append(rec["record"]), emit(rec))
+    server = _paged_server(lm, reg, flight_capacity=4)
+    _serve_all(server, _prompts(model, [4, 6, 5], seed=6))
+    assert server.engine.busy_ticks > 4
+    assert "serve_request" in seen and "serve_tick" not in seen
+    ring = server.engine.flight.snapshot()
+    assert len(ring) == 4 and server.engine.flight.recorded > 4
+    assert all("decode_wait" in e["phases"] or e["decode_active"] == 0
+               for e in ring)
+
+
+def test_phases_are_on_the_profilers_host_line_and_agree_with_records(
+        lm, tmp_path):
+    """One primitive writes both: under ``jax.profiler`` every
+    ``serve_tick*`` and ``serve_setup*`` phase is an event on a line of a
+    host plane, and its duration there agrees with its record's to 1 ms."""
+    import glob
+
+    import jax
+
+    model, _ = lm
+    reg, sink = _registry()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        server = _paged_server(lm, reg, warmup=True)
+        _serve_all(server, _prompts(model, [4, 6], seed=8), new_tokens=3)
+    finally:
+        jax.profiler.stop_trace()
+    [path] = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    events = {}   # name -> [(start_s, dur_s)]
+    for plane in jax.profiler.ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            for ev in line.events:
+                if ev.name.startswith(("serve_tick", "serve_setup")):
+                    assert plane.name.startswith("/host:"), plane.name
+                    events.setdefault(ev.name, []).append(
+                        (ev.start_ns / 1e9, ev.duration_ns / 1e9))
+    setup = [r for r in sink.of("span") if r["component"] == "setup"]
+    assert {r["name"] for r in setup} == {
+        "serve_setup.engine", "serve_setup.warmup",
+        "serve_setup.warmup.prefill_8", "serve_setup.warmup.prefill_16",
+        "serve_setup.warmup.decode", "serve_setup.warmup.drain"}
+    warmup = next(r for r in setup if r["name"] == "serve_setup.warmup")
+    assert warmup["parent"] is None
+    assert all(r["parent"] == warmup["span"] and r["trace"] == warmup["span"]
+               for r in setup if r["name"].startswith("serve_setup.warmup."))
+    # the two clocks differ by a constant: take it from the one warm-up
+    [(w_start, _)] = events["serve_setup.warmup"]
+    shift = w_start - warmup["t0_s"]
+
+    def agrees(name, t0, t1):
+        return any(abs(s - (t0 + shift)) < 1e-3 and abs(d - (t1 - t0)) < 1e-3
+                   for s, d in events.get(name, ()))
+
+    for r in setup:
+        assert agrees(r["name"], r["t0_s"], r["t1_s"]), r["name"]
+    ticks = sink.of("serve_tick")
+    assert ticks
+    for t in ticks:
+        assert agrees("serve_tick", t["t0_s"], t["t1_s"]), t["tick"]
+        for name, t0, t1, *_ in t["phases"]:
+            assert agrees("serve_tick." + name, t0, t1), (t["tick"], name)
+
+
+def test_spans_module_stays_jax_free():
+    code = (
+        "import sys\n"
+        "from pytorch_distributed_training_tpu.telemetry import spans\n"
+        "got = []\n"
+        "with spans.Phase('outer', got.append):\n"
+        "    with spans.setup_phase('inner'):\n"
+        "        pass\n"
+        "assert got[0].t1 >= got[0].t0 and spans.SETUP_SPANS\n"
+        "assert 'jax' not in sys.modules, 'jax was imported'\n"
+    )
+    p = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                       text=True, timeout=120)
+    assert p.returncode == 0, p.stderr[-2000:]
+
+
+def test_warm_start_record_splits_lowering_from_compiling(tmp_path):
+    """``aot_warm_start`` times ``.lower()`` apart from ``.compile()``:
+    the ``compile`` record's ``*_lower_s`` are parts of its
+    ``*_compile_s`` (which keep their meaning: lower plus compile), and
+    the four ``warm_start.*`` spans are the same stamps."""
+    from pytorch_distributed_training_tpu.cli import train_dp
+
+    mdir = tmp_path / "metrics"
+    train_dp.main([
+        "--model", "tiny", "--task", "synthetic", "--num-epochs", "1",
+        "--train-size", "64", "--eval-size", "32", "--max-seq-length", "32",
+        "--native-loader", "off", "--metrics-dir", str(mdir)])
+    records = [json.loads(ln) for ln in open(mdir / "metrics.jsonl")]
+    [c] = [r for r in records if r["record"] == "compile"]
+    spans = {r["name"]: r for r in records
+             if r["record"] == "span" and r["component"] == "setup"}
+    assert set(spans) == {
+        "warm_start", "warm_start.train.lower", "warm_start.train.compile",
+        "warm_start.eval.lower", "warm_start.eval.compile"}
+    for step in ("train", "eval"):
+        lower = spans[f"warm_start.{step}.lower"]
+        compile_ = spans[f"warm_start.{step}.compile"]
+        assert 0 < c[f"{step}_lower_s"] < c[f"{step}_compile_s"]
+        assert c[f"{step}_lower_s"] == pytest.approx(lower["dur_s"])
+        assert c[f"{step}_compile_s"] == pytest.approx(
+            lower["dur_s"] + compile_["dur_s"])
+        assert lower["parent"] == compile_["parent"] == spans["warm_start"]["span"]
+    assert c["compile_s"] == pytest.approx(
+        c["train_compile_s"] + c["eval_compile_s"])
+    assert trace_summary(list(spans.values()))["complete"] is True

@@ -91,6 +91,31 @@ def test_registry_counter_gauge_timer_semantics():
     assert t["min_s"] <= t["p50_s"] <= t["p95_s"] <= t["max_s"]
 
 
+def test_timer_holds_a_fixed_number_of_values_however_many_it_saw():
+    """Serving observes ``serve/tick`` for the life of the process and
+    never resets: count, total, min and max stay exact, the percentiles
+    come from a bounded uniform sample."""
+    reg = MetricsRegistry()
+    n = 10_000
+    for i in range(n):
+        reg.observe("serve/tick", (i + 1) / n)   # 0.0001 .. 1.0, in order
+    stat = reg._timers["serve/tick"]
+    assert len(stat.values) == stat.SAMPLE < n
+    t = reg.snapshot()["timers"]["serve/tick"]
+    assert t["count"] == n
+    assert t["total_s"] == pytest.approx((n + 1) / 2)
+    assert t["mean_s"] == pytest.approx((n + 1) / 2 / n)
+    assert t["min_s"] == pytest.approx(1 / n) and t["max_s"] == 1.0
+    # a uniform sample of 1024: the median within a few hundredths
+    assert t["p50_s"] == pytest.approx(0.5, abs=0.06)
+    assert t["p95_s"] == pytest.approx(0.95, abs=0.03)
+    # up to the sample's size every observation is kept: exact
+    small = MetricsRegistry()
+    for x in (0.3, 0.1, 0.2):
+        small.observe("t", x)
+    assert small.snapshot()["timers"]["t"]["p50_s"] == pytest.approx(0.2)
+
+
 def test_registry_snapshot_reset_clears_window():
     reg = MetricsRegistry()
     reg.inc("c")
