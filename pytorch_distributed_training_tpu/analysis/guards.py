@@ -135,6 +135,7 @@ class GuardedCall:
         self._warm = not hasattr(fn, "_cache_size")
         self._audit_donation = audit_donation
         self._comm_manifest = comm_manifest
+        self.comm_record = None  # the one-shot comm audit's record
         self.calls = 0
         self.recompiles = 0
 
@@ -192,7 +193,7 @@ class GuardedCall:
                 "error": str(e)[:200],
             })
             return
-        comm_audit(
+        self.comm_record = comm_audit(
             self.name, compiled, self._comm_manifest,
             registry=self.guards.registry, mode=self.guards.mode,
         )

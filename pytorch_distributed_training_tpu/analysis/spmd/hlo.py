@@ -68,6 +68,15 @@ _INSTR_RE = re.compile(
 _COMPUTATION_RE = re.compile(r"^(?:ENTRY\s+)?%?(?P<name>[\w.-]+)\s+\(.*\{\s*$")
 _TPU_REDUCE_SCATTER_PREFIX = "all-reduce-scatter"
 
+# `%copy.217 = bf16[3073,16,16,64]{3,2,1,0:T(8,128)(2,1)} copy(%pools...)`:
+# the instructions that rewrite a whole buffer without computing anything
+# new — a relayout, a transposition, a widening. `copy-start` is the async
+# form (its `-done` half has the same shape and is not counted twice).
+_RELAYOUT_RE = re.compile(
+    r"^\s*(?:ROOT\s+)?%?[\w.-]+\s*=\s*(?P<shape>\(.*?\)|\S+)\s+"
+    r"(?:copy|copy-start|transpose|convert)\("
+)
+
 _SHAPE_TOKEN_RE = re.compile(r"(" + _DTYPES_ALT + r")\[([0-9,]*)\]")
 
 _GROUPS_LIST_RE = re.compile(r"replica_groups=\{\{([^}]*)\}")
@@ -158,6 +167,27 @@ def extract_collectives(
             asynchronous=asynchronous,
         ))
     return out
+
+
+def count_relayouts(hlo_text: str, element_counts) -> int:
+    """How many ``copy``/``transpose``/``convert`` instructions of a
+    compiled program produce a buffer of one of ``element_counts``
+    elements, fused or not. Handed the element counts of a program's
+    resident buffers (the serve engine's KV page pools) this is the
+    number of times the program rewrites one of them whole: XLA:TPU
+    inserts such copies when a parameter's device layout is not the one
+    its consumer runs in, and they cost a pass over the buffer each."""
+    wanted = set(element_counts)
+    if not wanted:
+        return 0
+    count = 0
+    for line in hlo_text.splitlines():
+        m = _RELAYOUT_RE.match(line)
+        if m and any(
+            elems in wanted for _, elems in _shape_tokens(m.group("shape"))
+        ):
+            count += 1
+    return count
 
 
 @dataclasses.dataclass(frozen=True)

@@ -27,6 +27,7 @@ from typing import Optional
 from pytorch_distributed_training_tpu.analysis.spmd.hlo import (
     COLLECTIVE_KINDS,
     CostModel,
+    count_relayouts,
     extract_collectives,
     summarize_collectives,
 )
@@ -51,6 +52,11 @@ class CommManifest:
     # ceiling on ring-model bytes moved per device (CostModel.moved_bytes
     # summed over all collectives) — the wire-traffic twin of max_bytes
     max_moved_bytes: Optional[int] = None
+    # per-device element counts of the serve engine's resident KV page
+    # pools: the audit counts the program's whole-pool copy / transpose /
+    # convert instructions (``kv_pool_relayout_ops``; a pool that keeps one
+    # device layout from parameter to donated result reads 0)
+    kv_pool_elements: tuple = ()
 
     def __post_init__(self):
         for kind in tuple(self.allowed) + tuple(self.required):
@@ -100,6 +106,7 @@ class CommManifest:
             "required": list(self.required),
             "max_bytes": self.max_bytes,
             "max_moved_bytes": self.max_moved_bytes,
+            "kv_pool_elements": list(self.kv_pool_elements),
         }
 
 
@@ -224,7 +231,10 @@ def comm_audit(
     ``as_text()``) — pass the COMPILED object: SPMD-partitioner
     collectives only exist post-compile. Emits one ``comm_audit``
     record; deviations bump ``guards/comm_deviations`` and raise
-    ``GuardViolation`` in strict mode.
+    ``GuardViolation`` in strict mode. Where the manifest names the
+    engine's KV pools (``kv_pool_elements``) the record also carries
+    ``kv_pool_relayout_ops``, counted from the same text: a count, not a
+    deviation.
     """
     from pytorch_distributed_training_tpu.analysis.guards import (
         GuardViolation,
@@ -262,6 +272,11 @@ def comm_audit(
         "deviations": deviations,
         **summary,
     }
+    if manifest.kv_pool_elements:
+        record["kv_pool_elements"] = list(manifest.kv_pool_elements)
+        record["kv_pool_relayout_ops"] = count_relayouts(
+            text, manifest.kv_pool_elements
+        )
     registry.emit(record)
     if deviations:
         registry.inc("guards/comm_deviations", len(deviations))

@@ -97,8 +97,8 @@ def build_parser() -> argparse.ArgumentParser:
     p.add_argument("--tp", type=int, default=1,
                    help="tensor parallelism: shard THIS replica's engine "
                         "over N devices (attention heads + MLP hidden on "
-                        "a model-axis mesh, paged KV pools split on the "
-                        "head dim; streams stay bit-identical to tp=1). "
+                        "a model-axis mesh, paged KV pools split by "
+                        "heads; streams stay bit-identical to tp=1). "
                         "Requires paged KV + device sampling and a model "
                         "whose num_heads/intermediate_size divide by N")
     p.add_argument("--weights-dtype", default="float32",

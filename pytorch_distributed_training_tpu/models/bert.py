@@ -232,9 +232,12 @@ class BertSelfAttention(nn.Module):
 
         Same flax "cache" collection pattern as ``_cached_attend``, but the
         K/V buffers are page POOLS shared by every sequence in the batch:
-        ``k_pages``/``v_pages`` [num_pages, page_size, heads, head_dim],
-        addressed through a per-sequence ``block_table`` [batch, W] and
-        ``context_len`` [batch]. The serving engine owns page placement
+        ``k_pages``/``v_pages`` [num_pages, page_size, heads * head_dim]
+        (lane-dense: a token's heads folded into the minor axis, so the
+        pool's default TPU layout is the row-major one the write and the
+        gather run in — ops/paged_attention.py), addressed through a
+        per-sequence ``block_table`` [batch, W] and ``context_len``
+        [batch]. The serving engine owns page placement
         (serve/paged_cache.py) and injects block_table/context_len as traced
         operands per call; only the pools are engine-resident state.
 
@@ -272,17 +275,12 @@ class BertSelfAttention(nn.Module):
         # (ops/paged_attention.py); the allocator never sees dtypes.
         quant_kv = cfg.kv_cache_dtype == "int8"
         pool_dtype = jnp.int8 if quant_kv else k.dtype
+        pool_shape = (cfg.kv_num_pages, page_size, heads * head_dim)
         kp = self.variable(
-            "cache", "k_pages",
-            lambda: jnp.zeros(
-                (cfg.kv_num_pages, page_size, heads, head_dim), pool_dtype
-            ),
+            "cache", "k_pages", lambda: jnp.zeros(pool_shape, pool_dtype)
         )
         vp = self.variable(
-            "cache", "v_pages",
-            lambda: jnp.zeros(
-                (cfg.kv_num_pages, page_size, heads, head_dim), pool_dtype
-            ),
+            "cache", "v_pages", lambda: jnp.zeros(pool_shape, pool_dtype)
         )
         if quant_kv:
             ks = self.variable(
@@ -321,21 +319,23 @@ class BertSelfAttention(nn.Module):
             # quantize-on-write: the scale entries scatter through the SAME
             # (page, offset) indices as their values, so a token's int8
             # lanes and its fp32 scales can never drift apart
-            kq, ksc = quantize_kv(k)
-            vq, vsc = quantize_kv(v)
-            kp.value = kp.value.at[page_ids, offs].set(kq)
-            vp.value = vp.value.at[page_ids, offs].set(vq)
+            k_new, ksc = quantize_kv(k)
+            v_new, vsc = quantize_kv(v)
             ks.value = ks.value.at[page_ids, offs].set(ksc)
             vs.value = vs.value.at[page_ids, offs].set(vsc)
             pool_kw = dict(k_scales=ks.value, v_scales=vs.value)
         else:
-            kp.value = kp.value.at[page_ids, offs].set(
-                k.astype(kp.value.dtype)
-            )
-            vp.value = vp.value.at[page_ids, offs].set(
-                v.astype(vp.value.dtype)
-            )
+            k_new = k.astype(kp.value.dtype)
+            v_new = v.astype(vp.value.dtype)
             pool_kw = {}
+        # ONE write for decode (chunk 1), prefill, chunked prefill and
+        # verify alike: each token's heads fold into the pool's lane axis
+        kp.value = kp.value.at[page_ids, offs].set(
+            k_new.reshape(batch, chunk, heads * head_dim)
+        )
+        vp.value = vp.value.at[page_ids, offs].set(
+            v_new.reshape(batch, chunk, heads * head_dim)
+        )
         cl.value = idx + chunk
         scale = head_dim ** -0.5
         if chunk == 1:

@@ -10,10 +10,18 @@ Shapes
                   are the LAST q_len positions of the sequence and attend
                   causally: query row ``j`` sees positions
                   ``< lengths - q_len + 1 + j``.
-- ``k_pages``/``v_pages``: [num_pages, page_size, heads, head_dim] — the
-                  engine-resident page pools. Page 0 is the reserved null
-                  page (see serve/paged_cache.py); idle sequences park their
-                  block table on it.
+- ``k_pages``/``v_pages``: [num_pages, page_size, heads * head_dim] — the
+                  engine-resident page pools, LANE-DENSE: a token's heads
+                  are folded into one minor axis (head ``n`` owns lanes
+                  ``n*head_dim .. (n+1)*head_dim - 1``), so XLA:TPU's
+                  default layout for the pool is the row-major one the
+                  scatter and the gather run in. (With trailing
+                  ``[16, 64]`` axes the default layout put the PAGE axis on
+                  the lanes, and every program relaid the whole pool out
+                  and back around a one-token write.) ``heads`` and
+                  ``head_dim`` are read from ``q``. Page 0 is the reserved
+                  null page (see serve/paged_cache.py); idle sequences park
+                  their block table on it.
 - ``block_table``: [batch, pages_per_seq] int32 — page ids per sequence, in
                   token order; entries past the live length point at page 0.
 - ``lengths``:    [batch] int32 — valid tokens per sequence INCLUSIVE of all
@@ -33,7 +41,12 @@ Two implementations behind one signature:
   pages_per_seq), block table scalar-prefetched so each grid step's
   ``index_map`` streams exactly one page of K/V into VMEM, running
   max/denominator/accumulator rescaled per page, output written on the last
-  page. ``interpret=`` falls back to the Pallas interpreter off-TPU (same
+  page. The kernels still take a ``(1, page_size, heads, head_dim)``
+  block, fed by a reshape of the lane-dense pool at the ``pallas_call``
+  boundary: on the chip that reshape is a whole-pool relayout, every
+  call (no CLI flag and no benchmark cell reaches this impl; a kernel
+  over the ``(1, page_size, heads*head_dim)`` page is ROADMAP A2).
+  ``interpret=`` falls back to the Pallas interpreter off-TPU (same
   ``tpu_interpret_mode()`` contract as ops/flash_attention.py). The
   single-query kernel computes in exact fp32 on the VPU; the multi-query
   kernel's dots run on the MXU at its default precision, which rounds
@@ -58,12 +71,13 @@ _NEG_INF = jnp.finfo(jnp.float32).min
 _SCALE_AXES = ("num_pages", "page_size", "heads")
 
 
-def _check_scale_pool(pool_name, pool, scale_name, scales):
+def _check_scale_pool(pool_name, pool, scale_name, scales, heads):
     """Trace-time contract between an int8 page pool and its scale pool,
     in the named-axis error style: int8 pools REQUIRE fp32 scales of shape
-    [num_pages, page_size, heads]; float pools must not carry scales."""
+    [num_pages, page_size, heads] (one per token per head, ``heads`` from
+    ``q``); float pools must not carry scales."""
     if pool.dtype == jnp.int8:
-        want = pool.shape[:3]
+        want = (*pool.shape[:2], heads)
         if scales is None:
             raise ValueError(
                 f"{pool_name} is int8 but {scale_name} is missing: int8 "
@@ -85,7 +99,7 @@ def _check_scale_pool(pool_name, pool, scale_name, scales):
             )
             raise ValueError(
                 f"{scale_name} shape mismatch on {bad} (got {scales.shape},"
-                f" want {want} from {pool_name})"
+                f" want {want} from {pool_name} and q)"
             )
         if scales.dtype != jnp.float32:
             raise ValueError(
@@ -123,7 +137,7 @@ def paged_attention(
             f"q must be [batch, heads, head_dim] or "
             f"[batch, q_len, heads, head_dim], got {q.shape}"
         )
-    pool_axes = ("num_pages", "page_size", "heads", "head_dim")
+    pool_axes = ("num_pages", "page_size", "heads*head_dim")
     if k_pages.shape != v_pages.shape:
         bad = ", ".join(
             f"{name} (axis {i}): k_pages={ks} vs v_pages={vs}"
@@ -136,19 +150,23 @@ def paged_attention(
             f"k_pages/v_pages shapes differ on {bad} "
             f"(full shapes {k_pages.shape} vs {v_pages.shape})"
         )
-    # q's trailing [heads, head_dim] must match the pools — the axis pair
-    # that goes wrong first when heads shard over a tensor-parallel mesh
-    # and one side of the call still sees the unsharded width
-    for name, q_dim, pool_dim in (
-        ("heads", q.shape[-2], k_pages.shape[2]),
-        ("head_dim", q.shape[-1], k_pages.shape[3]),
-    ):
-        if q_dim != pool_dim:
-            raise ValueError(
-                f"q/pool mismatch on axis {name!r}: q has {q_dim}, "
-                f"k_pages/v_pages have {pool_dim} (q {q.shape}, pools "
-                f"{k_pages.shape})"
-            )
+    if k_pages.ndim != 3:
+        raise ValueError(
+            f"k_pages/v_pages must be [num_pages, page_size, "
+            f"heads*head_dim]: got shape {k_pages.shape} (rank "
+            f"{k_pages.ndim}, want 3)"
+        )
+    # q's trailing [heads, head_dim] must fold to the pools' lane axis —
+    # the axis that goes wrong first when heads shard over a
+    # tensor-parallel mesh and one side of the call still sees the
+    # unsharded width
+    heads, head_dim = q.shape[-2:]
+    if heads * head_dim != k_pages.shape[2]:
+        raise ValueError(
+            f"q/pool mismatch on axis 'heads*head_dim': q has {heads} x "
+            f"{head_dim} = {heads * head_dim}, k_pages/v_pages have "
+            f"{k_pages.shape[2]} (q {q.shape}, pools {k_pages.shape})"
+        )
     if block_table.ndim != 2 or block_table.shape[0] != q.shape[0]:
         raise ValueError(
             f"block_table must be [batch, pages_per_seq]: got shape "
@@ -166,8 +184,8 @@ def paged_attention(
             f"k_pages/v_pages dtypes differ: {k_pages.dtype} vs "
             f"{v_pages.dtype} (pools quantize together or not at all)"
         )
-    _check_scale_pool("k_pages", k_pages, "k_scales", k_scales)
-    _check_scale_pool("v_pages", v_pages, "v_scales", v_scales)
+    _check_scale_pool("k_pages", k_pages, "k_scales", k_scales, heads)
+    _check_scale_pool("v_pages", v_pages, "v_scales", v_scales, heads)
     scales = (k_scales, v_scales)
     if q.ndim == 4:
         if impl == "reference":
@@ -195,9 +213,10 @@ def paged_attention(
 
 def _gather_dequant(pages, scales, block_table, batch, tokens, heads,
                     head_dim):
-    """Gather pages through the block table ([B, W, P, H, D] → [B, T, H, D])
-    and, for int8 pools, dequantize against the identically-gathered scale
-    pool (one fp32 scale per token per head)."""
+    """Gather pages through the block table ([B, W, P, H*D]) and only then
+    unfold the gathered WINDOW to [B, T, H, D] — the resident pool itself
+    is never reshaped — and, for int8 pools, dequantize against the
+    identically-gathered scale pool (one fp32 scale per token per head)."""
     x = pages[block_table].reshape(batch, tokens, heads, head_dim)
     if scales is None:
         return x
@@ -208,10 +227,10 @@ def _gather_dequant(pages, scales, block_table, batch, tokens, heads,
 def _paged_reference(q, k_pages, v_pages, block_table, lengths, scale,
                      k_scales=None, v_scales=None):
     batch, heads, head_dim = q.shape
-    _, page_size, _, _ = k_pages.shape
+    page_size = k_pages.shape[1]
     windows = block_table.shape[1]
 
-    # Gather the full (padded) context per sequence: [B, W, P, H, D] →
+    # Gather the full (padded) context per sequence: [B, W, P, H*D] →
     # [B, W*P, H, D]. Token order is page order × in-page offset, which is
     # exactly how serve/paged_cache.py lays tokens out.
     tokens = windows * page_size
@@ -336,14 +355,25 @@ def _page_walk_specs(page_size, heads, head_dim, quantized):
     return specs
 
 
+def _kernel_pools(q, k_pages, v_pages):
+    """The lane-dense pools unfolded to the kernels' ``[num_pages,
+    page_size, heads, head_dim]`` block shape. On the chip this reshape
+    relays the WHOLE pool out, every call (the 4-D default layout puts the
+    page axis on the lanes, the 3-D one does not): the price of keeping
+    the kernels' ``(1, P, H, D)`` page until they are rewritten for the
+    ``(1, P, H*D)`` one (ROADMAP A2)."""
+    shape = (*k_pages.shape[:2], *q.shape[-2:])
+    return k_pages.reshape(shape), v_pages.reshape(shape)
+
+
 def _paged_pallas(q, k_pages, v_pages, block_table, lengths, scale,
                   k_scales=None, v_scales=None):
     batch, heads, head_dim = q.shape
-    _, page_size, _, _ = k_pages.shape
+    page_size = k_pages.shape[1]
     windows = block_table.shape[1]
     quantized = k_scales is not None
 
-    operands = [block_table, lengths, q, k_pages, v_pages]
+    operands = [block_table, lengths, q, *_kernel_pools(q, k_pages, v_pages)]
     if quantized:
         operands += [k_scales, v_scales]
     out = pl.pallas_call(
@@ -391,7 +421,7 @@ def _paged_pallas(q, k_pages, v_pages, block_table, lengths, scale,
 def _paged_reference_mq(q, k_pages, v_pages, block_table, lengths, scale,
                         k_scales=None, v_scales=None):
     batch, q_len, heads, head_dim = q.shape
-    _, page_size, _, _ = k_pages.shape
+    page_size = k_pages.shape[1]
     windows = block_table.shape[1]
 
     tokens = windows * page_size
@@ -501,11 +531,11 @@ def _paged_kernel_mq(
 def _paged_pallas_mq(q, k_pages, v_pages, block_table, lengths, scale,
                      k_scales=None, v_scales=None):
     batch, q_len, heads, head_dim = q.shape
-    _, page_size, _, _ = k_pages.shape
+    page_size = k_pages.shape[1]
     windows = block_table.shape[1]
     quantized = k_scales is not None
 
-    operands = [block_table, lengths, q, k_pages, v_pages]
+    operands = [block_table, lengths, q, *_kernel_pools(q, k_pages, v_pages)]
     if quantized:
         operands += [k_scales, v_scales]
     out = pl.pallas_call(

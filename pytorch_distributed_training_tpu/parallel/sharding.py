@@ -175,26 +175,22 @@ def serve_param_shardings(params, mesh: Mesh,
     )
 
 
-def serve_pool_pspec(ndim: int = 4) -> P:
-    """PartitionSpec for one paged-KV pool leaf ``[num_pages, page_size,
-    heads, head_dim]``: heads shard over ``model`` so each shard owns its
-    own page pool at 1/N width — page indices, block tables and the
-    allocator arithmetic are untouched (they address the page axis, which
-    stays whole). Rank-3 leaves are the int8 pools' fp32 scale pools
-    ``[num_pages, page_size, heads]`` (kv_cache_dtype='int8'); their heads
-    axis shards with the value pool it scales."""
-    if ndim == 3:
-        return P(None, None, "model")
-    return P(None, None, "model", None)
+def serve_pool_pspec() -> P:
+    """PartitionSpec for one paged-KV pool leaf: the value pools
+    ``[num_pages, page_size, heads * head_dim]`` and the int8 cache's fp32
+    scale pools ``[num_pages, page_size, heads]`` both carry their heads
+    on the LAST axis, which shards over ``model`` in contiguous blocks of
+    ``heads / N`` heads — each shard owns its own page pool at 1/N width.
+    Page indices, block tables and the allocator arithmetic are untouched
+    (they address the page axis, which stays whole)."""
+    return P(None, None, "model")
 
 
 def serve_pool_shardings(pools, mesh: Mesh):
-    """NamedSharding pytree for the engine's paged K/V pools (rank-4 value
-    pools, plus rank-3 scale pools when the cache is int8)."""
-    return jax.tree.map(
-        lambda leaf: NamedSharding(mesh, serve_pool_pspec(getattr(leaf, "ndim", 4))),
-        pools,
-    )
+    """NamedSharding pytree for the engine's paged K/V pools (value pools,
+    plus scale pools when the cache is int8)."""
+    sharding = NamedSharding(mesh, serve_pool_pspec())
+    return jax.tree.map(lambda _: sharding, pools)
 
 
 def state_shardings(state: TrainState, policy: ShardingPolicy, mesh: Mesh):

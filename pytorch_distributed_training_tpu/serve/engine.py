@@ -10,10 +10,13 @@ batching a la Orca, block-structured KV a la vLLM's PagedAttention):
 - **KV layout** (``EngineConfig.kv_layout``):
 
   * ``"paged"`` (default): K/V lives in fixed-size pages —
-    ``[num_pages, page_size, heads, head_dim]`` pools per attention
-    layer — addressed through a per-slot block table that
-    ``serve/paged_cache.py`` allocates on admit and frees on evict
-    (defrag-free; page 0 is the reserved null page idle slots park on).
+    ``[num_pages, page_size, heads * head_dim]`` pools per attention
+    layer (lane-dense: a token's heads folded into the minor axis, so a
+    pool keeps ONE device layout from parameter to donated result and no
+    program relays it out around a write) — addressed through a
+    per-slot block table that ``serve/paged_cache.py`` allocates on
+    admit and frees on evict (defrag-free; page 0 is the reserved null
+    page idle slots park on).
     The decode step runs the model at batch ``num_slots`` directly with
     per-slot ``position_ids``/``context_len`` operands; no vmap, no
     per-slot freeze select — page structure isolates slots. Admission is
@@ -96,6 +99,7 @@ trial window is free of copies.
 from __future__ import annotations
 
 import dataclasses
+import math
 import threading
 import time
 from collections import deque
@@ -200,7 +204,7 @@ class EngineConfig:
     # Tensor parallelism: the engine's jitted programs run under pjit over
     # a `model`-axis mesh of this many devices, attention heads + MLP
     # hidden sharded (parallel/sharding.py serve rules), paged pools split
-    # on the head dim. 1 = today's single-device engine, bit-identical
+    # by heads. 1 = today's single-device engine, bit-identical
     # streams either way. Requires kv_layout="paged" + sampling="device".
     tp: int = 1
     # Serving precision variants. weights_dtype="int8" quantizes every
@@ -506,7 +510,7 @@ class DecodeEngine:
         # tp == 1: the engine's one device. Tensor-parallel mesh (tp > 1):
         # every jitted program below runs under pjit over a `model`-axis
         # mesh — params shard by the serve rules (heads / MLP hidden),
-        # pools shard on the head dim, and all host-built operands are
+        # pools shard by heads, and all host-built operands are
         # placed REPLICATED through self._put (a device-0-committed operand
         # mixed with mesh-sharded params is a placement error, not a
         # resharding).
@@ -831,11 +835,12 @@ class DecodeEngine:
 
     def _place_pools(self, pools):
         """Commit a K/V pool tree to the engine's device — or shard it over
-        the tp mesh: pools split on the head dim (each shard owns its own
+        the tp mesh: pools split by heads (each shard owns its own
         1/N-width page pool) while the page axis stays whole, so the
-        allocator's block-table arithmetic is untouched. Rank-4 value
-        pools and (int8 cache) rank-3 scale pools both split on their
-        heads axis — shape-aware per leaf, one placement."""
+        allocator's block-table arithmetic is untouched. Value pools
+        ``[pages, page_size, heads*head_dim]`` and (int8 cache) scale
+        pools ``[pages, page_size, heads]`` both carry their heads on the
+        last axis — one spec, one placement."""
         if self._mesh is None:
             return self._put(pools)
         from pytorch_distributed_training_tpu.parallel.sharding import (
@@ -869,8 +874,7 @@ class DecodeEngine:
         when speculation replaces it — and the per-bucket/chunk prefills
         share its partitioning story (and already carry donation audits).
         Tests that skip warmup skip the manifest too."""
-        hot = "serve_verify" if self.config.spec_k > 0 else "serve_decode"
-        if not self.config.warmup or name != hot:
+        if not self.config.warmup or name != self._hot_program():
             return None
         if self.config.tp > 1:
             mcfg = self._decode_model.config
@@ -885,7 +889,7 @@ class DecodeEngine:
                 1 if self.config.weights_dtype == "int8"
                 else jnp.dtype(mcfg.param_dtype).itemsize
             )
-            return serve_tp_manifest(
+            manifest = serve_tp_manifest(
                 self.config.tp,
                 layers=mcfg.num_layers,
                 hidden=mcfg.hidden_size,
@@ -895,7 +899,23 @@ class DecodeEngine:
                 weight_bytes_floor=mcfg.hidden_size * mcfg.hidden_size
                 * wbytes,
             )
-        return serve_manifest(1, name=name)
+        else:
+            manifest = serve_manifest(1, name=name)
+        if self._pages is None:
+            return manifest
+        # the same compiled text also answers whether a resident pool is
+        # rewritten whole (kv_pool_relayout_ops): hand the audit the
+        # per-device element counts of the pools (a tp shard holds 1/N)
+        return dataclasses.replace(manifest, kv_pool_elements=tuple(sorted({
+            math.prod(leaf.sharding.shard_shape(leaf.shape))
+            for leaf in jax.tree.leaves(self._cache)
+        })))
+
+    def _hot_program(self) -> str:
+        """The steady-state program of a tick, the one whose compiled text
+        is audited: the verify program when speculation replaces the
+        single-token decode step."""
+        return "serve_verify" if self.config.spec_k > 0 else "serve_decode"
 
     def _prefill_fn(self, bucket: int):
         """Jitted prefill-into-slot for one prompt bucket. Compiles once per
@@ -1262,9 +1282,9 @@ class DecodeEngine:
     @staticmethod
     def _page_copy(pools, src, dst):
         """Copy page ``src`` onto page ``dst`` in every pool leaf. The page
-        axis leads every paged leaf — rank-4 K/V pools and (int8 cache)
-        rank-3 scale pools alike — and is never sharded under tp (pools
-        split on the heads axis only), so one shard-local gather/scatter
+        axis leads every paged leaf — K/V pools and (int8 cache) scale
+        pools alike — and is never sharded under tp (pools split on their
+        last, heads-bearing axis only), so one shard-local gather/scatter
         covers every dtype and tp variant."""
         return jax.tree.map(lambda leaf: leaf.at[dst].set(leaf[src]), pools)
 
@@ -2836,6 +2856,15 @@ class DecodeEngine:
             )
         return 2 * mcfg.num_layers * mcfg.num_heads * per_head
 
+    def _kv_pool_relayout_ops(self) -> Optional[int]:
+        """Whole-pool copy/transpose/convert instructions in the compiled
+        hot program, from its comm audit's record (None before warm-up,
+        without one, or on the dense layout; 0 when every pool keeps one
+        device layout from parameter to donated result)."""
+        hot = self._guards.wrapped.get(self._hot_program())
+        record = getattr(hot, "comm_record", None) or {}
+        return record.get("kv_pool_relayout_ops")
+
     def stats(self) -> dict:
         paged = self._pages is not None
         return {
@@ -2843,6 +2872,7 @@ class DecodeEngine:
             "busy_ticks": self.busy_ticks,
             "admitted": self.admitted,
             "finished": self.finished,
+            "kv_pool_relayout_ops": self._kv_pool_relayout_ops(),
             "queue_depth": self._queue.depth(),
             "queue_depth_by_tier": self._queue.depth_by_tier(),
             "slot_occupancy": self.slot_occupancy(),

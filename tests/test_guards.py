@@ -397,13 +397,13 @@ def test_serve_retrace_violation_fails_loop_and_records():
 
         # sabotage: shrink the resident KV state so the warmed programs see
         # a NEW shape -> guarded retrace. Paged layout (default): drop a
-        # page from the [num_pages, page_size, heads, head_dim] pools;
+        # page from the [num_pages, page_size, heads * head_dim] pools;
         # dense layout: drop the trailing sequence position (axis 2 of the
         # [slots, 1, cache_len, heads, head_dim] leaves).
         engine = server.engine
         engine._cache = jax.tree.map(
             lambda g: (
-                g[:-1] if g.ndim == 4 else g[:, :, :-1] if g.ndim == 5 else g
+                g[:-1] if g.ndim == 3 else g[:, :, :-1] if g.ndim == 5 else g
             ),
             engine._cache,
         )

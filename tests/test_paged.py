@@ -38,6 +38,8 @@ from pytorch_distributed_training_tpu.serve.sampling import device_sample
 from pytorch_distributed_training_tpu.serve.server import wait_until
 from pytorch_distributed_training_tpu.utils.config import model_preset
 
+from kv_pools import fold_heads  # sibling module (pytest sys.path)
+
 pytestmark = pytest.mark.serve
 
 REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -210,7 +212,8 @@ def test_pop_ready_accept_predicate_is_strict_fifo():
 def _paged_fixture(seed=0, batch=3, heads=2, head_dim=4, page_size=4,
                    windows=3, num_pages=16):
     """Random contiguous K/V scattered into a noise-filled page pool via a
-    shuffled block table, plus the dense [B, T, H, D] mirror."""
+    shuffled block table, plus the dense [B, T, H, D] mirror. The pools
+    come back in the engine's lane-dense [N, P, H*D] shape."""
     rng = np.random.default_rng(seed)
     T = page_size * windows
     q = rng.standard_normal((batch, heads, head_dim)).astype(np.float32)
@@ -231,7 +234,8 @@ def _paged_fixture(seed=0, batch=3, heads=2, head_dim=4, page_size=4,
             k_pages[block_table[b, w]] = k[b, w * page_size:(w + 1) * page_size]
             v_pages[block_table[b, w]] = v[b, w * page_size:(w + 1) * page_size]
     lengths = np.asarray([1, T - 3, T], np.int32)[:batch]
-    return q, k, v, k_pages, v_pages, block_table, lengths
+    return (q, k, v, fold_heads(k_pages), fold_heads(v_pages), block_table,
+            lengths)
 
 
 def _dense_formula(q, k, v, lengths, scale):
@@ -248,8 +252,17 @@ def _dense_formula(q, k, v, lengths, scale):
     return jnp.einsum("bnt,btnd->bnd", probs, v)
 
 
-def test_paged_reference_bitwise_matches_dense_formula():
-    q, k, v, k_pages, v_pages, bt, lengths = _paged_fixture()
+@pytest.mark.parametrize(
+    "heads,head_dim", [(2, 4), (4, 16), (3, 64), (16, 64)],
+    ids=["lanes8", "lanes64", "lanes192", "lanes1024"],
+)
+def test_paged_reference_bitwise_matches_dense_formula(heads, head_dim):
+    """Token identity with the dense formula at every lane width: under a
+    tile (8, 64), no multiple of 128 (192) and gpt2-medium's 1024."""
+    q, k, v, k_pages, v_pages, bt, lengths = _paged_fixture(
+        heads=heads, head_dim=head_dim
+    )
+    assert k_pages.shape == (16, 4, heads * head_dim)
     scale = q.shape[-1] ** -0.5
     want = _dense_formula(
         jnp.asarray(q), jnp.asarray(k), jnp.asarray(v),
@@ -303,6 +316,23 @@ def test_paged_attention_validates_shapes():
         paged_attention(
             jnp.asarray(q), jnp.asarray(k_pages), jnp.asarray(v_pages),
             jnp.asarray(bt), jnp.asarray(lengths)[:-1], scale=1.0,
+        )
+    # pools are lane-dense [N, P, H*D]: the unfolded [N, P, H, D] form is
+    # refused by rank, and a q whose heads x head_dim does not fold to the
+    # pools' lane axis by name
+    unfolded = jnp.asarray(k_pages).reshape(16, 4, 2, 4)
+    with pytest.raises(ValueError, match=r"rank 4, want 3"):
+        paged_attention(
+            jnp.asarray(q), unfolded, unfolded, jnp.asarray(bt),
+            jnp.asarray(lengths), scale=1.0,
+        )
+    with pytest.raises(
+        ValueError, match=r"axis 'heads\*head_dim': q has 2 x 2 = 4"
+    ):
+        paged_attention(
+            jnp.asarray(q)[..., :2], jnp.asarray(k_pages),
+            jnp.asarray(v_pages), jnp.asarray(bt), jnp.asarray(lengths),
+            scale=1.0,
         )
 
 

@@ -40,6 +40,8 @@ from pytorch_distributed_training_tpu.serve import (
 from pytorch_distributed_training_tpu.serve.server import wait_until
 from pytorch_distributed_training_tpu.utils.config import model_preset
 
+from kv_pools import fold_heads  # sibling module (pytest sys.path)
+
 pytestmark = [pytest.mark.serve]
 
 REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -224,6 +226,8 @@ def test_int8_kv_ops_tolerance_band():
     kq, ks = quantize_kv(k)
     vq, vs = quantize_kv(v)
     assert kq.dtype == jnp.int8 and ks.shape == (P, S, HEADS)
+    # the engine's lane-dense pools; the scale pools stay per head
+    k, v, kq, vq = (fold_heads(x) for x in (k, v, kq, vq))
 
     exact = paged_attention(q, k, v, bt, lengths, scale=scale,
                             impl="reference")
@@ -276,8 +280,8 @@ def test_int8_kv_serving_band_and_pool_accounting(lm, snapped):
 
 
 def test_int8_kv_pool_leaves_are_int8_with_fp32_scales(lm):
-    """The resident cache of an int8-KV engine holds int8 rank-4 page
-    pools and fp32 rank-3 scale pools of the matching leading shape."""
+    """The resident cache of an int8-KV engine holds int8 lane-dense page
+    pools [N, P, H*D] and fp32 scale pools [N, P, H] beside them."""
     model, params = lm
     server = InferenceServer(
         model, params,
@@ -287,13 +291,13 @@ def test_int8_kv_pool_leaves_are_int8_with_fp32_scales(lm):
             weights_dtype="int8", kv_dtype="int8",
         ),
     )
-    pools = [x for x in jax.tree.leaves(server.engine._cache) if x.ndim == 4]
-    scales = [x for x in jax.tree.leaves(server.engine._cache) if x.ndim == 3]
-    assert pools and scales and len(pools) == len(scales)
+    leaves = jax.tree.leaves(server.engine._cache)
+    pools = [x for x in leaves if x.dtype == jnp.int8]
+    scales = [x for x in leaves if x.dtype == jnp.float32]
+    assert pools and len(pools) == len(scales) == len(leaves) // 2
     for pool, sc in zip(pools, scales):
-        assert pool.dtype == jnp.int8
-        assert sc.dtype == jnp.float32
-        assert sc.shape == pool.shape[:3]
+        assert pool.shape[2] == HEADS * HEAD_DIM
+        assert sc.shape == (*pool.shape[:2], HEADS)
 
 
 # ------------------------------------------------------- tensor parallel
@@ -304,7 +308,7 @@ def test_tp2_int8_bit_identical_to_tp1_int8(lm, snapped):
     """Quantization composes with head sharding: the tp=2 full-int8 engine
     emits bit-identical greedy streams to the tp=1 full-int8 engine, the
     kernel_scale leaves shard with their kernel's channel axis, and the
-    rank-3 scale pools shard on the head axis like their page pools."""
+    scale pools shard on their last (heads) axis like their page pools."""
     from pytorch_distributed_training_tpu.parallel.sharding import (
         serve_pool_pspec,
     )
@@ -336,14 +340,15 @@ def test_tp2_int8_bit_identical_to_tp1_int8(lm, snapped):
             np.testing.assert_array_equal(
                 a, np.asarray(r.tokens, np.int32), err_msg=f"request {i}"
             )
-        scale_pools = [
-            x for x in jax.tree.leaves(server.engine._cache) if x.ndim == 3
-        ]
-        assert scale_pools
-        for sc in scale_pools:
-            assert sc.sharding.spec == serve_pool_pspec(3)
-            shard = sc.sharding.shard_shape(sc.shape)
-            assert shard[2] == HEADS // 2
+        leaves = jax.tree.leaves(server.engine._cache)
+        assert {x.dtype for x in leaves} == {
+            jnp.dtype(jnp.int8), jnp.dtype(jnp.float32)
+        }
+        for leaf in leaves:
+            assert leaf.sharding.spec == serve_pool_pspec()
+            shard = leaf.sharding.shard_shape(leaf.shape)
+            per_head = HEAD_DIM if leaf.dtype == jnp.int8 else 1
+            assert shard[2] == HEADS // 2 * per_head
     finally:
         server.close()
 
@@ -490,6 +495,7 @@ def test_scale_pool_validation_named_axes():
     lengths = jnp.asarray([3, 7], jnp.int32)
     kq, ks = quantize_kv(k)
     vq, vs = quantize_kv(v)
+    k, v, kq, vq = (fold_heads(x) for x in (k, v, kq, vq))
     kw = dict(scale=1.0, impl="reference")
 
     with pytest.raises(ValueError, match="k_scales is missing"):

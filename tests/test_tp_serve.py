@@ -220,10 +220,10 @@ def test_tp_requires_paged_device_sampling():
 
 
 def test_tp_pool_sharding_arithmetic(lm):
-    """The paged pools shard ONLY on the head axis: the page axis stays
-    whole (allocator arithmetic and block tables are tp-invariant), each
-    shard holds heads/tp heads, and pool capacity matches the tp=1
-    engine's exactly."""
+    """The lane-dense paged pools [N, P, H*D] shard ONLY on their last
+    axis: the page axis stays whole (allocator arithmetic and block tables
+    are tp-invariant), each shard holds a contiguous block of heads/tp
+    heads, and pool capacity matches the tp=1 engine's exactly."""
     from pytorch_distributed_training_tpu.parallel.sharding import (
         serve_pool_pspec,
     )
@@ -240,18 +240,16 @@ def test_tp_pool_sharding_arithmetic(lm):
         ).engine
 
     e1, e2 = engine(1), engine(2)
-    pool_leaves = [
-        leaf for leaf in jax.tree.leaves(e2._cache) if leaf.ndim == 4
-    ]
+    pool_leaves = jax.tree.leaves(e2._cache)
     assert pool_leaves
     want_spec = serve_pool_pspec()
     for leaf in pool_leaves:
         assert leaf.sharding.spec == want_spec
-        num_pages, page_size, heads, _head_dim = leaf.shape
+        num_pages, page_size, lanes = leaf.shape
         shard = leaf.sharding.shard_shape(leaf.shape)
-        # page/page-size/head_dim axes whole, head axis split
+        # page and page-size axes whole, the folded heads axis split
         assert shard[0] == num_pages and shard[1] == page_size
-        assert shard[2] == heads // 2 == HEADS // 2
+        assert shard[2] == lanes // 2 == HIDDEN // 2
     # allocator arithmetic is untouched by sharding: identical capacity
     s1, s2 = e1.stats(), e2.stats()
     assert s1["kv_pages_total"] == s2["kv_pages_total"]

@@ -16,6 +16,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from kv_pools import fold_heads  # sibling module (pytest sys.path)
+
 pytestmark = pytest.mark.tpu
 
 
@@ -292,10 +294,10 @@ def test_int8_dense_numerics_on_real_mxu():
 
 def _paged_case(pool_dtype, q_len, *, page_size=128, heads=16, head_dim=64,
                 batch=4, windows=3, seed=0):
-    """Random pools at gpt2-medium's head geometry (16 x 64) and the
-    lane-width page size; lengths cover one token, a mid-page tail, a full
-    table and a page boundary; entries past the live length park on the
-    null page like the engine's."""
+    """Random lane-dense pools at gpt2-medium's head geometry (16 x 64)
+    and the lane-width page size; lengths cover one token, a mid-page
+    tail, a full table and a page boundary; entries past the live length
+    park on the null page like the engine's."""
     from pytorch_distributed_training_tpu.ops.quant import quantize_kv
 
     rng = np.random.default_rng(seed)
@@ -321,12 +323,11 @@ def _paged_case(pool_dtype, q_len, *, page_size=128, heads=16, head_dim=64,
     if pool_dtype == jnp.int8:
         kq, ks = quantize_kv(jnp.asarray(k))
         vq, vs = quantize_kv(jnp.asarray(v))
-        return (jnp.asarray(q), kq, vq, bt, lengths), dict(
-            k_scales=ks, v_scales=vs
-        )
+        return (jnp.asarray(q), fold_heads(kq), fold_heads(vq), bt,
+                lengths), dict(k_scales=ks, v_scales=vs)
     return (
-        jnp.asarray(q, pool_dtype), jnp.asarray(k, pool_dtype),
-        jnp.asarray(v, pool_dtype), bt, lengths,
+        jnp.asarray(q, pool_dtype), fold_heads(jnp.asarray(k, pool_dtype)),
+        fold_heads(jnp.asarray(v, pool_dtype)), bt, lengths,
     ), {}
 
 

@@ -186,15 +186,16 @@ def test_flash_attention_short_sequence_compiles(one_chip, seq, grad):
 @pytest.mark.parametrize("page_size", [16, 128])
 @pytest.mark.parametrize("q_len", [None, 8], ids=["single", "multi"])
 def test_paged_attention_kernels_lower(pool_dtype, page_size, q_len):
-    """Both page-walk kernels, float and int8 pools, at gpt2-medium's head
-    geometry; page size 16 (the CLI default) and 128 (the lane width)."""
+    """Both page-walk kernels, float and int8 pools (lane-dense, as the
+    engine holds them), at gpt2-medium's head geometry; page size 16 (the
+    CLI default) and 128 (the lane width)."""
     batch, windows = 4, 3
     num_pages = 1 + batch * windows
     quantized = pool_dtype == jnp.int8
     q_shape = (batch, HEADS, HEAD_DIM)
     if q_len is not None:
         q_shape = (batch, q_len, HEADS, HEAD_DIM)
-    pools = [S((num_pages, page_size, HEADS, HEAD_DIM), pool_dtype)] * 2
+    pools = [S((num_pages, page_size, HEADS * HEAD_DIM), pool_dtype)] * 2
     scales = [S((num_pages, page_size, HEADS), F32)] * 2 if quantized else []
 
     def f(q, kp, vp, bt, ln, *sc):
