@@ -32,7 +32,7 @@ import threading
 import time
 import types
 
-from harness import adapters, compare, flops, traffic, trace_reduce, weights
+from harness import adapters, compare, family, traffic, trace_reduce
 from harness import window as win_math
 
 
@@ -156,7 +156,7 @@ def parse_log(sink: Sink, feed: Feed) -> dict:
     return entries
 
 
-def _patch_weights(ctx, spec):
+def _patch_weights(ctx, source):
     """`serve_lm` loads its model through `generate_lm`: hand it the
     benchmark's seeded weights in place of its own random ones."""
     from pytorch_distributed_training_tpu.cli import generate_lm
@@ -165,9 +165,7 @@ def _patch_weights(ctx, spec):
 
     def load(args, tok):
         model, params, step = original(args, tok)
-        params = adapters.install(
-            params, spec, weights.seed_key(ctx["seed"]),
-            ctx["config"]["adapter"], weights.std_of(ctx["config"]))
+        params = adapters.install(params, source, family.of(ctx["config"]))
         return model, params, step
 
     generate_lm.load_model_and_params = load
@@ -177,12 +175,13 @@ def _patch_weights(ctx, spec):
 def serve(ctx, phases, *, drain_s, trace_at=None, extra_argv=()):
     """One server life, fed `phases`: returns the feed, the log entries by
     request id, the engine's stats, the telemetry records, the tracer's
-    notes and the weight spec, as attributes."""
+    notes and the source of the seeded weights, as attributes."""
     from pytorch_distributed_training_tpu.cli import serve_lm
 
     config = ctx["config"]
     reference = importlib.import_module("reference." + config["reference"])
-    spec = reference.weight_spec(config["model"])
+    source = family.source(
+        config, reference.weight_spec(config["model"]), ctx["seed"])
     sink = Sink()
     tracer = {}
 
@@ -212,7 +211,7 @@ def serve(ctx, phases, *, drain_s, trace_at=None, extra_argv=()):
         metrics_dir = os.path.join(ctx["work_dir"], "metrics")
         argv += ["--metrics-dir", metrics_dir]
     print(f"benchmark: cli.serve_lm.main({argv})", flush=True)
-    restore = _patch_weights(ctx, spec)
+    restore = _patch_weights(ctx, source)
     if ctx.get("sabotage"):
         # tests only: the timed path broken underneath the harness
         ctx["sabotage"]()
@@ -228,7 +227,7 @@ def serve(ctx, phases, *, drain_s, trace_at=None, extra_argv=()):
             records = [json.loads(line) for line in f if line.strip()]
     return types.SimpleNamespace(
         feed=feed, entries=parse_log(sink, feed), stats=stats,
-        records=records, tracer=tracer, spec=spec)
+        records=records, tracer=tracer, source=source)
 
 
 def pick_sample(entries: list, requests: dict, seed: int, check: dict):
@@ -254,6 +253,10 @@ def run(ctx) -> dict:
     import jax
 
     config, mix = ctx["config"], ctx["traffic"]
+    # the family's counts, looked for before the server is built: a
+    # configuration whose family brings none exits here, naming it
+    count_prefill = family.count(config, "prefill_flops")
+    count_decode = family.count(config, "decode_flops")
     ramp_s, seconds = float(mix["ramp_s"]), float(ctx["seconds"])
     shutil.rmtree(ctx["work_dir"], ignore_errors=True)
     os.makedirs(ctx["work_dir"], exist_ok=True)
@@ -268,7 +271,7 @@ def run(ctx) -> dict:
     served = serve(
         ctx, [(requests, ramp_s + seconds)], drain_s=float(mix["drain_s"]),
         trace_at=trace_at, extra_argv=control)
-    feed, tracer, spec = served.feed, served.tracer, served.spec
+    feed, tracer = served.feed, served.tracer
     entries, stats, records = served.entries, served.stats, served.records
 
     w0 = feed.t_ready + ramp_s
@@ -280,13 +283,18 @@ def run(ctx) -> dict:
     ttft = win_math.ttfts(log, w0, w1, miss_at=feed.t_end)
     gaps = win_math.token_gaps(log, w0, w1)
     rate = win_math.tokens_per_s(log, w0, w1)
+    ttft_p95 = win_math.percentile(ttft, 95)
+    tpot_p95 = win_math.percentile(gaps, 95)
     peak = max(
         (d.memory_stats() or {}).get("peak_bytes_in_use", 0)
         for d in jax.local_devices())
     from pytorch_distributed_training_tpu.ops import dispatch
 
     print(f"benchmark: window {seconds:.1f}s after {ramp_s:.0f}s ramp: "
-          f"{len(due)} requests due, {failed} failed, {rate:.1f} tokens/s; "
+          f"{len(due)} requests due, {failed} failed, {rate:.1f} tokens/s, "
+          f"first token p95 {1e3 * (ttft_p95 or 0):.1f} ms, token gap p50 "
+          f"{1e3 * (win_math.percentile(gaps, 50) or 0):.1f} p95 "
+          f"{1e3 * (tpot_p95 or 0):.1f} ms; "
           f"feed ran at most {1e3 * feed.max_lag_s:.2f} ms late; setup "
           f"{setup_s:.2f}s; engine {json.dumps(stats, default=str)[:600]}; "
           f"dispatch paths {dict(dispatch.DISPATCH_PATHS)}", flush=True)
@@ -296,20 +304,23 @@ def run(ctx) -> dict:
                   flush=True)
 
     # work of the window, for the shares of the peak: prompts whose first
-    # token arrived in it, and every token decoded in it with its context
+    # token arrived in it, and every token decoded in it with its context,
+    # counted by the configuration's family, which is handed what the run
+    # observed of the request beside the sizes
     prefill_flops = decode_flops = 0.0
     window_contexts = []
     trace_contexts = []
     t_tr = (tracer.get("t0"), tracer.get("t1"))
     for e in log:
         r = by_id[e["id"]]
+        observed = {"request": r, "entry": e, "engine": stats}
         for j, t in enumerate(e["tokens"]):
             context = r.prompt_len + j
             if w0 <= t < w1:
                 if j == 0:
-                    prefill_flops += flops.prefill_flops(config["model"], r.prompt_len)
+                    prefill_flops += count_prefill(config, r.prompt_len, observed)
                 else:
-                    decode_flops += flops.decode_flops(config["model"], context)
+                    decode_flops += count_decode(config, context, observed)
                     window_contexts.append(context)
             if j and t_tr[0] is not None and t_tr[0] <= t < t_tr[1]:
                 trace_contexts.append(context)
@@ -323,7 +334,11 @@ def run(ctx) -> dict:
             trace["contexts"] = trace_contexts
 
     reference = importlib.import_module("reference." + config["reference"])
-    sample = pick_sample(due, by_id, ctx["seed"], config["check"])
+    # how many requests make the sample's hundreds of served tokens is the
+    # mix's to say, where its outputs are shorter than the configuration
+    # reckoned with
+    sample = pick_sample(due, by_id, ctx["seed"],
+                         {**config["check"], **mix.get("check", {})})
     pairs = [
         ([ord(c) for c in by_id[e["id"]].prompt], e["token_ids"])
         for e in sample
@@ -332,8 +347,7 @@ def run(ctx) -> dict:
     ref_control = (config["control"]["reference_precision"]
                    if ctx.get("read_faults") else None)
     ref = reference.served_token_gaps(
-        config, weights.make(spec, ctx["seed"], weights.std_of(config)), pairs,
-        control=ref_control)
+        config, served.source, pairs, control=ref_control)
     ref_s = time.perf_counter() - t_ref
     checks = [
         compare.Check("max_logit_gap",
@@ -353,8 +367,8 @@ def run(ctx) -> dict:
         "failed": failed,
         "end_to_end": {
             "serve.tokens_per_s": rate,
-            "serve.ttft_p95_ms": 1e3 * win_math.percentile(ttft, 95),
-            "serve.tpot_p95_ms": 1e3 * win_math.percentile(gaps, 95),
+            "serve.ttft_p95_ms": 1e3 * ttft_p95,
+            "serve.tpot_p95_ms": 1e3 * tpot_p95,
             "setup_s": setup_s,
         },
         "memory_peak_bytes": peak,

@@ -157,14 +157,24 @@ def host_label(host_events, a: int, b: int) -> str:
     return best
 
 
+#: the program's own phase spans (`telemetry.spans.Phase`); everything
+#: else on a host line is a frame of the profiler's Python tracer or a
+#: runtime thread's event, whose names have no place in a result line
+PHASE_NAMES = re.compile(
+    r"(serve_tick|serve_setup|warm_start)(\..*)?|train_step|eval_step")
+
+
 def host_spans(planes: dict) -> list:
-    """The host's Python spans (the profiler's Python tracer and the
-    program's own `TraceAnnotation`s), all threads together."""
-    out = []
-    for name, lines in planes.items():
-        if name.startswith("/host:"):
-            out.extend(lines.get("python", ()))
-    return out
+    """The program's phase spans on the host, all threads together: every
+    line of every `/host:` plane (the line that holds them is named after
+    the command, `python3` on the chip, `python` elsewhere), kept to the
+    program's phase names."""
+    return [
+        ev
+        for name, lines in planes.items() if name.startswith("/host:")
+        for events in lines.values()
+        for ev in events if PHASE_NAMES.fullmatch(ev[0])
+    ]
 
 
 SHORT_GAP_NS = 20_000

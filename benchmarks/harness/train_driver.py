@@ -30,16 +30,16 @@ import time
 
 import numpy as np
 
-from harness import adapters, compare, trace_reduce, weights
+from harness import adapters, compare, family, trace_reduce
 
 BATCH_KEYS = ("input_ids", "attention_mask", "token_type_ids", "labels")
 
 
-def _norms_by_name(tree, family) -> dict:
+def _norms_by_name(tree, fam) -> dict:
     import jax.numpy as jnp
 
     return {
-        adapters.leaf_name(k, family): jnp.sqrt(
+        adapters.leaf_name(k, fam): jnp.sqrt(
             jnp.sum(jnp.square(v.astype(jnp.float32))))
         for k, v in adapters.flat(tree).items()}
 
@@ -59,8 +59,8 @@ def _find_mu(opt_state):
 class Window:
     """What the epoch wrapper notes; one per run."""
 
-    def __init__(self, ctx, spec, n_check):
-        self.ctx, self.spec, self.n_check = ctx, spec, n_check
+    def __init__(self, ctx, source, n_check):
+        self.ctx, self.source, self.n_check = ctx, source, n_check
         self.losses = []          # device scalars, one per dispatched step
         self.batches = []         # host copies of the checked batches
         self.grad1 = None         # {leaf: device scalar}
@@ -82,19 +82,17 @@ def _epoch_wrapper(win: Window, trainer, orig_epoch):
     ctx = win.ctx
     traffic = ctx["traffic"]
     b1 = ctx["config"]["recipe"]["adam_b1"]
-    key = weights.seed_key(ctx["seed"])
-    family = ctx["config"]["adapter"]
-    std = weights.std_of(ctx["config"])
+    key = win.source.key()
+    fam = family.of(ctx["config"])
 
     def grad1_norms(mu):
         return {k: v / (1.0 - b1)
-                for k, v in _norms_by_name(mu, family).items()}
+                for k, v in _norms_by_name(mu, fam).items()}
 
     def delta_norms(params, key):
-        start = adapters.to_program(
-            weights.generate(win.spec, key, std), params, family)
+        start = adapters.to_program(win.source.generate(key), params, fam)
         return _norms_by_name(
-            jax.tree.map(lambda a, b: a - b, params, start), family)
+            jax.tree.map(lambda a, b: a - b, params, start), fam)
 
     def bounded():
         # never more than two steps ahead of the device, as a loop that
@@ -179,9 +177,14 @@ def run(ctx) -> dict:
 
     config, traffic = ctx["config"], ctx["traffic"]
     reference = importlib.import_module("reference." + config["reference"])
-    spec = reference.weight_spec(config["model"])
+    # looked for before anything is built: a configuration whose family
+    # brings no count for a training cell exits here, naming it
+    family.count(config, "train_flops_per_sample")
+    fam = family.of(config)
+    source = family.source(
+        config, reference.weight_spec(config["model"]), ctx["seed"])
     n_check = config["check"]["updates"]
-    win = Window(ctx, spec, n_check)
+    win = Window(ctx, source, n_check)
     win.trace_dir = os.path.join(ctx["work_dir"], "trace")
     shutil.rmtree(ctx["work_dir"], ignore_errors=True)
     os.makedirs(ctx["work_dir"], exist_ok=True)
@@ -193,9 +196,8 @@ def run(ctx) -> dict:
             super().__init__(model_config, *a, **k)
             win.trainer = self
             old, self.state = self.state.params, self.state.replace(params=None)
-            self.state = self.state.replace(params=adapters.install(
-                old, spec, weights.seed_key(ctx["seed"]), config["adapter"],
-                weights.std_of(config)))
+            self.state = self.state.replace(
+                params=adapters.install(old, source, fam))
             del old
             self.train_loader.epoch = _epoch_wrapper(
                 win, self, self.train_loader.epoch)
@@ -267,12 +269,8 @@ def run(ctx) -> dict:
             trace = trace_reduce.reduce(planes)
             trace["steps"] = win.trace_steps
 
-    def make_weights():
-        return weights.make(spec, ctx["seed"], weights.std_of(config))
-
     t_ref = time.perf_counter()
-    ref = reference.train(
-        config, make_weights, win.batches)
+    ref = reference.train(config, source, win.batches)
     ref_s = time.perf_counter() - t_ref
     checks, where = compare.training_checks(prog, ref, config["limits"])
     if ctx.get("read_faults"):
@@ -284,7 +282,7 @@ def run(ctx) -> dict:
         if lower:
             plant.append(("reference_" + lower, dict(precision=lower)))
         for name, how in plant:
-            other = reference.train(config, make_weights, win.batches, **how)
+            other = reference.train(config, source, win.batches, **how)
             where["faults"][name] = {
                 c.name: c.value for c in compare.training_checks(
                     other, ref, config["limits"])[0]}

@@ -1,7 +1,9 @@
 """The whole model step's share of the chip's bf16 peak over the window:
 operations of the prompts whose first token arrived in it (causal prefill,
 head on the last token) and of every token decoded in it over its own
-context (`flops.py`), over window seconds times chips times peak."""
+context, both counted by the configuration's family file
+(`prefill_flops`, `decode_flops`; the driver sums them as the tokens
+arrive), over window seconds times chips times peak."""
 
 
 def read(obs):

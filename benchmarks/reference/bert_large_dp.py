@@ -93,13 +93,13 @@ def leaf_norms(tree: dict, model: dict) -> dict:
     return out
 
 
-def train(config: dict, weights_fn, batches: list, *,
+def train(config: dict, source, batches: list, *,
           precision: str = "float32", fault: str | None = None) -> dict:
-    """Follow `len(batches)` optimizer updates from `weights_fn()`.
+    """Follow `len(batches)` optimizer updates from `source.whole()`.
 
-    `weights_fn` makes a fresh copy of the seeded weights (called twice:
-    the start is not kept through the updates, so that the reference fits
-    beside nothing but itself). `batches`: per update a dict of
+    `source` (`harness/weights.py::Source`) makes a fresh copy of the
+    seeded weights, taken whole (twice: the start is not kept through the
+    updates, so that the reference fits beside nothing but itself). `batches`: per update a dict of
     [accum, micro, ...] integer arrays as the program's step was fed them.
     Returns each update's loss, the per-leaf norms of the first gradient
     (and of its micro-batches' gradients, averaged: `grad1_scale`) and of
@@ -138,7 +138,7 @@ def train(config: dict, weights_fn, batches: list, *,
     norms = jax.jit(functools.partial(leaf_norms, model=model))
     delta_norms = jax.jit(lambda a, b: leaf_norms(
         jax.tree.map(jnp.subtract, a, b), model))
-    w = weights_fn()
+    w = source.whole()
     mu = jax.tree.map(jnp.zeros_like, w)
     nu = jax.tree.map(jnp.zeros_like, w)
     losses, grad1, scale1 = [], None, None
@@ -167,7 +167,7 @@ def train(config: dict, weights_fn, batches: list, *,
             w, mu, nu = adam(w, mu, nu, g_acc, learning_rate(recipe, t - 1), t=t)
         del g_acc
     del mu, nu
-    delta = delta_norms(w, weights_fn())
+    delta = delta_norms(w, source.whole())
     return {
         "losses": losses,
         "grad1_norms": grad1,

@@ -20,7 +20,8 @@ import numpy as np
 
 from reference import block
 
-PAD_TO = 128  # sequences are padded to a multiple: few compiled shapes
+PAD_TO = 128  # sequences and their served tails are padded to a multiple:
+# few compiled shapes, the same from run to run, so the compile cache holds
 
 
 def weight_spec(model: dict) -> dict:
@@ -67,9 +68,11 @@ def _gaps(weights, ids, positions, tokens, model_items, control):
     return served, best[positions] - ref[positions, first]
 
 
-def served_token_gaps(config: dict, weights: dict, samples: list,
+def served_token_gaps(config: dict, source, samples: list,
                       control: str | None = None) -> dict:
-    """`samples`: (prompt ids, served ids) pairs. For every served token,
+    """`source`: the run's seeded weights (`harness/weights.py::Source`),
+    taken whole: 1.4 GB in float32 at gpt2-medium, beside nothing else.
+    `samples`: (prompt ids, served ids) pairs. For every served token,
     the gap by which its logit lies below the reference's best at its
     position; with `control`, also the gap of the token the same
     mathematics in that lower precision puts first there (calibration and
@@ -77,6 +80,7 @@ def served_token_gaps(config: dict, weights: dict, samples: list,
     model_items = tuple(sorted(
         (k, v) for k, v in config["model"].items()
         if isinstance(v, (int, float))))
+    weights = source.whole()
     worst, worst_control, n = 0.0, 0.0, 0
     for prompt, served in samples:
         if not len(served):
@@ -85,10 +89,15 @@ def served_token_gaps(config: dict, weights: dict, samples: list,
         padded = -(-len(seq) // PAD_TO) * PAD_TO
         ids = np.zeros(min(padded, config["model"]["n_positions"]), np.int32)
         ids[: len(seq)] = seq
-        positions = len(prompt) - 1 + np.arange(len(served), dtype=np.int32)
+        # the tail padded with copies of its first entry: a gap read twice
+        # leaves the widest as it is
+        tail = -(-len(served) // PAD_TO) * PAD_TO
+        positions = np.full(tail, len(prompt) - 1, np.int32)
+        positions[: len(served)] += np.arange(len(served), dtype=np.int32)
+        tokens = np.full(tail, served[0], np.int32)
+        tokens[: len(served)] = served
         s, c = _gaps(weights, jnp.asarray(ids), jnp.asarray(positions),
-                     jnp.asarray(np.asarray(served, np.int32)),
-                     model_items, control)
+                     jnp.asarray(tokens), model_items, control)
         worst = max(worst, float(s.max()))
         worst_control = max(worst_control, float(c.max()))
         n += len(served)

@@ -4,10 +4,16 @@
 
 Everything about a cell is data, found by name: the cell's entry in
 `BENCHMARK.json` names its configuration (`benchmarks/configs/<config>.json`,
-which names its driver module and its plain reference) and its traffic mix
+which names its driver module, its plain reference
+`benchmarks/reference/<reference>.py` and its model family
+`benchmarks/families/<adapter>.py`: the map between the program's
+parameter tree and the reference's leaves, the operation and byte counts,
+weight kinds of its own; `harness/family.py`) and its traffic mix
 (`benchmarks/traffic/<traffic>.json`); a per-layer metric is read by
 `benchmarks/metrics/<metric>.py::read(observations)`. No list of cells,
-drivers or metrics lives in code: a later PR adds files and entries.
+drivers, families or metrics lives in code: a later PR adds files and
+entries, a new model family among them
+(`tests/benchmarks/test_family_files.py` adds one).
 
 The last line of standard output is the result, one JSON object; the
 numbers `correct` was decided from are printed beside their limits as the

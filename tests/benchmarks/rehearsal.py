@@ -1,7 +1,7 @@
 """A temporary copy of the benchmark with tiny configurations added as
 files and entries only: how the tests rehearse `benchmarks/run.py` on the
-CPU, and the proof that a configuration, a cell and a per-layer metric
-need no edit of a file that is there. Never a cell: the configurations are
+CPU, and the proof that a configuration, a model family, a cell and a
+per-layer metric need no edit of a file that is there. Never a cell: the configurations are
 flagged `"rehearsal": true`, which is what lets `run.py` leave the chip.
 """
 
@@ -72,12 +72,26 @@ TINY_CHAT = {
     "output_tokens": {"median": 8, "sigma": 0.5, "min": 2, "max": 24},
     "trace_after_s": 0.5, "trace_s": 0.5,
 }
+# long prompts in, a few tokens out, as `summarize_prefill`: the sample
+# needs more requests than the configuration's four to hold 60 tokens
+TINY_SUMMARIZE = {
+    "kind": "open_loop", "shape_seed": 4, "rate_rps": 10.0, "ramp_s": 1,
+    "drain_s": 30,
+    "prompt_tokens": {"median": 24, "sigma": 0.3, "min": 8, "max": 32},
+    "output_tokens": {"median": 4, "sigma": 0.5, "min": 2, "max": 8},
+    "trace_after_s": 0.5, "trace_s": 0.5,
+    "check": {"tokens": 60, "max_requests": 24},
+}
 
 
 def make_checkout(tmp: str, *, configs=(), traffic=(), cells=(),
-                  per_layer=(), readers=(), end_to_end_cells=()) -> str:
+                  per_layer=(), readers=(), end_to_end_cells=(),
+                  listed=(), files=()) -> str:
     """Copy `BENCHMARK.json` and `benchmarks/` into `tmp`, link the system
-    under test beside them, and ADD the given files and entries."""
+    under test beside them, and ADD the given files and entries (`files`:
+    `(path under benchmarks/, text)`, a family's or a reference's;
+    `listed`: `(per-layer metric, cell)`, the cell's name added to the
+    list of a metric that is there, as a PR that adds a cell does)."""
     root = os.path.join(tmp, "checkout")
     os.makedirs(root)
     shutil.copytree(os.path.join(REPO, "benchmarks"),
@@ -106,9 +120,14 @@ def make_checkout(tmp: str, *, configs=(), traffic=(), cells=(),
                 "bound": 0.1, "source": "host_clock", "workloads": []}
             bench["end_to_end"].append(have[metric])
         have[metric]["workloads"].append(cell)
+    for metric, cell in listed:
+        named, = [m for m in bench["per_layer"] if m["name"] == metric]
+        named["workloads"].append(cell)
     bench["per_layer"].extend(per_layer)
     for name, source in readers:
         _write_new(os.path.join(root, f"benchmarks/metrics/{name}.py"), source)
+    for rel, text in files:
+        _write_new(os.path.join(root, "benchmarks", rel), text)
     with open(os.path.join(root, "BENCHMARK.json"), "w") as f:
         json.dump(bench, f, indent=1)
     return root
@@ -145,11 +164,22 @@ TINY_CELLS = [
      "chips": 1, "why": "tests only"},
     {"name": "tiny_chat1", "config": "tiny_paged", "traffic": "tiny_chat",
      "chips": 1, "why": "tests only"},
+    {"name": "tiny_prefill1", "config": "tiny_paged",
+     "traffic": "tiny_summarize", "chips": 1, "why": "tests only"},
+]
+#: the readers that take the harness's own observations, which a
+#: rehearsal has too (shares of a peak read nothing without a chip)
+TINY_LISTED = [
+    ("train.data_wait_ms", "tiny_dp1"), ("train.mfu", "tiny_dp1"),
+    ("train.device_idle", "tiny_dp1"),
+    *[(m, c) for m in ("serve.tpot_p50_ms", "serve.mfu", "serve.device_idle")
+      for c in ("tiny_chat1", "tiny_prefill1")],
 ]
 TINY_END_TO_END = [
     ("train.samples_per_s_per_chip", "tiny_dp1"),
     ("serve.ttft_p95_ms", "tiny_chat1"),
     ("serve.tpot_p95_ms", "tiny_chat1"),
+    ("serve.tpot_p95_ms", "tiny_prefill1"),
 ]
 
 
@@ -159,9 +189,10 @@ def make_tiny_checkout(tmp: str, **more) -> str:
     more.setdefault("cells", [])
     return make_checkout(
         tmp, configs=[TINY_BERT, TINY_GPT2],
-        traffic=[("tiny_steady", TINY_STEADY), ("tiny_chat", TINY_CHAT)],
+        traffic=[("tiny_steady", TINY_STEADY), ("tiny_chat", TINY_CHAT),
+                 ("tiny_summarize", TINY_SUMMARIZE)],
         cells=TINY_CELLS + list(more.pop("cells")),
-        end_to_end_cells=TINY_END_TO_END, **more)
+        end_to_end_cells=TINY_END_TO_END, listed=TINY_LISTED, **more)
 
 
 def run_script(root: str, script: str, *args, timeout=600):

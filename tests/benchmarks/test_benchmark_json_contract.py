@@ -115,9 +115,18 @@ def test_metrics(bench):
             assert m["unit"] == "%"
     assert all(layers_of_cell.values())
     # the whole step's share of the peak, in every cell of its kind
-    for kind, e in (("train.mfu", "train.samples_per_s_per_chip"),):
+    for kind, e in (("train.mfu", "train.samples_per_s_per_chip"),
+                    ("serve.mfu", "serve.tpot_p95_ms")):
         m = next(m for m in layer if m["name"] == kind)
         assert set(m.get("workloads", reports[m["moves"]])) == reports[e]
+
+
+def test_every_per_layer_metric_lists_its_cells(bench):
+    """A PR that adds a cell is refused while a metric that moves its
+    end-to-end metric has no list: the new cell would have to report a
+    metric whose reader may find nothing to read there."""
+    for m in bench["per_layer"]:
+        assert m.get("workloads"), m["name"]
 
 
 def test_files_under_paths_are_named_from_name_characters(bench):

@@ -36,13 +36,19 @@ def over_limit(result, numbers: dict) -> list:
 
 
 @pytest.mark.parametrize("cell,control", [
-    ("tiny_dp1", "reference_bfloat16"), ("tiny_chat1", "reference_int8")])
+    ("tiny_dp1", "reference_bfloat16"), ("tiny_chat1", "reference_int8"),
+    ("tiny_prefill1", "reference_int8")])
 def test_sound_run_is_correct_and_its_control_is_not(checkout, cell, control):
     result = drive(checkout, cell, "control")
     assert result["correct"] is True, result["checks"]
     assert result["failed"] == 0 and result["attempted"] > 0
     # the same numbers, read off the reference one precision down
     assert over_limit(result, result["notes"]["faults"][control])
+    if cell == "tiny_prefill1":
+        # short outputs: the mix's own `check` asks for more requests than
+        # the configuration's four, so that the sample still holds 60 tokens
+        assert result["notes"]["checked_requests"] > 4
+        assert result["notes"]["checked_tokens"] >= 60
 
 
 def test_half_batch_planted_in_the_reference_reads_over_the_limits(checkout):
@@ -56,6 +62,7 @@ def test_half_batch_planted_in_the_reference_reads_over_the_limits(checkout):
     ("tiny_dp1", "unchanged_state", {"grad1_norm_gap", "delta_norm_gap"}),
     ("tiny_dp1", "half_batch", {"grad1_norm_gap"}),
     ("tiny_chat1", "altered_token", {"max_logit_gap"}),
+    ("tiny_prefill1", "altered_token", {"max_logit_gap"}),
 ])
 def test_fault_under_the_harness_comes_out_not_correct(
         checkout, cell, fault, caught_by):

@@ -62,6 +62,29 @@ def test_reduce_reports_window_busy_and_breakdown():
     assert out["per_name_s"]["dal_kernel"] == pytest.approx(100e-6)
 
 
+@pytest.mark.parametrize("line", ["python", "python3", ""])
+def test_host_spans_are_the_phases_of_any_host_line(line):
+    """On the chip the program's spans sit on a line named after the
+    command, `python3`, beside the Python tracer's frames and the
+    runtime's threads: every `/host:` line is read, kept to phase names."""
+    planes = hand_made()
+    planes["/host:CPU"] = {
+        line: [("train_step", 290 * US, 250 * US),
+               ("$threading.py:1002 run", 0, 700 * US),
+               ("serve_tick.prefill_wait", 100 * US, 50 * US)],
+        "pjrt-tpu-tasks/7": [("ThreadpoolListener::Run", 0, 700 * US)],
+        "main/1": [("serve_setup.warmup.prefill_64", 0, 10 * US),
+                   ("warm_start.train.lower", 0, 10 * US), ("eval_step", 0, 1)],
+    }
+    planes["/device:TPU:0"]["python"] = [("serve_tick", 0, 1)]  # no host plane
+    assert sorted(e[0] for e in tr.host_spans(planes)) == [
+        "eval_step", "serve_setup.warmup.prefill_64", "serve_tick.prefill_wait",
+        "train_step", "warm_start.train.lower"]
+    out = tr.reduce(planes)
+    assert out["breakdown"]["idle_gaps"] == [
+        ["train_step", 300e-6], ["serve_tick.prefill_wait", 50e-6]]
+
+
 def test_idle_share_reads_the_reduction_and_nothing_without_a_trace():
     obs = {"trace": tr.reduce(hand_made())}
     assert tr.idle_share(obs) == pytest.approx(100.0 * (1 - 300 / 700))

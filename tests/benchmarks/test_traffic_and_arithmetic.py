@@ -1,12 +1,13 @@
-"""The generator, the operation counts and the window arithmetic: all
-plain numbers, no device."""
+"""The generator, the operation counts (each family's own file, found
+from the configuration's `adapter`) and the window arithmetic: all plain
+numbers, no device."""
 
 import json
 import os
 
 import pytest
 
-from harness import flops, traffic, window
+from harness import family, flops, traffic, window
 
 BENCH = os.path.join(os.path.dirname(__file__), "..", "..", "benchmarks")
 
@@ -70,21 +71,47 @@ def test_shared_prefix_tenants():
 
 
 def test_bert_large_sample_needs_237_gflop():
-    model = load("configs/bert_large_dp.json")["model"]
-    assert flops.train_flops_per_sample(model, 128) == pytest.approx(2.37e11, rel=0.01)
+    config = load("configs/bert_large_dp.json")
+    count = family.count(config, "train_flops_per_sample")
+    assert count(config, 128) == pytest.approx(2.37e11, rel=0.01)
+    # the family asks for the dense block's count, to the last digit
+    assert count(config, 128) == flops.dense_train_flops_per_sample(
+        config["model"], 128) == 3 * (128 * (
+            2 * 24 * (4 * 1024**2 + 2 * 1024 * 4096) + 24 * 4 * 128 * 1024)
+            + 2 * 1024**2)
 
 
 def test_gpt2_medium_token_keeps_98304_bytes_of_kv():
-    model = load("configs/gpt2_medium_paged.json")["model"]
-    assert flops.kv_bytes_per_token(model) == 98_304
-    assert flops.decode_attention_bytes(model, [100, 300]) == 400 * 98_304
+    config = load("configs/gpt2_medium_paged.json")
+    fam = family.of(config)
+    assert fam.cache_bytes_per_token(config) == 98_304
+    assert fam.cache_read_bytes(config, [100, 300]) == 400 * 98_304
     # a decoded token: two operations per weight, attention over its context
     dense = 2 * 24 * (4 * 1024**2 + 2 * 1024 * 4096) + 2 * 1024 * 50257
-    assert flops.decode_flops(model, 0) == dense
-    assert flops.decode_flops(model, 512) == dense + 24 * 4 * 512 * 1024
+    decode = family.count(config, "decode_flops")
+    assert decode(config, 0) == dense
+    assert decode(config, 512) == dense + 24 * 4 * 512 * 1024
     # a prompt costs about its length in decoded tokens, less the head
-    assert flops.prefill_flops(model, 512) == pytest.approx(
+    prefill = family.count(config, "prefill_flops")
+    assert prefill(config, 512) == pytest.approx(
         512 * (dense - 2 * 1024 * 50257), rel=0.05)
+    assert prefill(config, 512) == 512 * 2 * 24 * (
+        4 * 1024**2 + 2 * 1024 * 4096) + 24 * 4 * 1024 * 512 * 513 / 2 \
+        + 2 * 1024 * 50257
+
+
+@pytest.mark.parametrize("name,has,lacks", [
+    ("bert_large_dp", "train_flops_per_sample", "prefill_flops"),
+    ("gpt2_medium_paged", "decode_flops", "train_flops_per_sample"),
+])
+def test_a_count_the_family_lacks_is_an_exit_that_names_it(name, has, lacks):
+    config = load(f"configs/{name}.json")
+    assert callable(family.count(config, has))
+    with pytest.raises(SystemExit) as e:
+        family.count(config, lacks)
+    assert lacks in str(e.value) and f"families/{config['adapter']}.py" in str(e.value)
+    with pytest.raises(SystemExit, match="no file benchmarks/families/never.py"):
+        family.of(dict(config, adapter="never"))
 
 
 def test_percentile_is_nearest_rank():
