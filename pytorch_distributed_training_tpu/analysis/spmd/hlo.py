@@ -79,6 +79,27 @@ _RELAYOUT_RE = re.compile(
 
 _SHAPE_TOKEN_RE = re.compile(r"(" + _DTYPES_ALT + r")\[([0-9,]*)\]")
 
+# `%copy-start.10 = (bf16[11777,16,128]{2,1,0:T(8,128)(2,1)},
+# bf16[11777,16,128]{2,1,0:T(8,128)(2,1)S(1)}, u32[]{:S(2)}) copy-start(..)`:
+# the two halves of an async copy with their layouts; `S(n)` names the
+# memory space (1: on-chip). XLA:TPU's memory-space assignment stages a
+# buffer that fits on-chip through it and writes it back: the same layout
+# on both sides (`count_space_moves` says how many of the relayouts are
+# of this kind).
+_COPY_START_HALVES_RE = re.compile(
+    r"=\s*\(\s*\w+\[[0-9,]*\](\{[^{}]*\})?,\s*\w+\[[0-9,]*\](\{[^{}]*\})?,"
+    r".*\)\s+copy-start\(")
+_MEMORY_SPACE_RE = re.compile(r"S\(\d+\)")
+
+
+def _is_space_move(line: str) -> bool:
+    """An async copy whose two halves differ by memory space alone."""
+    m = _COPY_START_HALVES_RE.search(line)
+    if not m:
+        return False
+    dst, src = (_MEMORY_SPACE_RE.sub("", g or "") for g in m.groups())
+    return dst == src and (m.group(1) or "") != (m.group(2) or "")
+
 _GROUPS_LIST_RE = re.compile(r"replica_groups=\{\{([^}]*)\}")
 _GROUPS_IOTA_RE = re.compile(r"replica_groups=\[(\d+),(\d+)\]<=\[")
 _PAIRS_RE = re.compile(r"source_target_pairs=\{((?:\{\d+,\d+\},?)+)\}")
@@ -169,6 +190,39 @@ def extract_collectives(
     return out
 
 
+_INSTRUCTION_SCOPE_RE = re.compile(
+    r"^\s*(?:ROOT\s+)?%?(?P<name>[\w.-]+)\s*=.*"
+    r"metadata=\{[^}]*op_name=\"(?P<op>[^\"]*)\"")
+
+
+def scope_instructions(hlo_text: str, scopes) -> dict:
+    """``{scope: [instruction names]}`` of a compiled program: the
+    instructions (outside fusion bodies, which run as their fusion) whose
+    ``op_name`` metadata passes through ``jax.named_scope(scope)`` or a
+    scope ``scope.<more>`` under it. A device trace names its events by
+    instruction and carries no metadata, so this map is what ties a traced
+    operation back to the program's own scopes. An instruction is listed
+    under the first scope of ``scopes`` it matches."""
+    out = {scope: [] for scope in scopes}
+    in_fusion_body = False
+    for line in hlo_text.splitlines():
+        header = _COMPUTATION_RE.match(line)
+        if header:
+            in_fusion_body = header.group("name").startswith("fused_computation")
+            continue
+        if in_fusion_body:
+            continue
+        m = _INSTRUCTION_SCOPE_RE.match(line)
+        if not m:
+            continue
+        parts = m.group("op").split("/")
+        for scope in scopes:
+            if any(p == scope or p.startswith(scope + ".") for p in parts):
+                out[scope].append(m.group("name"))
+                break
+    return out
+
+
 def count_relayouts(hlo_text: str, element_counts) -> int:
     """How many ``copy``/``transpose``/``convert`` instructions of a
     compiled program produce a buffer of one of ``element_counts``
@@ -177,6 +231,20 @@ def count_relayouts(hlo_text: str, element_counts) -> int:
     number of times the program rewrites one of them whole: XLA:TPU
     inserts such copies when a parameter's device layout is not the one
     its consumer runs in, and they cost a pass over the buffer each."""
+    return _count_copies(hlo_text, element_counts, lambda line: True)
+
+
+def count_space_moves(hlo_text: str, element_counts) -> int:
+    """How many of ``count_relayouts``' instructions are async copies that
+    move the buffer between memory spaces in ONE layout: the compiler
+    staging a buffer that fits on-chip through it. Still a pass over the
+    buffer each, and counted by ``count_relayouts`` like every other; this
+    part of the count is at the compiler's discretion and no fault of the
+    buffer's layout."""
+    return _count_copies(hlo_text, element_counts, _is_space_move)
+
+
+def _count_copies(hlo_text: str, element_counts, wanted_line) -> int:
     wanted = set(element_counts)
     if not wanted:
         return 0
@@ -185,7 +253,7 @@ def count_relayouts(hlo_text: str, element_counts) -> int:
         m = _RELAYOUT_RE.match(line)
         if m and any(
             elems in wanted for _, elems in _shape_tokens(m.group("shape"))
-        ):
+        ) and wanted_line(line):
             count += 1
     return count
 

@@ -28,7 +28,9 @@ from pytorch_distributed_training_tpu.analysis.spmd.hlo import (
     COLLECTIVE_KINDS,
     CostModel,
     count_relayouts,
+    count_space_moves,
     extract_collectives,
+    scope_instructions,
     summarize_collectives,
 )
 
@@ -57,6 +59,11 @@ class CommManifest:
     # convert instructions (``kv_pool_relayout_ops``; a pool that keeps one
     # device layout from parameter to donated result reads 0)
     kv_pool_elements: tuple = ()
+    # ``jax.named_scope`` names of the program's own: the audit also emits
+    # a ``program_scopes`` record listing the compiled instructions under
+    # each, so that a device trace (events named by instruction, no
+    # metadata) can be read scope by scope
+    trace_scopes: tuple = ()
 
     def __post_init__(self):
         for kind in tuple(self.allowed) + tuple(self.required):
@@ -277,7 +284,15 @@ def comm_audit(
         record["kv_pool_relayout_ops"] = count_relayouts(
             text, manifest.kv_pool_elements
         )
+        record["kv_pool_space_moves"] = count_space_moves(
+            text, manifest.kv_pool_elements
+        )
     registry.emit(record)
+    if manifest.trace_scopes:
+        registry.emit({
+            "record": "program_scopes", "name": name,
+            "scopes": scope_instructions(text, manifest.trace_scopes),
+        })
     if deviations:
         registry.inc("guards/comm_deviations", len(deviations))
         if mode == "strict":

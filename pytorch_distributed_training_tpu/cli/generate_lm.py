@@ -27,6 +27,7 @@ The model/tokenizer loading helpers (``build_tokenizer``,
 from __future__ import annotations
 
 import argparse
+import dataclasses
 
 import numpy as np
 
@@ -121,7 +122,16 @@ def load_model_and_params(args, tok):
             f"tokenizer vocab {tok.vocab_size} exceeds model vocab "
             f"{mcfg.vocab_size}"
         )
-    model = GPT2LMModel(mcfg)
+    from pytorch_distributed_training_tpu.models import latent_moe
+
+    if isinstance(mcfg, latent_moe.LatentMoEConfig):
+        if getattr(args, "weights_dtype", None) == "bfloat16":
+            # created in the resident type: a model sized to the chip has
+            # no room for a float32 tree beside its bfloat16 one
+            mcfg = dataclasses.replace(mcfg, param_dtype="bfloat16")
+        model = latent_moe.LatentMoELM(mcfg)
+    else:
+        model = GPT2LMModel(mcfg)
 
     if args.hf_checkpoint:
         from pytorch_distributed_training_tpu.models.hf_loader import (
@@ -140,10 +150,13 @@ def load_model_and_params(args, tok):
         )
     else:
         log0("no checkpoint given: generating from RANDOM weights (demo)")
-        params = model.init(
-            jax.random.key(args.seed),
-            np.ones((1, 8), np.int32),
-        )["params"]
+        init = lambda key: model.init(  # noqa: E731
+            key, np.ones((1, 8), np.int32))["params"]
+        if isinstance(model, latent_moe.LatentMoELM):
+            # one compiled program: op by op, init would run the expert
+            # layers' loops and compile every distinct shape on its own
+            init = jax.jit(init)
+        params = init(jax.random.key(args.seed))
     return model, params, ckpt_step
 
 

@@ -26,6 +26,17 @@ never queued unboundedly), ``--deadline-s`` default per-request deadline
 TPOT, queue wait) through telemetry/; fold them into a percentile table
 with ``scripts/summarize_metrics.py``.
 
+Latent expert presets (``--model glm-5.2-share16``: latent attention with
+a learned sparse indexer, routed experts of which this chip holds a share;
+``models/latent_moe.py``) serve through the same server, tick, allocator,
+prefix cache and sampling. They need ``--kv-layout paged --sampling
+device`` (the defaults) and, at the published widths, ``--weights-dtype
+bfloat16``; ``--prefill-chunk`` and ``--prefix-cache`` work over their
+pools. They refuse, at start-up and by the flag's name: ``--tp``,
+``--spec-k``, ``--weights-dtype int8``, ``--kv-dtype int8``, ``--kv-layout
+dense`` and ``--sampling host``. Size ``--num-pages`` yourself where
+prompts share prefixes (the default reserves every slot a whole context).
+
 Live reload: with ``--checkpoint-dir`` the server exposes ``POST /swap``
 (swap to a named step) and ``--hotswap-poll-s N`` additionally watches the
 directory, hot-swapping each newly published manifest-verified step into
@@ -102,8 +113,13 @@ def build_parser() -> argparse.ArgumentParser:
                         "Requires paged KV + device sampling and a model "
                         "whose num_heads/intermediate_size divide by N")
     p.add_argument("--weights-dtype", default="float32",
-                   choices=("float32", "int8"),
-                   help="serving weight precision: int8 quantizes every "
+                   choices=("float32", "bfloat16", "int8"),
+                   help="serving weight precision: float32 leaves the "
+                        "tree as the preset or checkpoint gives it; "
+                        "bfloat16 keeps every floating leaf resident in "
+                        "bfloat16 (a latent expert preset is then created "
+                        "in it: at 9.4 GB it has no room for a float32 "
+                        "tree); int8 quantizes every "
                         "attention/MLP matmul weight at load (per-channel "
                         "scales, dequantized in-trace — activations and "
                         "logits stay fp32) at ~0.5x resident weight bytes")
@@ -223,11 +239,25 @@ def build_parser() -> argparse.ArgumentParser:
     return p
 
 
+def _check_model_flags(args) -> None:
+    """Exit at start-up, naming the flag, where the model's family lacks
+    a serving path the flags ask for (the engine checks the same again)."""
+    from pytorch_distributed_training_tpu.utils.config import model_preset
+
+    try:
+        check = getattr(model_preset(args.model), "check_serving", None)
+        if check is not None:
+            check(args)
+    except (KeyError, ValueError) as e:
+        raise SystemExit(f"serve_lm: {e.args[0]}")
+
+
 def main(argv=None, in_stream=None, out_stream=None) -> dict:
     """Run the server until EOF (stdio mode) or interrupt (HTTP mode);
     returns the engine's final stats dict (machine-checkable in tests).
     Raises ``SystemExit`` (exit code 1) when the serve loop died."""
     args = build_parser().parse_args(argv)
+    _check_model_flags(args)
 
     import jax
 

@@ -1,0 +1,578 @@
+"""A latent-attention, sparse-selection, routed-expert decoder (flax.linen).
+
+The block the GPT-2 family lacks, by mechanism:
+
+- RMSNorm before attention and before the MLP, residual adds, a final
+  RMSNorm and an UNTIED vocabulary head; rotary positions on interleaved
+  pairs; gated SiLU MLPs.
+- Latent attention (MLA): queries through a low-rank bottleneck, keys and
+  values through ONE shared latent per token plus one rotary key for all
+  heads. The cache holds the latent row, never per-head K/V. Two paths
+  that compute the same function (``ops/latent_attention.py``): a fresh
+  sequence EXPANDS the latents to per-head keys and values; a step over a
+  paged cache ABSORBS the key up-projection into the query and the value
+  up-projection into the output and attends in the latent.
+- A learned sparse-attention indexer in the layers typed ``full``: its
+  scores pick the ``index_topk`` cached positions a query attends to. A
+  layer typed ``shared`` has no indexer weights and no indexer pool: it is
+  handed the selection of the nearest ``full`` layer before it, a value
+  passed from layer to layer inside the compiled step.
+- Expert layers (``ops/moe.py``) that are told which experts they hold:
+  the router scores all ``n_routed_experts``, the chip computes its own
+  experts' part plus the shared expert.
+
+Serving contract (``serve/engine.py``, ``serve/paged_cache.py``): the same
+flax "cache" collection pattern as ``models/bert.py::_paged_attend``. Every
+attention layer keeps a ``latent_pages`` pool ``[pages, page_size, 640]``
+(lane-dense row ``[c_kv 512 | k_rope 64 | 0]``), the ``full`` layers an
+``index_pages`` pool ``[pages, page_size, 128]`` beside it; one block table
+addresses both, so a prefix-cache hit maps them together and copy-on-write
+copies both. Prefill (fresh sequence), decode (one token a sequence) and
+the multi-token-query view (prefill chunks at a nonzero context) all write
+first and read after.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+from typing import Any, Optional
+
+import flax.linen as nn
+import jax
+import jax.numpy as jnp
+
+from pytorch_distributed_training_tpu.ops import latent_attention as la
+from pytorch_distributed_training_tpu.ops import moe
+
+
+@dataclasses.dataclass
+class LatentMoEConfig:
+    """Sizes under the names of the family's published ``config.json``,
+    then the serving fields the engine sets (as ``ModelConfig`` has them)."""
+
+    vocab_size: int                 # rows held (a share of the published)
+    hidden_size: int
+    num_attention_heads: int
+    q_lora_rank: int
+    kv_lora_rank: int
+    qk_nope_head_dim: int
+    qk_rope_head_dim: int
+    v_head_dim: int
+    intermediate_size: int          # width of a dense layer's MLP
+    moe_intermediate_size: int      # width of one expert
+    n_routed_experts: int           # the router's outputs: ALL experts
+    num_experts_per_tok: int
+    n_shared_experts: int
+    routed_scaling_factor: float
+    index_n_heads: int
+    index_head_dim: int
+    index_topk: int
+    mlp_layer_types: tuple          # "dense" | "sparse", one a layer
+    indexer_types: tuple            # "full" | "shared", one a layer
+    max_position_embeddings: int
+    rope_theta: float = 10000.0
+    rms_norm_eps: float = 1e-5
+    index_norm_eps: float = 1e-6
+    # the experts THIS chip holds: (first, count) of the routed experts
+    experts_held: tuple = (0, 0)
+    # held experts stacked to one parameter leaf
+    expert_block: int = 8
+    # at or under this many tokens a step every held expert multiplies
+    # every token (same work whatever the routing); above it the products
+    # are grouped by expert (ops/moe.py)
+    moe_dense_tokens: int = 64
+    # queries a block when a multi-token step selects and attends
+    attention_query_block: int = 128
+    compute_dtype: str = "bfloat16"
+    param_dtype: str = "bfloat16"
+    initializer_range: float = 0.02
+    # ---- what the serving engine reads and sets (ModelConfig's names)
+    causal: bool = True
+    scan_layers: bool = False
+    decode: bool = False
+    kv_layout: str = "paged"
+    kv_page_size: int = 16
+    kv_num_pages: int = 0
+    paged_attention_impl: str = "reference"
+    kv_cache_dtype: str = "auto"
+    paged_multiquery: bool = False
+
+    def __post_init__(self):
+        self.mlp_layer_types = tuple(self.mlp_layer_types)
+        self.indexer_types = tuple(self.indexer_types)
+        self.experts_held = tuple(int(v) for v in self.experts_held)
+        if len(self.mlp_layer_types) != len(self.indexer_types):
+            raise ValueError("mlp_layer_types and indexer_types differ in length")
+        if self.indexer_types[0] != "full":
+            raise ValueError(
+                "the first layer must be typed 'full': a 'shared' layer "
+                "takes its selection from a 'full' layer before it")
+        first, held = self.experts_held
+        if "sparse" in self.mlp_layer_types:
+            if held < 1 or first < 0 or first + held > self.n_routed_experts:
+                raise ValueError(
+                    f"experts_held {self.experts_held} is no range of the "
+                    f"{self.n_routed_experts} routed experts")
+            if held % self.expert_block:
+                raise ValueError(
+                    f"expert_block {self.expert_block} does not divide the "
+                    f"{held} experts held")
+        if self.scan_layers:
+            raise ValueError("layers of different kinds cannot be scanned")
+
+    @property
+    def num_layers(self) -> int:
+        return len(self.mlp_layer_types)
+
+    @property
+    def latent_row(self) -> int:
+        """Values of one cached latent row: latent plus rotary key, padded
+        to whole lane tiles."""
+        return la.lane_pad(self.kv_lora_rank + self.qk_rope_head_dim)
+
+    def cache_values_per_token(self) -> int:
+        """Resident pool values one token occupies over all layers."""
+        full = sum(1 for t in self.indexer_types if t == "full")
+        return self.num_layers * self.latent_row + full * self.index_head_dim
+
+    def check_serving(self, engine) -> None:
+        """Refuse, by the flag's name, what this family's serving path does
+        not have (``engine``: an ``EngineConfig`` or the parsed CLI
+        arguments: the same attribute names)."""
+        def bad(flag, why):
+            raise ValueError(
+                f"{flag} is not supported for a latent-attention "
+                f"expert model: {why}")
+
+        if getattr(engine, "kv_layout", "paged") != "paged":
+            bad("--kv-layout dense", "its cache is latent page pools; "
+                "pass --kv-layout paged")
+        if getattr(engine, "sampling", "device") != "device":
+            bad("--sampling host", "pass --sampling device")
+        if getattr(engine, "tp", 1) != 1:
+            bad("--tp", "the latent pools have no head axis to shard and "
+                "no expert axis exists yet")
+        if getattr(engine, "spec_k", 0):
+            bad("--spec-k", "no draft lane (the multi-token-prediction "
+                "block is not loaded)")
+        if getattr(engine, "weights_dtype", "bfloat16") == "int8":
+            bad("--weights-dtype int8", "expert leaves have no int8 form; "
+                "pass --weights-dtype bfloat16")
+        if getattr(engine, "kv_dtype", "float32") == "int8":
+            bad("--kv-dtype int8", "the latent pools have no scale pools")
+
+
+def _cdt(cfg):
+    return jnp.dtype(cfg.compute_dtype)
+
+
+def _pdt(cfg):
+    return jnp.dtype(cfg.param_dtype)
+
+
+def _init(cfg):
+    return nn.initializers.normal(stddev=cfg.initializer_range)
+
+
+class RMSNorm(nn.Module):
+    eps: float
+    param_dtype: Any
+
+    @nn.compact
+    def __call__(self, x):
+        scale = self.param("scale", nn.initializers.ones, (x.shape[-1],),
+                           self.param_dtype)
+        x32 = x.astype(jnp.float32)
+        x32 = x32 * jax.lax.rsqrt(
+            jnp.mean(x32 * x32, axis=-1, keepdims=True) + self.eps)
+        return (x32 * scale.astype(jnp.float32)).astype(x.dtype)
+
+
+def _mm(x, w):
+    """x [..., k] @ w [k, ...]: compute-dtype operands, float32 sums."""
+    return jax.lax.dot_general(
+        x, w.astype(x.dtype), (((x.ndim - 1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)
+
+
+class GatedMLP(nn.Module):
+    """``down(silu(gate x) * up x)``."""
+
+    config: LatentMoEConfig
+    width: int
+
+    @nn.compact
+    def __call__(self, x):
+        cfg = self.config
+        h = cfg.hidden_size
+        gate = self.param("gate", _init(cfg), (h, self.width), _pdt(cfg))
+        up = self.param("up", _init(cfg), (h, self.width), _pdt(cfg))
+        down = self.param("down", _init(cfg), (self.width, h), _pdt(cfg))
+        a = (jax.nn.silu(_mm(x, gate)) * _mm(x, up)).astype(x.dtype)
+        return _mm(a, down)
+
+
+class ExpertLayer(nn.Module):
+    """Router over all experts, this chip's experts' part, the shared
+    expert. Sows the step's routing counts into the ``routing`` collection
+    where the caller asks for it."""
+
+    config: LatentMoEConfig
+
+    @nn.compact
+    def __call__(self, x, token_mask=None):
+        cfg = self.config
+        h, f = cfg.hidden_size, cfg.moe_intermediate_size
+        first, held = cfg.experts_held
+        block = cfg.expert_block
+        router = self.param(
+            "router", _init(cfg), (h, cfg.n_routed_experts), jnp.float32)
+        bias = self.param(
+            "router_bias", nn.initializers.zeros, (cfg.n_routed_experts,),
+            jnp.float32)
+        experts = []
+        for j in range(held // block):
+            experts.append(tuple(
+                self.param(f"experts_{j}_{name}", _init(cfg), shape, _pdt(cfg))
+                for name, shape in (("gate", (block, h, f)),
+                                    ("up", (block, h, f)),
+                                    ("down", (block, f, h)))))
+        lead = x.shape[:-1]
+        flat = x.reshape(-1, h)
+        with jax.named_scope("moe"):
+            chosen, weights = moe.route(
+                flat, router, bias, cfg.num_experts_per_tok,
+                cfg.routed_scaling_factor)
+            if self.is_mutable_collection("routing"):
+                mask = None if token_mask is None else token_mask.reshape(-1)
+                per_expert, absent = moe.routing_counts(
+                    chosen, first, held, mask)
+                self.sow("routing", "held_tokens", per_expert,
+                         reduce_fn=lambda a, b: a + b,
+                         init_fn=lambda: jnp.zeros((held,), jnp.int32))
+                self.sow("routing", "absent_pairs", absent,
+                         reduce_fn=lambda a, b: a + b,
+                         init_fn=lambda: jnp.zeros((), jnp.int32))
+            product = (moe.dense_experts
+                       if flat.shape[0] <= cfg.moe_dense_tokens
+                       else moe.grouped_experts)
+            routed = product(flat, chosen, weights, first, experts)
+            with jax.named_scope("moe.shared"):
+                shared = GatedMLP(
+                    cfg, f * cfg.n_shared_experts, name="shared")(flat)
+            return (routed + shared).reshape(*lead, h)
+
+
+class LatentAttention(nn.Module):
+    """MLA with an optional indexer (``indexer=True``: a layer typed
+    ``full``). Returns (output, selection): the selection is this layer's
+    own where it has an indexer, the one it was handed otherwise."""
+
+    config: LatentMoEConfig
+    indexer: bool
+
+    @nn.compact
+    def __call__(self, x, positions, selection):
+        cfg = self.config
+        dt = _cdt(cfg)
+        h, heads = cfg.hidden_size, cfg.num_attention_heads
+        dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+        rank = cfg.kv_lora_rank
+        init, pdt = _init(cfg), _pdt(cfg)
+        q_a = self.param("q_a", init, (h, cfg.q_lora_rank), pdt)
+        q_b = self.param("q_b", init, (cfg.q_lora_rank, heads, dn + dr), pdt)
+        # the latent and the rotary key are two projections of x (one
+        # leaf each: they are drawn, loaded and sharded apart)
+        kv_a_latent = self.param("kv_a_latent", init, (h, rank), pdt)
+        kv_a_rope = self.param("kv_a_rope", init, (h, dr), pdt)
+        kv_b_k = self.param("kv_b_k", init, (rank, heads, dn), pdt)
+        kv_b_v = self.param("kv_b_v", init, (rank, heads, dv), pdt)
+        o_w = self.param("o", init, (heads, dv, h), pdt)
+
+        cq = RMSNorm(cfg.rms_norm_eps, pdt, name="q_a_norm")(
+            _mm(x, q_a).astype(dt))
+        q = _mm(cq, q_b).astype(dt)                       # [b, t, heads, dn+dr]
+        ckv = RMSNorm(cfg.rms_norm_eps, pdt, name="kv_a_norm")(
+            _mm(x, kv_a_latent).astype(dt))
+        cos, sin = la.rope_angles(positions, dr, cfg.rope_theta)
+        k_rope = la.apply_rope(_mm(x, kv_a_rope).astype(dt), cos, sin)
+        q_nope = q[..., :dn]
+        q_rope = la.apply_rope(q[..., dn:], cos[:, :, None], sin[:, :, None])
+        scale = (dn + dr) ** -0.5
+
+        qi = ki = wi = None
+        if self.indexer:
+            ih, idim = cfg.index_n_heads, cfg.index_head_dim
+            iq_w = self.param("index_q", init, (cfg.q_lora_rank, ih, idim), pdt)
+            ik_w = self.param("index_k", init, (h, idim), pdt)
+            iw_w = self.param("index_w", init, (h, ih), pdt)
+            qi = _mm(cq, iq_w).astype(dt)
+            ki = nn.LayerNorm(
+                epsilon=cfg.index_norm_eps, dtype=dt, param_dtype=pdt,
+                name="index_k_norm")(_mm(x, ik_w).astype(dt))
+            # the rotary span is the first qk_rope_head_dim dims of both
+            qi = jnp.concatenate([
+                la.apply_rope(qi[..., :dr], cos[:, :, None], sin[:, :, None]),
+                qi[..., dr:]], axis=-1)
+            ki = jnp.concatenate(
+                [la.apply_rope(ki[..., :dr], cos, sin), ki[..., dr:]], axis=-1)
+            wi = _mm(x, iw_w) * (ih * idim) ** -0.5       # float32
+
+        serving = cfg.decode and not self.is_initializing()
+        if serving:
+            pools = self._write(ckv, k_rope, ki, positions)
+        if serving:
+            # the decode step, a prefill chunk and a bucket prefill alike
+            # (a bucket is one chunk at context 0)
+            ctx, selection = self._paged(
+                pools, q_nope, q_rope, qi, wi, positions, selection,
+                kv_b_k, kv_b_v, scale)
+        else:
+            # no cache (training, evaluation, the tests' comparisons, and
+            # declaring the cache's shapes): the sequence's own latents are
+            # the whole context
+            ctx, selection = self._fresh(
+                q_nope, q_rope, ckv, k_rope, qi, ki, wi, positions,
+                selection, kv_b_k, kv_b_v, scale)
+            if cfg.decode and not serving:
+                self._pools()   # initializing: declare the cache's shapes
+        out = jnp.einsum("bqhv,hvd->bqd", ctx.astype(dt), o_w.astype(dt),
+                         preferred_element_type=jnp.float32)
+        if self.is_mutable_collection("selection"):
+            self.sow("selection", "positions", selection.positions)
+            self.sow("selection", "valid", selection.valid)
+        return out.astype(dt), selection
+
+    # ---------------------------------------------------------- fresh path
+
+    def _fresh(self, q_nope, q_rope, ckv, k_rope, qi, ki, wi, positions,
+               selection, kv_b_k, kv_b_v, scale):
+        """A sequence over itself, no cache: EXPAND the latents. Not
+        blocked over queries (the causal square is whole): for sequences
+        of the tests' and a trainer's lengths, never the server's."""
+        cfg = self.config
+        dt = ckv.dtype
+        seq = ckv.shape[1]
+        if self.indexer:
+            selection = la.select_topk(
+                la.fresh_index_scores(qi, wi, ki, positions), cfg.index_topk,
+                positions)
+        k_nope = jnp.einsum("bsc,chd->bshd", ckv, kv_b_k.astype(dt),
+                            preferred_element_type=jnp.float32).astype(dt)
+        v = jnp.einsum("bsc,chv->bshv", ckv, kv_b_v.astype(dt),
+                       preferred_element_type=jnp.float32).astype(dt)
+        ctx = la.expanded_attention(
+            q_nope, q_rope, k_nope, k_rope, v,
+            la.select_mask(selection, seq), scale)
+        return ctx, selection
+
+    # ---------------------------------------------------------- paged path
+
+    def _pools(self):
+        cfg = self.config
+        if cfg.kv_num_pages < 2:
+            raise ValueError(
+                "paged serving needs kv_num_pages >= 2 (page 0 is the "
+                f"reserved null page), got {cfg.kv_num_pages}")
+        dt = _cdt(cfg)
+        shape = (cfg.kv_num_pages, cfg.kv_page_size)
+        latent = self.variable(
+            "cache", "latent_pages",
+            lambda: jnp.zeros(shape + (cfg.latent_row,), dt))
+        index = None
+        if self.indexer:
+            index = self.variable(
+                "cache", "index_pages",
+                lambda: jnp.zeros(shape + (cfg.index_head_dim,), dt))
+        # placeholders: the engine supplies both per call (with_tables)
+        bt = self.variable(
+            "cache", "block_table", lambda: jnp.zeros((1, 1), jnp.int32))
+        cl = self.variable(
+            "cache", "context_len", lambda: jnp.zeros((1,), jnp.int32))
+        return latent, index, bt, cl
+
+    def _write(self, ckv, k_rope, ki, positions):
+        """This step's rows into the pools, through the block table: the
+        latent row ``[c_kv | k_rope | 0]`` and, with an indexer, its key."""
+        cfg = self.config
+        dt = ckv.dtype
+        latent, index, bt, cl = self._pools()
+        batch, chunk = positions.shape
+        pad = cfg.latent_row - cfg.kv_lora_rank - cfg.qk_rope_head_dim
+        rows = jnp.concatenate(
+            [ckv, k_rope, jnp.zeros((batch, chunk, pad), dt)], axis=-1)
+        latent.value = la.write_rows(latent.value, bt.value, positions, rows)
+        if self.indexer:
+            index.value = la.write_rows(index.value, bt.value, positions, ki)
+        cl.value = cl.value + chunk
+        return latent, index, bt
+
+    def _paged(self, pools, q_nope, q_rope, qi, wi, positions, selection,
+               kv_b_k, kv_b_v, scale):
+        """Select, gather and attend in the latent through the block
+        table, after the step's own rows are written: ABSORB. One code
+        path for the decode step (one token a sequence), a prefill chunk
+        at a nonzero context and a bucket prefill at context 0."""
+        cfg = self.config
+        dt = q_nope.dtype
+        latent, index, bt = pools
+        batch, chunk = positions.shape
+        pad = cfg.latent_row - cfg.kv_lora_rank - cfg.qk_rope_head_dim
+        # the absorbed query, laid out as a pool row
+        q_lat = jnp.einsum("bqhd,chd->bqhc", q_nope, kv_b_k.astype(dt),
+                           preferred_element_type=jnp.float32).astype(dt)
+        q_row = jnp.concatenate([
+            q_lat, q_rope,
+            jnp.zeros((batch, chunk, q_rope.shape[2], pad), dt)], axis=-1)
+
+        def values(ctx):
+            # the probability-weighted latent, up to per-head values
+            return jnp.einsum(
+                "bqhc,chv->bqhv", ctx[..., :cfg.kv_lora_rank].astype(dt),
+                kv_b_v.astype(dt), preferred_element_type=jnp.float32)
+
+        def block(q_row, qi, wi, positions, selection):
+            if self.indexer:
+                selection = la.look_up_rows(la.select_topk(
+                    la.index_scores(qi, wi, index.value, bt.value, positions),
+                    cfg.index_topk, positions), bt.value, cfg.kv_page_size)
+            rows = la.gather_rows(latent.value, selection)
+            ctx = la.latent_attention(q_row, rows, selection.valid, scale)
+            return values(ctx), selection
+
+        qb = cfg.attention_query_block
+        if chunk <= qb:
+            return block(q_row, qi, wi, positions, selection)
+        # a prefill, in blocks of queries: the indexer's [queries, heads,
+        # context] float32 scores and the gathered rows are bounded by the
+        # block, not by the chunk or the bucket. A last block that is not
+        # whole is filled with copies of the last query and cut off again.
+        n = -(-chunk // qb)
+
+        def split(t):
+            if t is None:
+                return None
+            t = jnp.pad(t, [(0, 0), (0, n * qb - chunk)]
+                        + [(0, 0)] * (t.ndim - 2), mode="edge")
+            return jnp.moveaxis(t.reshape(batch, n, qb, *t.shape[2:]), 1, 0)
+
+        def merge(t):
+            return jnp.moveaxis(t, 0, 1).reshape(
+                batch, n * qb, *t.shape[3:])[:, :chunk]
+
+        ctx, sel = jax.lax.map(
+            lambda a: block(*a),
+            (split(q_row), split(qi), split(wi), split(positions),
+             None if self.indexer else jax.tree.map(split, selection)))
+        return merge(ctx), jax.tree.map(merge, sel)
+
+
+class DecoderLayer(nn.Module):
+    config: LatentMoEConfig
+    sparse: bool
+    indexer: bool
+
+    @nn.compact
+    def __call__(self, x, positions, selection, token_mask):
+        cfg = self.config
+        pdt = _pdt(cfg)
+        h = RMSNorm(cfg.rms_norm_eps, pdt, name="attention_norm")(x)
+        a, selection = LatentAttention(cfg, self.indexer, name="attention")(
+            h, positions, selection)
+        x = x + a
+        h = RMSNorm(cfg.rms_norm_eps, pdt, name="mlp_norm")(x)
+        if self.sparse:
+            m = ExpertLayer(cfg, name="experts")(h, token_mask)
+        else:
+            with jax.named_scope("dense_mlp"):
+                m = GatedMLP(cfg, cfg.intermediate_size, name="mlp")(h)
+        return x + m.astype(x.dtype), selection
+
+
+class LatentMoELM(nn.Module):
+    """Embedding -> layers -> final RMSNorm -> untied head. Signature as
+    ``GPT2LMModel``'s (the serving engine drives either): ``position_ids``
+    [batch, seq] are the tokens' positions; ``token_mask`` [batch, seq]
+    marks the tokens the routing counts take (all where None)."""
+
+    config: LatentMoEConfig
+    #: the ``jax.named_scope`` names a device trace is read by
+    #: (``analysis/spmd/hlo.scope_instructions``; ops/latent_attention.py,
+    #: ops/moe.py)
+    trace_scopes = (
+        "sparse_attn.index_scores", "sparse_attn.topk", "sparse_attn.gather",
+        "sparse_attn.attend", "moe")
+
+    @nn.compact
+    def __call__(self, input_ids, attention_mask=None, token_type_ids=None,
+                 position_ids=None, deterministic: bool = True,
+                 token_mask=None):
+        cfg = self.config
+        if attention_mask is not None:
+            raise ValueError(
+                "padding is expressed through positions and context_len")
+        batch, seq = input_ids.shape
+        if position_ids is None:
+            if cfg.decode and not self.is_initializing():
+                raise ValueError("paged serving passes position_ids")
+            position_ids = jnp.broadcast_to(
+                jnp.arange(seq, dtype=jnp.int32)[None], (batch, seq))
+        dt = _cdt(cfg)
+        embed = self.param("embed", _init(cfg),
+                           (cfg.vocab_size, cfg.hidden_size), _pdt(cfg))
+        head = self.param("head", _init(cfg),
+                          (cfg.hidden_size, cfg.vocab_size), _pdt(cfg))
+        x = embed[input_ids].astype(dt)
+        selection: Optional[la.Selection] = None
+        for i, (mlp, idx) in enumerate(
+                zip(cfg.mlp_layer_types, cfg.indexer_types)):
+            x, selection = DecoderLayer(
+                cfg, mlp == "sparse", idx == "full", name=f"layer_{i}")(
+                x, position_ids, selection, token_mask)
+        x = RMSNorm(cfg.rms_norm_eps, _pdt(cfg), name="final_norm")(x)
+        return _mm(x, head)
+
+
+#: published sizes of the family's presets (``utils/config.model_preset``
+#: finds them here); a ``-shareN`` preset is the cut a chip holds when N
+#: chips share each layer
+PRESETS: dict[str, dict[str, Any]] = {
+    # https://huggingface.co/zai-org/GLM-5.2/blob/main/config.json, cut
+    # as benchmarks/configs/glm52_share16.json states: layers 2..7 of 78,
+    # experts 0..15 of 256, an eighth of the vocabulary, no MTP block
+    "glm-5.2-share16": dict(
+        vocab_size=19360, hidden_size=6144, num_attention_heads=64,
+        q_lora_rank=2048, kv_lora_rank=512, qk_nope_head_dim=192,
+        qk_rope_head_dim=64, v_head_dim=256, intermediate_size=12288,
+        moe_intermediate_size=2048, n_routed_experts=256,
+        num_experts_per_tok=8, n_shared_experts=1,
+        routed_scaling_factor=2.5, index_n_heads=32, index_head_dim=128,
+        index_topk=2048,
+        mlp_layer_types=("dense",) + ("sparse",) * 5,
+        indexer_types=("full", "shared", "shared", "shared", "full", "shared"),
+        max_position_embeddings=1048576, rope_theta=8e6, rms_norm_eps=1e-5,
+        experts_held=(0, 16), expert_block=8,
+    ),
+    # the CPU tests' size: every mechanism, contexts past index_topk
+    "latent-moe-tiny": dict(
+        vocab_size=512, hidden_size=64, num_attention_heads=4,
+        q_lora_rank=32, kv_lora_rank=32, qk_nope_head_dim=16,
+        qk_rope_head_dim=8, v_head_dim=16, intermediate_size=128,
+        moe_intermediate_size=32, n_routed_experts=8,
+        num_experts_per_tok=2, n_shared_experts=1,
+        routed_scaling_factor=2.5, index_n_heads=4, index_head_dim=16,
+        index_topk=8,
+        mlp_layer_types=("dense", "sparse", "sparse", "sparse"),
+        indexer_types=("full", "shared", "full", "shared"),
+        max_position_embeddings=4096, rope_theta=8e6, rms_norm_eps=1e-5,
+        experts_held=(0, 2), expert_block=1, moe_dense_tokens=4,
+        attention_query_block=4, compute_dtype="float32",
+        param_dtype="float32",
+    ),
+}
+
+
+def preset(name: str, **overrides: Any) -> LatentMoEConfig:
+    kwargs = dict(PRESETS[name])
+    kwargs.update(overrides)
+    return LatentMoEConfig(**kwargs)

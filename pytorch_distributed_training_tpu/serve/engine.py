@@ -119,6 +119,7 @@ from pytorch_distributed_training_tpu.analysis.spmd.manifest import (
     serve_tp_manifest,
 )
 from pytorch_distributed_training_tpu.faults.watchdog import watchdog_guard
+from pytorch_distributed_training_tpu.ops.moe import routing_totals
 from pytorch_distributed_training_tpu.ops.quant import (
     dequantize_serve_params,
     quantize_serve_params,
@@ -215,7 +216,11 @@ class EngineConfig:
     # paged K/V pools as int8 with fp32 per-page-per-head scale pools
     # riding beside the block tables (allocator arithmetic and admission
     # are dtype-invariant). Both compose with tp and speculation;
-    # "float32" keeps today's exact baseline.
+    # "float32" keeps today's exact baseline. weights_dtype="bfloat16" keeps
+    # every floating leaf resident in bfloat16 (cast once at build; a tree
+    # that arrives in bfloat16, as a model sized to the chip is loaded,
+    # stays as it is): half the weight bytes a step reads, nothing
+    # dequantized in-trace.
     weights_dtype: str = "float32"
     kv_dtype: str = "float32"
     # Shared-KV prefix cache (serve/prefix_cache.py): finished prompts'
@@ -297,9 +302,9 @@ class EngineConfig:
                 raise ValueError("tp > 1 requires kv_layout='paged'")
             if self.sampling != "device":
                 raise ValueError("tp > 1 requires sampling='device'")
-        if self.weights_dtype not in ("float32", "int8"):
+        if self.weights_dtype not in ("float32", "bfloat16", "int8"):
             raise ValueError(
-                f"weights_dtype must be float32/int8, got "
+                f"weights_dtype must be float32/bfloat16/int8, got "
                 f"{self.weights_dtype!r}"
             )
         if self.kv_dtype not in ("float32", "int8"):
@@ -481,6 +486,11 @@ class DecodeEngine:
             model = type(model)(cfg)
             params = unstack_scanned_params(params)
         self.config = config
+        # a model family that lacks some serving paths says so itself, by
+        # the flag's name, before anything is placed or compiled
+        check = getattr(cfg, "check_serving", None)
+        if check is not None:
+            check(config)
         if config.cache_len + config.spec_k > cfg.max_position_embeddings:
             raise ValueError(
                 f"cache_len {config.cache_len} (= largest bucket "
@@ -499,6 +509,7 @@ class DecodeEngine:
         self.variant = "int8" if config.weights_dtype == "int8" else "fp32"
         if config.weights_dtype == "int8":
             params = quantize_serve_params(params)
+        params = self._resident_dtype(params)
         # Placement: EVERY program input — params, KV state, host-built
         # operands — is committed to one explicit sharding from the first
         # call on. jit keys its executables on committed-ness: a resident
@@ -549,6 +560,9 @@ class DecodeEngine:
                 ),
             )
         self._decode_model = type(model)(dcfg)
+        # routed experts (a share of them held here): the decode step also
+        # returns its routing counts, fetched with the sampled ids
+        self._routed = bool(getattr(cfg, "n_routed_experts", 0))
         # Multi-token-query view of the SAME decode model (shared params,
         # shared pools): the verify and chunk programs append a block of
         # tokens at context_len and attend over prior pages plus the block.
@@ -743,6 +757,15 @@ class DecodeEngine:
         # the bench's cached-vs-cold reduction numerator — and is kept even
         # with the cache off so A/B runs compare like with like.
         self.prefill_tokens = 0
+        # prompt tokens admissions took from cached pages instead
+        self.prefix_cached_tokens = 0
+        # routed-expert accounting over the decode steps (``_routed``):
+        # steps counted, tokens routed to held experts (all layers), the
+        # sum of each step's busiest expert, pairs routed to absent ones
+        self.moe_steps = 0
+        self.moe_held_tokens = 0
+        self.moe_held_max_sum = 0
+        self.moe_absent_pairs = 0
         self.cow_copies = 0             # COW page copies dispatched
         self.tenant_blocked = 0         # admissions held by tenant quota
         self._tenant_pages: dict[str, int] = {}  # tenant -> private pages
@@ -806,6 +829,17 @@ class DecodeEngine:
         self._prev_brownout_level = 0
         # the current tick's closed phases, in closing order (engine thread)
         self._tick_phases: list = []
+
+    def _resident_dtype(self, params):
+        """``weights_dtype="bfloat16"``: every floating leaf resident in
+        bfloat16 (leaves that already are stay untouched; any other
+        setting leaves the tree as it is)."""
+        if self.config.weights_dtype != "bfloat16":
+            return params
+        return jax.tree.map(
+            lambda x: x.astype(jnp.bfloat16)
+            if jnp.issubdtype(x.dtype, jnp.floating)
+            and x.dtype != jnp.bfloat16 else x, params)
 
     def _phase(self, name: str, rid: Optional[str] = None) -> Phase:
         """One phase of the current tick, ``serve_tick.<name>``: closed, it
@@ -906,10 +940,17 @@ class DecodeEngine:
         # the same compiled text also answers whether a resident pool is
         # rewritten whole (kv_pool_relayout_ops): hand the audit the
         # per-device element counts of the pools (a tp shard holds 1/N)
-        return dataclasses.replace(manifest, kv_pool_elements=tuple(sorted({
-            math.prod(leaf.sharding.shard_shape(leaf.shape))
-            for leaf in jax.tree.leaves(self._cache)
-        })))
+        return dataclasses.replace(
+            manifest,
+            kv_pool_elements=tuple(sorted({
+                math.prod(leaf.sharding.shard_shape(leaf.shape))
+                for leaf in jax.tree.leaves(self._cache)
+            })),
+            # the model's own named scopes, for the audit's map from
+            # compiled instructions to scopes (``program_scopes`` record)
+            trace_scopes=tuple(
+                getattr(self._decode_model, "trace_scopes", ())),
+        )
 
     def _hot_program(self) -> str:
         """The steady-state program of a tick, the one whose compiled text
@@ -1042,6 +1083,7 @@ class DecodeEngine:
         if self._decode_fn is not None:
             return self._decode_fn
         device = self.config.sampling == "device"
+        routed = self._routed
 
         if self._pages is not None:
 
@@ -1049,19 +1091,26 @@ class DecodeEngine:
                        top_ks):
                 params = dequantize_serve_params(params)
                 cache = with_tables(pools, bt, ctx)
+                # a model with routed experts also hands back what the
+                # step routed where (counted over the live slots: an idle
+                # slot sits at context 0, which no live one does)
+                extra = (
+                    {"token_mask": (ctx > 0)[:, None]} if routed else {}
+                )
                 logits, vars_ = self._decode_model.apply(
                     {"params": params, "cache": cache},
                     tokens[:, None],
                     position_ids=ctx[:, None],
-                    mutable=["cache"],
+                    mutable=["cache", "routing"] if routed else ["cache"],
+                    **extra,
                 )
                 new_pools = strip_tables(vars_["cache"])
                 last = logits[:, 0, :].astype(jnp.float32)
                 if device:
-                    return (
-                        device_sample(last, seeds, steps, temps, top_ks),
-                        new_pools,
-                    )
+                    out = device_sample(last, seeds, steps, temps, top_ks)
+                    if routed:
+                        out = (out, routing_totals(vars_["routing"]))
+                    return out, new_pools
                 return last, new_pools
 
         else:
@@ -1539,10 +1588,10 @@ class DecodeEngine:
         the warm programs' input shapes/dtypes are invariant."""
         incoming = serve_params_variant(params)
         if incoming == self.variant:
-            return params, incoming
+            return self._resident_dtype(params), incoming
         if self.variant == "int8":
             return quantize_serve_params(params), incoming
-        return dequantize_serve_params(params), incoming
+        return self._resident_dtype(dequantize_serve_params(params)), incoming
 
     def request_swap(self, params, version: Optional[int]) -> SwapTicket:
         """Queue a validated weight swap from ANY thread; the serve loop
@@ -1720,6 +1769,8 @@ class DecodeEngine:
             "status": req.status,
             "finish_reason": req.finish_reason,
             "prompt_len": req.prompt_len,
+            "cached_tokens": req.cached_tokens,
+            "chunks": req.chunks,
             "bucket": req.bucket,
             "new_tokens": n,
             "queue_wait_s": queue_wait,
@@ -2072,6 +2123,7 @@ class DecodeEngine:
             raise
         req.prefix_hit = True
         req.cached_tokens = match.cached_len
+        self.prefix_cached_tokens += match.cached_len
         self._slots[slot] = _Slot(
             request=req, pending_token=-1, phase="prefill",
             prefill_pos=match.cached_len, spec=self._slot_spec(req),
@@ -2546,6 +2598,8 @@ class DecodeEngine:
         t0 = tick.t0
         worked = False
         admitted0, prefill_tokens0 = self.admitted, self.prefill_tokens
+        chunks0, cached0 = self.prefill_chunks, self.prefix_cached_tokens
+        moe_attrs = {}
 
         with self._phase("expire"):
             for req in self._queue.expire_overdue():
@@ -2686,6 +2740,10 @@ class DecodeEngine:
                 # the device operands and the output are done with: freed
                 # here, inside a phase, not at the tick's return
                 del ops, out
+                if self._routed and self.config.sampling == "device":
+                    # (ids, (tokens a held expert, pairs to absent ones))
+                    fetched, (held, absent) = fetched
+                    moe_attrs = self._count_routing(held, int(absent))
                 if self.config.sampling == "device":
                     sampled = fetched
                 else:
@@ -2716,6 +2774,9 @@ class DecodeEngine:
                 "decode_active": len(active),
                 "admitted": self.admitted - admitted0,
                 "prefill_tokens": self.prefill_tokens - prefill_tokens0,
+                "cached_tokens": self.prefix_cached_tokens - cached0,
+                "chunks": self.prefill_chunks - chunks0,
+                **moe_attrs,
             }
             depth = self._queue.depth()
             self._registry.gauge("serve/queue_depth", depth)
@@ -2840,6 +2901,21 @@ class DecodeEngine:
             self._registry.inc("serve/cancelled")
             self._finish(req, "cancelled", "cancelled")
 
+    def _count_routing(self, held, absent: int) -> dict:
+        """Fold one decode step's routing counts (tokens routed to each
+        held expert, summed over the expert layers; pairs routed to absent
+        experts) into the stats; returns the tick record's attributes."""
+        total, top = int(held.sum()), int(held.max())
+        self.moe_steps += 1
+        self.moe_held_tokens += total
+        self.moe_held_max_sum += top
+        self.moe_absent_pairs += absent
+        return {
+            "expert_tokens_max": top,
+            "expert_tokens_mean": total / len(held),
+            "absent_pairs": absent,
+        }
+
     def _kv_bytes_per_token(self) -> int:
         """Resident pool bytes one committed token occupies across every
         layer (K and V): ``head_dim`` values per head at the pool dtype,
@@ -2848,6 +2924,11 @@ class DecodeEngine:
         (at head_dim 64 and fp32 compute, int8 pools cost (64+4)/256 of
         the fp32 bytes per token)."""
         mcfg = self._decode_model.config
+        values = getattr(mcfg, "cache_values_per_token", None)
+        if values is not None:
+            # pools of another kind (a latent row a layer, an indexer key
+            # in some): the model counts its own
+            return values() * jnp.dtype(mcfg.compute_dtype).itemsize
         if self.config.kv_dtype == "int8":
             per_head = mcfg.head_dim + 4
         else:
@@ -2861,9 +2942,11 @@ class DecodeEngine:
         hot program, from its comm audit's record (None before warm-up,
         without one, or on the dense layout; 0 when every pool keeps one
         device layout from parameter to donated result)."""
+        return self._hot_audit().get("kv_pool_relayout_ops")
+
+    def _hot_audit(self) -> dict:
         hot = self._guards.wrapped.get(self._hot_program())
-        record = getattr(hot, "comm_record", None) or {}
-        return record.get("kv_pool_relayout_ops")
+        return getattr(hot, "comm_record", None) or {}
 
     def stats(self) -> dict:
         paged = self._pages is not None
@@ -2873,6 +2956,10 @@ class DecodeEngine:
             "admitted": self.admitted,
             "finished": self.finished,
             "kv_pool_relayout_ops": self._kv_pool_relayout_ops(),
+            # how many of those only stage a pool through on-chip memory
+            # and back, in one layout (analysis/spmd/hlo.count_space_moves)
+            "kv_pool_space_moves": self._hot_audit().get(
+                "kv_pool_space_moves"),
             "queue_depth": self._queue.depth(),
             "queue_depth_by_tier": self._queue.depth_by_tier(),
             "slot_occupancy": self.slot_occupancy(),
@@ -2904,6 +2991,16 @@ class DecodeEngine:
             "kv_pages_peak": self._pages.peak_used if paged else None,
             "page_exhausted": self.page_exhausted,
             "prefill_tokens": self.prefill_tokens,
+            "prefix_cached_tokens": self.prefix_cached_tokens,
+            "moe": (
+                {
+                    "steps": self.moe_steps,
+                    "held_tokens": self.moe_held_tokens,
+                    "held_max_sum": self.moe_held_max_sum,
+                    "absent_pairs": self.moe_absent_pairs,
+                }
+                if self._routed else None
+            ),
             "prefix_cache": (
                 {
                     **self._prefix.stats(),

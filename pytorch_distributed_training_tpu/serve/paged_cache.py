@@ -234,12 +234,15 @@ class PageAllocator:
 def with_tables(pools: Mapping[str, Any], block_table: Any,
                 context_len: Any) -> dict[str, Any]:
     """Rebuild a full cache tree from engine-resident ``pools`` by injecting
-    ``block_table``/``context_len`` beside every ``k_pages`` leaf (one per
-    attention layer). Used at TRACE level inside the jitted programs."""
+    ``block_table``/``context_len`` beside every attention layer's page
+    pools: the node that holds ``k_pages`` (per-head K/V pools) or
+    ``latent_pages`` (a latent pool, with or without an indexer pool
+    beside it: two kinds of pool under the one table). Used at TRACE level
+    inside the jitted programs."""
     def walk(node):
         if isinstance(node, Mapping):
             out = {k: walk(v) for k, v in node.items()}
-            if "k_pages" in node:
+            if "k_pages" in node or "latent_pages" in node:
                 out["block_table"] = block_table
                 out["context_len"] = context_len
             return out

@@ -321,9 +321,18 @@ _MODEL_PRESETS: dict[str, dict[str, Any]] = {
 }
 
 
-def model_preset(name: str, **overrides: Any) -> ModelConfig:
+def model_preset(name: str, **overrides: Any):
+    """The preset's configuration: a ``ModelConfig``, or the latent
+    expert family's own (``models/latent_moe.py::LatentMoEConfig``, which
+    is no set of ``ModelConfig`` fields: another block, other sizes)."""
     if name not in _MODEL_PRESETS:
-        raise KeyError(f"unknown model preset {name!r}; have {sorted(_MODEL_PRESETS)}")
+        from pytorch_distributed_training_tpu.models import latent_moe
+
+        if name in latent_moe.PRESETS:
+            return latent_moe.preset(name, **overrides)
+        raise KeyError(
+            f"unknown model preset {name!r}; have "
+            f"{sorted(_MODEL_PRESETS) + sorted(latent_moe.PRESETS)}")
     kwargs = dict(_MODEL_PRESETS[name])
     kwargs.update(overrides)
     return ModelConfig(**kwargs)
