@@ -93,7 +93,7 @@ class LatentMoEConfig:
     kv_layout: str = "paged"
     kv_page_size: int = 16
     kv_num_pages: int = 0
-    paged_attention_impl: str = "reference"
+    paged_attention_impl: str = "auto"
     kv_cache_dtype: str = "auto"
     paged_multiquery: bool = False
 

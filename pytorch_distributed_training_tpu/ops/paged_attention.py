@@ -30,28 +30,46 @@ Shapes
                   the query block itself). ``lengths >= q_len`` is an engine
                   contract: every query row has at least one visible token.
 
-Two implementations behind one signature:
+Which path runs where (``impl="auto"``, the default everywhere: the choice
+is made at trace time from what the code can see, and counted as
+``paged_attn:direct`` / ``paged_attn:xla`` in ``dispatch.DISPATCH_PATHS``,
+which ``serve_lm`` logs):
 
-- ``impl="reference"``: XLA gather + the exact einsum/softmax formula of the
-  dense flax cache path (models/bert.py ``_cached_attend``). Masked lanes go
-  to ``finfo.min`` so their exp underflows to an exact 0.0 in fp32; paged
+- **The page-walk kernel ``paged_attn``** (``_paged_page_walk``): a 3-D
+  query over float pools where ``dispatch.mode() == "direct"`` (one TPU
+  device, or the interpret context) and a page is whole tiles. This is the
+  decode step of every GPT-2/BERT-block decoder ``serve_lm`` serves paged on
+  one chip (`gpt2_medium_decode`). It takes the pools as they lie, copies
+  only the pages a slot's length covers, several a step with the next
+  block's copies in flight, and stays lane-dense inside; scores, softmax and
+  both accumulations in float32 (see the section below).
+- **The XLA formula** (``_paged_reference`` / ``_paged_reference_mq``): XLA
+  gather + the exact einsum/softmax formula of the dense flax cache path
+  (models/bert.py ``_cached_attend``), everywhere else: the CPU (the whole
+  tier-1 suite, and with it every token-identity pin), several devices
+  (``--tp``, ``fleet_lm``: the gate says ``"shard_map"`` or ``"off"``),
+  int8 pools (their scale pools keep a pages-minor layout), the 4-D query of
+  speculative verify, chunked prefill and prefix-cache tails. Masked lanes go
+  to ``finfo.min`` so their exp underflows to an exact 0.0 in fp32; its
   output is therefore token-identical to the dense cache whatever the pool
-  geometry (same argument that pins slotted serve to one-shot generate).
-- ``impl="pallas"``: an online-softmax page-walk kernel — grid (batch,
-  pages_per_seq), block table scalar-prefetched so each grid step's
-  ``index_map`` streams exactly one page of K/V into VMEM, running
-  max/denominator/accumulator rescaled per page, output written on the last
-  page. The kernels still take a ``(1, page_size, heads, head_dim)``
-  block, fed by a reshape of the lane-dense pool at the ``pallas_call``
-  boundary: on the chip that reshape is a whole-pool relayout, every
-  call (no CLI flag and no benchmark cell reaches this impl; a kernel
-  over the ``(1, page_size, heads*head_dim)`` page is ROADMAP A2).
-  ``interpret=`` falls back to the Pallas interpreter off-TPU (same
-  ``tpu_interpret_mode()`` contract as ops/flash_attention.py). The
-  single-query kernel computes in exact fp32 on the VPU; the multi-query
-  kernel's dots run on the MXU at its default precision, which rounds
-  fp32 operands to bf16 (on the chip: ~6e-3 max abs error against the
-  reference on fp32 pools, tests/test_tpu_kernels.py).
+  geometry (same argument that pins slotted serve to one-shot generate). It
+  unfolds the whole ``[slots, cache_len, heads, head_dim]`` window whatever
+  a slot holds: 49 of a 56 ms step at `gpt2_medium_decode` before the
+  kernel took its place there (PERF.md).
+
+``impl="reference"`` and ``impl="pallas"`` pin a path for the tests. An
+explicit ``"pallas"`` means ``paged_attn`` for a 3-D query over float pools,
+and otherwise the older kernels ``_paged_kernel`` (int8 pools) and
+``_paged_kernel_mq`` (4-D query): grid (batch, pages_per_seq), one page a
+grid step through the ``index_map``, over a ``(1, page_size, heads,
+head_dim)`` block fed by a reshape of the lane-dense pool at the
+``pallas_call`` boundary, which on the chip relays the WHOLE pool out every
+call; nothing chooses them by itself. The multi-query kernel's dots run on
+the MXU at its default precision, which rounds fp32 operands to bf16 (on the
+chip: ~6e-3 max abs error against the reference on fp32 pools,
+tests/test_tpu_kernels.py). ``interpret=`` falls back to the Pallas
+interpreter off-TPU (same ``tpu_interpret_mode()`` contract as
+ops/flash_attention.py).
 """
 
 from __future__ import annotations
@@ -63,9 +81,11 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from pytorch_distributed_training_tpu.ops import dispatch
 from pytorch_distributed_training_tpu.ops.flash_attention import _interpreting
 
 _NEG_INF = jnp.finfo(jnp.float32).min
+_LANES = 128
 
 
 _SCALE_AXES = ("num_pages", "page_size", "heads")
@@ -120,7 +140,7 @@ def paged_attention(
     lengths: jax.Array,
     *,
     scale: float,
-    impl: str = "reference",
+    impl: str = "auto",
     k_scales: jax.Array | None = None,
     v_scales: jax.Array | None = None,
 ) -> jax.Array:
@@ -186,26 +206,34 @@ def paged_attention(
         )
     _check_scale_pool("k_pages", k_pages, "k_scales", k_scales, heads)
     _check_scale_pool("v_pages", v_pages, "v_scales", v_scales, heads)
-    scales = (k_scales, v_scales)
-    if q.ndim == 4:
-        if impl == "reference":
-            return _paged_reference_mq(
-                q, k_pages, v_pages, block_table, lengths, scale, *scales
-            )
-        if impl == "pallas":
-            return _paged_pallas_mq(
-                q, k_pages, v_pages, block_table, lengths, scale, *scales
-            )
+    operands = (
+        q, k_pages, v_pages, block_table, lengths, scale, k_scales, v_scales
+    )
+    if impl not in ("auto", "reference", "pallas"):
         raise ValueError(f"unknown paged attention impl {impl!r}")
+    page_walk = q.ndim == 3 and k_scales is None
+    if impl == "auto":
+        # the choice, from what the trace can see: the page-walk kernel for
+        # the single-token read of float pools (in the query's dtype, as
+        # the models hold them) where the gate says one chip (or the
+        # interpreter) runs the kernels and the page is whole tiles
+        kernel = (
+            page_walk
+            and q.dtype == k_pages.dtype
+            and dispatch.mode() == "direct"
+            and _page_tiles(k_pages)
+        )
+        impl = "pallas" if kernel else "reference"
+    dispatch.note_path("paged_attn", "direct" if impl == "pallas" else "xla")
     if impl == "reference":
-        return _paged_reference(
-            q, k_pages, v_pages, block_table, lengths, scale, *scales
+        reference = _paged_reference_mq if q.ndim == 4 else _paged_reference
+        return reference(*operands)
+    if page_walk:
+        return _paged_page_walk(
+            q, k_pages, v_pages, block_table, lengths,
+            scale=scale, interpret=_interpreting(),
         )
-    if impl == "pallas":
-        return _paged_pallas(
-            q, k_pages, v_pages, block_table, lengths, scale, *scales
-        )
-    raise ValueError(f"unknown paged attention impl {impl!r}")
+    return (_paged_pallas_mq if q.ndim == 4 else _paged_pallas)(*operands)
 
 
 # ---------------------------------------------------------------- reference
@@ -255,7 +283,245 @@ def _paged_reference(q, k_pages, v_pages, block_table, lengths, scale,
     return jnp.einsum("bnt,btnd->bnd", probs, v)
 
 
-# ------------------------------------------------------------------- pallas
+# ------------------------------------------- pallas: the single-token read
+#
+# The decode step's read of float pools, as the pools lie in HBM:
+# ``[num_pages, page_size, heads * head_dim]``, a page one contiguous
+# ``[page_size, lanes]`` slab of whole tiles. The kernel never sees a pool
+# reshaped: both stay in HBM (``pl.ANY``) and the kernel copies the LIVE
+# pages of a slot, ``ceil(length / page_size)`` of them and no more, into a
+# double buffer of ``_BLOCK_TOKENS``-token blocks, the next block's copies
+# in flight while this one is computed — across slots too: the grid is
+# ``(slots,)``, and a slot's last block starts the next slot's first. An idle
+# slot (parked on page 0, length 1) costs one page.
+#
+# A head's lanes are never unfolded onto sublanes. With ``Qbd [heads, lanes]``
+# holding head ``n``'s query in row ``n``, lanes ``n*head_dim ..`` and zeros
+# elsewhere, the scores of a block are ``Qbd @ K^T`` = ``[heads, tokens]`` on
+# the MXU (products of the pool's dtype accumulated in float32: the zeros add
+# exact zeros, so this is the reference's contraction), the online softmax
+# runs in float32 over two vregs, and ``P [heads, tokens] @ V [tokens,
+# lanes]`` accumulates ``[heads, lanes]`` in float32, of which head ``n``
+# keeps its own lanes at the end. Rows of a buffer that no copy filled hold
+# whatever VMEM held: their scores are masked and their V rows zeroed before
+# the product (zero times NaN is NaN).
+
+_BLOCK_TOKENS = 128
+
+
+def _page_tiles(pages) -> bool:
+    """A page is whole (sublanes, 128) tiles, 8 rows of float32 or 16 of
+    bfloat16, so each page's copy lands on tile borders of the block
+    buffer (the chip's compiler refuses the others)."""
+    sublanes = 32 // pages.dtype.itemsize
+    return pages.shape[2] % _LANES == 0 and pages.shape[1] % sublanes == 0
+
+
+def _page_walk_kernel(
+    bt_ref,  # scalar-prefetch: [B, W] int32
+    len_ref,  # scalar-prefetch: [B] int32
+    q_ref,  # [1, 1, H*D]
+    k_hbm,  # [num_pages, P, H*D], in HBM
+    v_hbm,
+    o_ref,  # [1, 1, H*D]
+    kbuf,  # [2, C*P, H*D]: the block being computed and the one in flight
+    vbuf,
+    sems,  # DMA semaphores [K/V, buffer]
+    first_ref,  # SMEM [1]: the buffer that holds this slot's first block
+    m_ref,  # [H, 128] running max, lane-replicated
+    l_ref,  # [H, 128] running denominator
+    acc_ref,  # [H, H*D] float32
+    *,
+    scale: float,
+    page_size: int,
+    windows: int,
+    block_pages: int,
+    heads: int,
+    head_dim: int,
+):
+    b = pl.program_id(0)
+    block_tokens = block_pages * page_size
+    lanes = heads * head_dim
+    # float32 pools: every pass of the MXU, as the reference's products are
+    # exact float32; bf16 operands are exact in one
+    precision = (
+        jax.lax.Precision.HIGHEST if k_hbm.dtype == jnp.float32 else None
+    )
+
+    def live_pages(slot):
+        # at least one (the engine's lengths are >= 1; a slot always has a
+        # block in flight), at most the table's width
+        return jnp.clip(
+            jax.lax.div(len_ref[slot] + (page_size - 1), page_size),
+            1, windows,
+        )
+
+    def for_live_pages(slot, block, buf, fn):
+        """``fn`` on the copy of each LIVE page of a slot's block: a page
+        past the length costs neither a copy nor a wait."""
+        pages = live_pages(slot)
+        for i in range(block_pages):
+            w = block * block_pages + i
+
+            @pl.when(w < pages)
+            def _():
+                page = bt_ref[slot, w]
+                rows = pl.ds(i * page_size, page_size)
+                for pool, buffer, sem in (
+                    (k_hbm, kbuf, sems.at[0, buf]),
+                    (v_hbm, vbuf, sems.at[1, buf]),
+                ):
+                    fn(pltpu.make_async_copy(
+                        pool.at[page], buffer.at[buf, rows], sem
+                    ))
+
+    def start(slot, block, buf):
+        for_live_pages(slot, block, buf, lambda copy: copy.start())
+
+    def wait(slot, block, buf):
+        for_live_pages(slot, block, buf, lambda copy: copy.wait())
+
+    def blank_dead_rows(block, buf):
+        """V's rows of the block's dead pages, which no copy filled, hold
+        whatever VMEM held: zero them, since their zero probabilities do
+        not keep a NaN out of the product. (K's only reach their own
+        scores, which the mask replaces.)"""
+        for i in range(block_pages):
+
+            @pl.when(block * block_pages + i >= my_pages)
+            def _():
+                vbuf[buf, pl.ds(i * page_size, page_size)] = jnp.zeros(
+                    (page_size, lanes), vbuf.dtype
+                )
+
+    @pl.when(b == 0)
+    def _prime():
+        first_ref[0] = 0
+        start(0, 0, 0)
+
+    length = len_ref[b]
+    my_pages = live_pages(b)
+    num_blocks = jax.lax.div(my_pages + (block_pages - 1), block_pages)
+    first = first_ref[0]
+    m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
+    l_ref[...] = jnp.zeros_like(l_ref)
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    # Qbd: the slot's query, one head a row, each in its own lanes
+    row = jax.lax.broadcasted_iota(jnp.int32, (heads, lanes), 0)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (heads, lanes), 1)
+    own = (lane >= row * head_dim) & (lane < (row + 1) * head_dim)
+    q = jnp.broadcast_to(q_ref[0].astype(jnp.float32), (heads, lanes))
+    qbd = jnp.where(own, q, 0.0).astype(k_hbm.dtype)
+
+    def block_step(j, carry):
+        buf = (first + j) % 2
+        nxt = 1 - buf
+
+        @pl.when(j + 1 < num_blocks)
+        def _():
+            start(b, j + 1, nxt)
+
+        @pl.when((j + 1 == num_blocks) & (b + 1 < pl.num_programs(0)))
+        def _():
+            start(b + 1, 0, nxt)
+
+        wait(b, j, buf)
+        blank_dead_rows(j, buf)
+        k = kbuf[buf]  # [T, lanes]
+        v = vbuf[buf]
+        s = jax.lax.dot_general(
+            qbd, k, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32, precision=precision,
+        ) * scale  # [H, T]
+        pos = j * block_tokens + jax.lax.broadcasted_iota(
+            jnp.int32, s.shape, 1
+        )
+        valid = pos < length
+        s = jnp.where(valid, s, _NEG_INF)
+
+        m_prev = m_ref[...][:, :1]  # [H, 1]
+        l_prev = l_ref[...][:, :1]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        p = jnp.where(valid, jnp.exp(s - m_new), 0.0)  # [H, T]
+        l_new = alpha * l_prev + jnp.sum(p, axis=1, keepdims=True)
+        # probabilities meet V in the pool's dtype, as the reference's do
+        pv = jnp.dot(
+            p.astype(v.dtype), v,
+            preferred_element_type=jnp.float32, precision=precision,
+        )  # [H, lanes]
+        acc_ref[...] = acc_ref[...] * alpha + pv
+        m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
+        l_ref[...] = jnp.broadcast_to(l_new, l_ref.shape)
+        return carry
+
+    jax.lax.fori_loop(0, num_blocks, block_step, 0)
+    first_ref[0] = (first + num_blocks) % 2
+
+    # length >= 1 by engine contract, so l > 0; the where only shields the
+    # all-masked degenerate case from producing NaN
+    l = l_ref[...][:, :1]
+    out = acc_ref[...] / jnp.where(l > 0.0, l, 1.0)
+    # head n keeps lanes n*head_dim ..: one row of lanes again
+    o_ref[0] = jnp.sum(
+        jnp.where(own, out, 0.0), axis=0, keepdims=True
+    ).astype(o_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("scale", "interpret"))
+def _paged_page_walk(q, k_pages, v_pages, block_table, lengths, *, scale,
+                     interpret):
+    """Jitted, so that a model's layers, which call it with the same
+    shapes, share ONE trace and one lowering of the kernel (traced 24
+    times, gpt2-medium's decode program took 34 s longer to build)."""
+    batch, heads, head_dim = q.shape
+    _, page_size, lanes = k_pages.shape
+    windows = block_table.shape[1]
+    block_pages = max(1, _BLOCK_TOKENS // page_size)
+    slot_row = pl.BlockSpec((1, 1, lanes), lambda b, bt, ln: (b, 0, 0))
+    block = (2, block_pages * page_size, lanes)
+    out = pl.pallas_call(
+        functools.partial(
+            _page_walk_kernel,
+            scale=scale,
+            page_size=page_size,
+            windows=windows,
+            block_pages=block_pages,
+            heads=heads,
+            head_dim=head_dim,
+        ),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(batch,),
+            in_specs=[
+                slot_row,
+                pl.BlockSpec(memory_space=pl.ANY),
+                pl.BlockSpec(memory_space=pl.ANY),
+            ],
+            out_specs=slot_row,
+            scratch_shapes=[
+                pltpu.VMEM(block, k_pages.dtype),
+                pltpu.VMEM(block, v_pages.dtype),
+                pltpu.SemaphoreType.DMA((2, 2)),
+                pltpu.SMEM((1,), jnp.int32),
+                pltpu.VMEM((heads, _LANES), jnp.float32),
+                pltpu.VMEM((heads, _LANES), jnp.float32),
+                pltpu.VMEM((heads, lanes), jnp.float32),
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct((batch, 1, lanes), v_pages.dtype),
+        # the slots run in order: each starts the next one's first copies
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)
+        ),
+        name="paged_attn",
+        interpret=interpret,
+    )(block_table, lengths, q.reshape(batch, 1, lanes), k_pages, v_pages)
+    return out.reshape(q.shape)
+
+
+# ------------------------------------------- pallas: one page a grid step
 
 
 def _paged_kernel(
@@ -332,9 +598,6 @@ def _paged_kernel(
         o_ref[0] = (acc_ref[...] / l).astype(o_ref.dtype)
 
 
-_LANES = 128
-
-
 def _page_walk_specs(page_size, heads, head_dim, quantized):
     """K/V (and, for int8 pools, scale-pool) BlockSpecs: one page per grid
     step, chosen through the prefetched block table — this is the whole
@@ -360,8 +623,9 @@ def _kernel_pools(q, k_pages, v_pages):
     page_size, heads, head_dim]`` block shape. On the chip this reshape
     relays the WHOLE pool out, every call (the 4-D default layout puts the
     page axis on the lanes, the 3-D one does not): the price of keeping
-    the kernels' ``(1, P, H, D)`` page until they are rewritten for the
-    ``(1, P, H*D)`` one (ROADMAP A2)."""
+    these two kernels' ``(1, P, H, D)`` page. The decode step's read no
+    longer pays it (``_paged_page_walk``); nothing chooses these two
+    unless a test pins ``impl="pallas"``."""
     shape = (*k_pages.shape[:2], *q.shape[-2:])
     return k_pages.reshape(shape), v_pages.reshape(shape)
 

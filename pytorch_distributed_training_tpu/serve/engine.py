@@ -181,7 +181,9 @@ class EngineConfig:
     # Token selection: "device" (in-jit, [slots] int32 D2H per tick) or
     # "host" (fp32 logits D2H + np/eager sampling — the pinned reference).
     sampling: str = "device"
-    paged_attention_impl: str = "reference"
+    # "auto": the kernel dispatch gate chooses (ops/paged_attention.py);
+    # "reference" / "pallas" pin one path (tests)
+    paged_attention_impl: str = "auto"
     # Compile every program (all buckets + decode) at engine build so the
     # first request never pays compilation and strict tick-wide transfer
     # scoping arms from the first real tick.

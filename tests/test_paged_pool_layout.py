@@ -155,6 +155,66 @@ def test_cell_programs_never_relayout_a_pool(one_chip, cell_engine, program):
     assert count_aliased_buffers(text) == 2 * LAYERS
 
 
+@pytest.fixture(scope="module")
+def decode_text_on_one_chip(one_chip, cell_engine):
+    """The cell's decode program compiled with the kernel dispatch gate
+    answering as one TPU device does. The engine keeps one jitted decode
+    program and jax one trace of it, so the traces made under the other
+    answer are dropped before and after."""
+    from pytorch_distributed_training_tpu.ops import dispatch
+
+    fn, args = _program(cell_engine, "decode")
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(dispatch, "mode", lambda: "direct")
+        jax.clear_caches()
+        dispatch.DISPATCH_PATHS.clear()
+        try:
+            text = _compile_for(one_chip, fn, args)
+            paths = dict(dispatch.DISPATCH_PATHS)
+        finally:
+            dispatch.DISPATCH_PATHS.clear()
+            jax.clear_caches()
+    assert paths.get("paged_attn:direct") == LAYERS, paths
+    assert "paged_attn:xla" not in paths, paths
+    return text
+
+
+def test_decode_program_on_one_chip_reads_through_the_page_walk(
+    decode_text_on_one_chip,
+):
+    """With the gate at "direct" the cell's decode program holds one
+    ``paged_attn`` Mosaic call a layer, and nothing of the XLA formula's
+    ``[48, 1024, 16, 64]`` window (gathered, unfolded, widened) in any
+    dtype or axis order."""
+    text = decode_text_on_one_chip
+    calls = [
+        line for line in text.splitlines()
+        if "custom-call(" in line and "tpu_custom_call" in line
+        and "paged_attn" in line
+    ]
+    assert len(calls) == LAYERS, len(calls)
+    window = SLOTS * (BUCKETS[-1] + MAX_NEW) * HEADS * HEAD_DIM
+    sized = [
+        m.group(0)
+        for m in re.finditer(r"\b(?:bf16|f32|f16|s8|s32)\[([0-9,]+)\]", text)
+        if math.prod(int(d) for d in m.group(1).split(",")) == window
+    ]
+    assert not sized, sorted(set(sized))
+
+
+def test_decode_program_on_one_chip_keeps_the_pools_in_place(
+    decode_text_on_one_chip,
+):
+    """The kernel takes the pools as they lie: still 48 row-major
+    parameters, no pool-sized copy, transpose or convert around the 24
+    calls, every donated pool aliased."""
+    text = decode_text_on_one_chip
+    pool = PAGES * PAGE * HEADS * HEAD_DIM
+    assert _pool_parameter_orders(text, pool) == ["2,1,0"] * (2 * LAYERS)
+    assert count_relayouts(text, {pool}) == 0
+    assert count_aliased_buffers(text) == 2 * LAYERS
+
+
 def test_detector_counts_the_copies_around_a_4d_pool(one_chip):
     """The parent's shape, built by hand: a ``[3073, 16, 16, 64]`` pool's
     default layout puts the pages on the lanes, and a one-token-per-slot

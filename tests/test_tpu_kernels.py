@@ -362,6 +362,123 @@ def test_paged_pallas_matches_reference_on_chip(pool_dtype, q_len):
     np.testing.assert_allclose(got, ref, atol=tol, rtol=tol)
 
 
+def _cell_fill(busy, tokens=None, *, slots=48, windows=64, page_size=16,
+               seed=0):
+    """Block table and lengths of `gpt2_medium_decode`'s decode step with
+    ``busy`` slots at ``tokens`` tokens each (None: 1 .. 1,024, mixed) over
+    shuffled page ids; the others idle as the engine parks them (page 0,
+    length 1)."""
+    rng = np.random.default_rng(seed)
+    ids = rng.permutation(np.arange(1, 1 + slots * windows))
+    table = np.zeros((slots, windows), np.int32)
+    lengths = np.ones((slots,), np.int32)
+    for n, b in enumerate(rng.permutation(slots)[:busy]):
+        lengths[b] = tokens or rng.integers(1, windows * page_size + 1)
+        live = -(-int(lengths[b]) // page_size)
+        table[b, :live] = ids[n * windows:n * windows + live]
+    return table, lengths
+
+
+@pytest.mark.parametrize("pool_dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "f32"])
+def test_page_walk_matches_reference_at_the_decode_cell_on_chip(pool_dtype):
+    """``paged_attn`` through real Mosaic at `gpt2_medium_decode`'s
+    geometry (48 slots, 64 pages of 16, 16 x 64 heads, a 3,073-page pool),
+    the impl left to the gate: mixed lengths over shuffled pages with idle
+    slots between them, then every slot full."""
+    from pytorch_distributed_training_tpu.ops import dispatch
+    from pytorch_distributed_training_tpu.ops.paged_attention import (
+        paged_attention,
+    )
+
+    rng = np.random.default_rng(1)
+    shape = (3073, 16, 1024)
+    k = jnp.asarray(rng.standard_normal(shape, np.float32), pool_dtype)
+    v = jnp.asarray(rng.standard_normal(shape, np.float32), pool_dtype)
+    q = jnp.asarray(rng.standard_normal((48, 16, 64), np.float32), pool_dtype)
+    run = jax.jit(paged_attention, static_argnames=("scale", "impl"))
+    dispatch.DISPATCH_PATHS.clear()
+    for busy, tokens in ((30, None), (48, 1024)):
+        table, lengths = _cell_fill(busy, tokens)
+        args = (q, k, v, jnp.asarray(table), jnp.asarray(lengths))
+        ref = np.asarray(
+            run(*args, scale=0.125, impl="reference"), np.float32
+        )
+        got = np.asarray(run(*args, scale=0.125), np.float32)
+        assert np.isfinite(got).all()
+        # f32 pools: every MXU pass (precision HIGHEST); bf16: one bf16
+        # rounding of O(1) outputs
+        tol = 2e-2 if pool_dtype == jnp.bfloat16 else 2e-5
+        np.testing.assert_allclose(got, ref, atol=tol, rtol=tol)
+    # one trace serves both fills (the shapes are the same)
+    assert dispatch.DISPATCH_PATHS["paged_attn:direct"] == 1
+
+
+def test_decode_step_time_follows_the_fill_on_chip():
+    """The decode program of `gpt2_medium_decode` (gpt2-medium, 48 slots,
+    3,073 bf16 pages of 16, device sampling), timed at four fills with the
+    page walk (the default on one chip) and with the XLA formula pinned:
+    the kernel's step grows with what is live and stays under the
+    formula's, which costs the same whatever the fill. The times go to
+    ``chiprun_out/paged_decode_step_fills.json`` for PERF.md."""
+    import json
+    import os
+    import time
+
+    from pytorch_distributed_training_tpu.models.gpt2 import GPT2LMModel
+    from pytorch_distributed_training_tpu.serve.engine import (
+        DecodeEngine,
+        EngineConfig,
+    )
+    from pytorch_distributed_training_tpu.serve.queue import RequestQueue
+    from pytorch_distributed_training_tpu.utils.config import model_preset
+
+    model = GPT2LMModel(model_preset("gpt2-medium"))
+    params = jax.jit(
+        lambda: model.init(jax.random.key(0), jnp.ones((1, 8), jnp.int32))
+    )()["params"]
+    fills = {"4x200": (4, 200), "24x200": (24, 200), "48x200": (48, 200),
+             "48x1024": (48, 1024)}
+    step_ms = {}
+    for impl in ("auto", "reference"):
+        econf = EngineConfig(
+            num_slots=48, prompt_buckets=(64, 128, 256, 512),
+            max_new_tokens=512, page_size=16, kv_layout="paged",
+            sampling="device", warmup=False, paged_attention_impl=impl,
+        )
+        queue = RequestQueue(
+            max_depth=16, prompt_buckets=econf.prompt_buckets,
+            max_new_tokens=econf.max_new_tokens,
+        )
+        engine = DecodeEngine(model, params, econf, queue)
+        fn, pools = engine._decode_step_fn(), engine._cache
+        zeros = np.zeros((48,), np.int32)
+        for name, (busy, tokens) in fills.items():
+            table, lengths = _cell_fill(busy, tokens)
+            # the program appends one token at ctx, then reads ctx + 1
+            ctx = (lengths - 1).astype(np.int32)
+            ops = (zeros, table, ctx, zeros, zeros,
+                   np.zeros((48,), np.float32), zeros)
+            times = []
+            for _ in range(12):
+                t0 = time.perf_counter()
+                ids, pools = fn(engine._params, pools, *ops)
+                jax.block_until_ready(ids)
+                times.append((time.perf_counter() - t0) * 1e3)
+            step_ms[f"{impl}:{name}"] = float(np.median(times[2:]))
+        del engine, fn, pools
+    print("paged_decode_step_fills", json.dumps(step_ms))
+    os.makedirs("chiprun_out", exist_ok=True)
+    with open("chiprun_out/paged_decode_step_fills.json", "w") as f:
+        json.dump(step_ms, f, indent=1)
+    walk = [step_ms[f"auto:{name}"] for name in fills]
+    assert walk == sorted(walk), step_ms
+    assert all(
+        step_ms[f"auto:{name}"] < step_ms[f"reference:{name}"]
+        for name in fills
+    ), step_ms
+
+
 def test_dispatch_paths_at_model_shapes_on_chip():
     """Which path each fused op takes on ONE chip at the shapes the two
     smoke models run: bert-large's block tails (micro 8 x seq 128 x 1024)

@@ -208,3 +208,21 @@ def test_paged_attention_kernels_lower(pool_dtype, page_size, q_len):
         f, S(q_shape, F32 if quantized else pool_dtype), *pools,
         S((batch, windows), jnp.int32), S((batch,), jnp.int32), *scales,
     )
+
+
+def test_paged_attn_lowers_at_the_decode_cells_geometry(one_chip):
+    """The decode step's read as `gpt2_medium_decode` runs it: 48 slots x
+    64 pages of 16 over a 3,073-page bf16 pool, the impl left to the gate,
+    which answers as one chip does and so takes the page-walk kernel."""
+    slots, windows, page_size = 48, 64, 16
+    pool = S((1 + slots * windows, page_size, HEADS * HEAD_DIM), BF16)
+
+    def f(q, kp, vp, bt, ln):
+        return pa.paged_attention(q, kp, vp, bt, ln, scale=HEAD_DIM ** -0.5)
+
+    lower_for_tpu(
+        f, S((slots, HEADS, HEAD_DIM), BF16), pool, pool,
+        S((slots, windows), jnp.int32), S((slots,), jnp.int32),
+    )
+    assert took_kernel("paged_attn")
+
