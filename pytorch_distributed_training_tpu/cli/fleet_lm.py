@@ -52,15 +52,10 @@ def build_parser() -> argparse.ArgumentParser:
     p.add_argument("--max-new-tokens-cap", type=int, default=64)
     p.add_argument("--queue-depth", type=int, default=16)
     p.add_argument("--deadline-s", type=float, default=0.0)
-    p.add_argument("--kv-layout", default="paged", choices=("paged", "dense"),
-                   help="replica KV cache layout (see serve_lm)")
     p.add_argument("--page-size", type=int, default=16,
-                   help="tokens per KV page when --kv-layout=paged")
+                   help="tokens per KV page")
     p.add_argument("--num-pages", type=int, default=0,
                    help="KV page pool size per replica (0 = auto-size)")
-    p.add_argument("--sampling", default="device",
-                   choices=("device", "host"),
-                   help="replica sampling mode (see serve_lm)")
     p.add_argument("--tp", type=int, default=1,
                    help="tensor-parallel width per replica: each replica "
                         "subprocess spans this many devices (heads + MLP "
@@ -214,10 +209,8 @@ def main(argv=None) -> dict:
         "--max-new-tokens-cap", str(args.max_new_tokens_cap),
         "--queue-depth", str(args.queue_depth),
         "--deadline-s", str(args.deadline_s),
-        "--kv-layout", args.kv_layout,
         "--page-size", str(args.page_size),
         "--num-pages", str(args.num_pages),
-        "--sampling", args.sampling,
         "--guards", guard_mode,
     ]
     replica_env = {}

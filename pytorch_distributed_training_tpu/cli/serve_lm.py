@@ -29,13 +29,12 @@ with ``scripts/summarize_metrics.py``.
 Latent expert presets (``--model glm-5.2-share16``: latent attention with
 a learned sparse indexer, routed experts of which this chip holds a share;
 ``models/latent_moe.py``) serve through the same server, tick, allocator,
-prefix cache and sampling. They need ``--kv-layout paged --sampling
-device`` (the defaults) and, at the published widths, ``--weights-dtype
-bfloat16``; ``--prefill-chunk`` and ``--prefix-cache`` work over their
-pools. They refuse, at start-up and by the flag's name: ``--tp``,
-``--spec-k``, ``--weights-dtype int8``, ``--kv-dtype int8``, ``--kv-layout
-dense`` and ``--sampling host``. Size ``--num-pages`` yourself where
-prompts share prefixes (the default reserves every slot a whole context).
+prefix cache and sampling. They need, at the published widths,
+``--weights-dtype bfloat16``; ``--prefill-chunk`` and ``--prefix-cache``
+work over their pools. They refuse, at start-up and by the flag's name:
+``--tp``, ``--spec-k``, ``--weights-dtype int8`` and ``--kv-dtype int8``.
+Size ``--num-pages`` yourself where prompts share prefixes (the default
+reserves every slot a whole context).
 
 Live reload: with ``--checkpoint-dir`` the server exposes ``POST /swap``
 (swap to a named step) and ``--hotswap-poll-s N`` additionally watches the
@@ -67,30 +66,25 @@ def build_parser() -> argparse.ArgumentParser:
     p.add_argument("--queue-depth", type=int, default=16,
                    help="admission-queue depth; submissions beyond it are "
                         "rejected with a backpressure error")
-    p.add_argument("--kv-layout", default="paged",
-                   choices=("paged", "dense"),
-                   help="KV-cache layout: paged (block-table pages, "
-                        "page-budget admission) or dense (one "
-                        "[slots, cache_len] buffer — the A/B baseline)")
+    p.add_argument("--kv-layout", default="paged", choices=("paged",),
+                   help="accepted for existing command lines; no other "
+                        "value exists")
     p.add_argument("--page-size", type=int, default=16,
-                   help="tokens per KV page (paged layout); 128 matches the "
-                        "TPU lane width for real deployments")
+                   help="tokens per KV page; 128 matches the TPU lane "
+                        "width for real deployments")
     p.add_argument("--num-pages", type=int, default=0,
                    help="total KV pages incl. the reserved null page "
                         "(0 = auto-size so every slot fits a worst-case "
                         "request; set lower to trade admission concurrency "
                         "for KV memory — page exhaustion backpressures)")
-    p.add_argument("--sampling", default="device",
-                   choices=("device", "host"),
-                   help="token selection: device (in-jit sampling, [slots] "
-                        "int32 D2H per tick) or host (fp32 logits D2H + np "
-                        "sampling — the pinned reference path)")
+    p.add_argument("--sampling", default="device", choices=("device",),
+                   help="accepted for existing command lines; no other "
+                        "value exists")
     p.add_argument("--spec-k", type=int, default=0,
                    help="speculative decoding: draft tokens proposed per "
                         "slot per tick (0 = off); each verify dispatch "
                         "scores k+1 positions and commits every accepted "
-                        "one — same token stream, fewer dispatches "
-                        "(requires --kv-layout paged --sampling device)")
+                        "one — same token stream, fewer dispatches")
     p.add_argument("--draft-checkpoint", default=None,
                    help="trainer-format checkpoint dir for a small DRAFT "
                         "model that proposes the speculative tokens; "
@@ -110,8 +104,8 @@ def build_parser() -> argparse.ArgumentParser:
                         "over N devices (attention heads + MLP hidden on "
                         "a model-axis mesh, paged KV pools split by "
                         "heads; streams stay bit-identical to tp=1). "
-                        "Requires paged KV + device sampling and a model "
-                        "whose num_heads/intermediate_size divide by N")
+                        "Requires a model whose "
+                        "num_heads/intermediate_size divide by N")
     p.add_argument("--weights-dtype", default="float32",
                    choices=("float32", "bfloat16", "int8"),
                    help="serving weight precision: float32 leaves the "
@@ -128,16 +122,14 @@ def build_parser() -> argparse.ArgumentParser:
                    help="paged KV cache precision: int8 pools + fp32 "
                         "per-page-per-head scales beside the block tables "
                         "(~0.3x KV bytes/token at head_dim 16; allocator "
-                        "and admission arithmetic unchanged). Requires "
-                        "--kv-layout paged")
+                        "and admission arithmetic unchanged)")
     p.add_argument("--prefix-cache", action="store_true",
                    help="shared-KV prefix cache: finished prompts' pages "
                         "are indexed in a token-keyed trie and a matching "
                         "prompt prefix is served from the cache (refcounted "
                         "pages, copy-on-write at the divergence point) — "
                         "only the tail is prefilled, streams bit-identical "
-                        "to cold prefill. Requires --kv-layout paged + "
-                        "device sampling; a weight hot-swap flushes the "
+                        "to cold prefill; a weight hot-swap flushes the "
                         "index")
     p.add_argument("--tenant-page-quota", type=float, default=0.0,
                    help="per-tenant PRIVATE-page ceiling as a fraction of "

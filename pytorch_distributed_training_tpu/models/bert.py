@@ -346,7 +346,7 @@ class BertSelfAttention(nn.Module):
                 )
             out = paged_attention(
                 q[:, 0], kp.value, vp.value, bt.value, idx + 1,
-                scale=scale, impl=cfg.paged_attention_impl, **pool_kw,
+                scale=scale, **pool_kw,
             )
             return out[:, None]
         if cfg.paged_multiquery:
@@ -361,7 +361,7 @@ class BertSelfAttention(nn.Module):
                 )
             return paged_attention(
                 q, kp.value, vp.value, bt.value, idx + chunk,
-                scale=scale, impl=cfg.paged_attention_impl, **pool_kw,
+                scale=scale, **pool_kw,
             )
         # Prefill: fresh sequence (idx == 0 by engine contract), so the
         # visible context IS this chunk — attend intra-chunk with the exact

@@ -93,7 +93,6 @@ class LatentMoEConfig:
     kv_layout: str = "paged"
     kv_page_size: int = 16
     kv_num_pages: int = 0
-    paged_attention_impl: str = "auto"
     kv_cache_dtype: str = "auto"
     paged_multiquery: bool = False
 
@@ -144,11 +143,6 @@ class LatentMoEConfig:
                 f"{flag} is not supported for a latent-attention "
                 f"expert model: {why}")
 
-        if getattr(engine, "kv_layout", "paged") != "paged":
-            bad("--kv-layout dense", "its cache is latent page pools; "
-                "pass --kv-layout paged")
-        if getattr(engine, "sampling", "device") != "device":
-            bad("--sampling host", "pass --sampling device")
         if getattr(engine, "tp", 1) != 1:
             bad("--tp", "the latent pools have no head axis to shard and "
                 "no expert axis exists yet")

@@ -3,12 +3,12 @@
 Training (train/) and one-shot batch generation (models/generate.py) leave
 the repo with no way to SERVE a model; this package is that missing half:
 
-- ``engine``  — slotted KV-cache decode: a fixed ``[num_slots, ...]`` cache
-                (the flax "cache" collection with a vmapped slot axis), one
-                jitted prefill per prompt-length bucket, one jitted decode
-                step advancing every active slot per tick, admit/evict
-                between ticks (continuous batching, Orca-style; fixed slots
-                are the XLA-static-shape stand-in for paged KV blocks);
+- ``engine``  — slotted paged-KV decode: K/V page pools addressed through
+                per-slot block tables (``paged_cache``), one jitted
+                prefill per prompt-length bucket, one jitted decode step
+                advancing every active slot per tick with the next token
+                sampled in-trace (``sampling``), admit/evict between
+                ticks (continuous batching, Orca-style);
 - ``queue``   — bounded admission queue: ``BackpressureError`` at max
                 depth, per-request deadlines, FIFO-within-bucket
                 scheduling, weighted SLO tier lanes (interactive/batch)
@@ -35,8 +35,8 @@ the repo with no way to SERVE a model; this package is that missing half:
                 SIGTERM/exit-75 drain — no in-flight request dies);
 - ``trace``   — seeded open-loop traffic traces (Poisson base + burst
                 episodes, heavy-tailed sizes, SLO tiers, optional
-                multi-tenant shared-system-prompt mix) and the replay
-                driver behind ``bench.py --storm``;
+                multi-tenant shared-system-prompt mix) and their replay
+                driver (tests/test_storm.py);
 - ``prefix_cache`` — shared-KV prefix cache: a token-keyed trie over
                 finished prompts' fully-written page runs; a matching
                 request maps the shared pages into its block table
@@ -57,7 +57,7 @@ gauges go through ``telemetry/`` (``scripts/summarize_metrics.py``
 renders the serving percentile table), prefill/decode dispatch is armed
 under the ``faults/`` watchdog, and ``PDT_TPU_FAULT=slow_host:<f>x``
 stretches tick time deterministically to drill deadline/backpressure
-paths. ``bench.py --serve`` is the closed-loop load generator.
+paths. ``benchmarks/run.py`` is the load generator (chip only).
 """
 
 from pytorch_distributed_training_tpu.utils.lazy import lazy_exports

@@ -72,7 +72,7 @@ class AutoscaleConfig:
     #: Autoscaler and its worst burn rate holds at/above this, the pool is
     #: overloaded regardless of instantaneous queue depth (and is never
     #: idle while burning). 0.0 = off — the default keeps queue/page
-    #: signals the sole policy, so BENCH_storm semantics are unchanged.
+    #: signals the sole policy.
     slo_burn_high: float = 0.0
 
     def __post_init__(self):

@@ -7,46 +7,36 @@ wrong for a server where requests arrive at different times with
 different lengths. This engine is the serving counterpart (continuous
 batching a la Orca, block-structured KV a la vLLM's PagedAttention):
 
-- **KV layout** (``EngineConfig.kv_layout``):
-
-  * ``"paged"`` (default): K/V lives in fixed-size pages —
-    ``[num_pages, page_size, heads * head_dim]`` pools per attention
-    layer (lane-dense: a token's heads folded into the minor axis, so a
-    pool keeps ONE device layout from parameter to donated result and no
-    program relays it out around a write) — addressed through a
-    per-slot block table that ``serve/paged_cache.py`` allocates on
-    admit and frees on evict (defrag-free; page 0 is the reserved null
-    page idle slots park on).
-    The decode step runs the model at batch ``num_slots`` directly with
-    per-slot ``position_ids``/``context_len`` operands; no vmap, no
-    per-slot freeze select — page structure isolates slots. Admission is
-    a PAGE budget, not a slot-shape budget: one engine serves wildly
-    mixed context lengths, and the pool can be sized well under
-    ``num_slots * cache_len`` tokens (the dense layout's floor) because
-    short requests only hold the pages they need.
-  * ``"dense"``: the PR-4 layout — one resident ``[num_slots, 1,
-    cache_len, ...]`` flax cache, slot-vmapped decode, kept as the A/B
-    baseline (``bench.py --paged``) and fallback.
+- **KV pages**: K/V lives in fixed-size pages — ``[num_pages, page_size,
+  heads * head_dim]`` pools per attention layer (lane-dense: a token's
+  heads folded into the minor axis, so a pool keeps ONE device layout
+  from parameter to donated result and no program relays it out around a
+  write) — addressed through a per-slot block table that
+  ``serve/paged_cache.py`` allocates on admit and frees on evict
+  (defrag-free; page 0 is the reserved null page idle slots park on).
+  The decode step runs the model at batch ``num_slots`` directly with
+  per-slot ``position_ids``/``context_len`` operands; no vmap, no
+  per-slot freeze select — page structure isolates slots. Admission is
+  a PAGE budget, not a slot-shape budget: one engine serves wildly
+  mixed context lengths, and the pool can be sized well under
+  ``num_slots * cache_len`` tokens because short requests only hold the
+  pages they need.
 
 - **Prefill into a slot**: one jitted program per prompt-length *bucket*
-  (compilation stays bounded by the bucket list). Paged prefill scatters
-  the prompt's K/V straight into the slot's pages and attends
-  intra-chunk (no dense staging buffer); pad positions beyond the real
-  length are overwritten by generated tokens exactly one step before
-  the causal mask would first expose them — same argument as dense.
+  (compilation stays bounded by the bucket list). Prefill scatters the
+  prompt's K/V straight into the slot's pages and attends intra-chunk
+  (no staging buffer); pad positions beyond the real length are
+  overwritten by generated tokens exactly one step before the causal
+  mask would first expose them.
 
-- **Sampling** (``EngineConfig.sampling``):
-
-  * ``"device"`` (default): temperature/top-k/seed/step ride into the
-    jitted programs as traced per-slot operands and the next token is
-    selected in-trace (``serve/sampling.device_sample``; greedy is a
-    ``jnp.where`` select, per the traced-branch rule). Each tick's D2H
-    is ONE explicit ``jax.device_get`` of ``[slots]`` int32 ids — which
-    is why the whole tick can run under a strict
-    ``GuardSet.transfer_scope`` once every program is warm.
-  * ``"host"``: the PR-4 path — fp32 logits D2H, ``np``/eager sampling
-    on the host. Kept for the A/B and as the reference the device
-    sampler is pinned bit-identical against.
+- **Sampling**: temperature/top-k/seed/step ride into the jitted
+  programs as traced per-slot operands and the next token is selected
+  in-trace (``serve/sampling.device_sample``; greedy is a ``jnp.where``
+  select, per the traced-branch rule). Each tick's D2H is ONE explicit
+  ``jax.device_get`` of ``[slots]`` int32 ids — which is why the whole
+  tick can run under a strict ``GuardSet.transfer_scope`` once every
+  program is warm. (``serve/sampling.host_sample`` is the numpy mirror
+  the tests hold the device sampler to; the engine never calls it.)
 
 Integration: prefill/decode dispatch+block run under
 ``faults.watchdog_guard``; each tick routes through
@@ -54,11 +44,11 @@ Integration: prefill/decode dispatch+block run under
 tick-level queue-depth/slot-occupancy and per-tick
 ``kv_pages_used``/``kv_pages_free`` go through ``telemetry/``.
 
-**Speculative decoding** (``EngineConfig.spec_k > 0``, paged + device
-sampling only): a cheap draft lane proposes k tokens per slot per tick —
-either host-side n-gram self-drafting (``spec_draft="ngram"``, zero extra
-dispatches: prompt-lookup over the slot's own history) or a small draft
-model resident beside the base model (``spec_draft="model"``, greedy
+**Speculative decoding** (``EngineConfig.spec_k > 0``): a cheap draft
+lane proposes k tokens per slot per tick — either host-side n-gram
+self-drafting (``spec_draft="ngram"``, zero extra dispatches:
+prompt-lookup over the slot's own history) or a small draft model
+resident beside the base model (``spec_draft="model"``, greedy
 single-token draft dispatches sharing the allocator's block table into
 separate draft pools). ONE jitted verify dispatch then scores all k+1
 positions (pending token + k drafts) through the multi-token-query paged
@@ -86,13 +76,13 @@ queues a validated replacement params tree from any thread; the serve
 loop applies it at the START of the next tick (``swap_params`` — never
 mid-tick, so a tick is never torn between two weight versions) and the
 OLD params stay alive until the first post-swap tick completes cleanly
-(trial/commit; a trial-tick failure rolls back to them). The resident KV
-state (page pools or dense cache) is untouched by a swap — in-flight
-slots simply continue decoding on the new weights — and because the
+(trial/commit; a trial-tick failure rolls back to them). The resident
+page pools are untouched by a swap — in-flight slots simply continue
+decoding on the new weights — and because the
 replacement tree is validated to the same treedef/shapes/dtypes and
 pre-placed on device, the swap hits the existing compiled programs (no
 retrace, no implicit transfer: clean under ``PDT_TPU_GUARDS=strict``).
-Only the KV state is donated, so holding the previous params through the
+Only the pools are donated, so holding the previous params through the
 trial window is free of copies.
 """
 
@@ -161,44 +151,39 @@ class EngineConfig:
     cache_len``, which holds by construction since per-request
     ``max_new_tokens`` is capped at the config value.
 
-    Paged-layout sizing: a request admitted at bucket ``b`` holds
+    Pool sizing: a request admitted at bucket ``b`` holds
     ``ceil((b + max_new_tokens) / page_size)`` pages for its whole life
     (worst case reserved up front, so decode can never starve mid-answer).
     ``num_pages=0`` auto-sizes the pool so every slot can hold a
-    worst-case request (plus the reserved null page) — functionally
-    equivalent to dense capacity; set it LOWER to trade admission
-    concurrency for KV memory (page-exhaustion backpressure kicks in).
+    worst-case request (plus the reserved null page); set it LOWER to
+    trade admission concurrency for KV memory (page-exhaustion
+    backpressure kicks in).
     """
 
     num_slots: int = 4
     prompt_buckets: tuple = (16, 32, 64)
     max_new_tokens: int = 64
-    # KV layout: "paged" (block-table pages, the default) or "dense"
-    # (one [num_slots, cache_len] buffer — the A/B baseline).
+    # Fixed names, one value each: the engine has one KV layout and one
+    # sampler. Kept (with ``stats()["kv_layout"]``/``["sampling"]`` and
+    # serve_lm's two flags) because benchmarks/configs/*.json and
+    # tests/benchmarks/ still pass them; see ROADMAP C11.
     kv_layout: str = "paged"
+    sampling: str = "device"
     page_size: int = 16
     num_pages: int = 0          # total pages incl. null page; 0 = auto
-    # Token selection: "device" (in-jit, [slots] int32 D2H per tick) or
-    # "host" (fp32 logits D2H + np/eager sampling — the pinned reference).
-    sampling: str = "device"
-    # "auto": the kernel dispatch gate chooses (ops/paged_attention.py);
-    # "reference" / "pallas" pin one path (tests)
-    paged_attention_impl: str = "auto"
     # Compile every program (all buckets + decode) at engine build so the
     # first request never pays compilation and strict tick-wide transfer
     # scoping arms from the first real tick.
     warmup: bool = False
     # Speculative decoding: draft tokens proposed per slot per tick; 0
-    # disables (the legacy one-token decode program runs unchanged).
-    # Requires kv_layout="paged" + sampling="device".
+    # disables (the one-token decode program runs).
     spec_k: int = 0
     # Draft lane: "ngram" = host-side prompt-lookup self-drafting (no
     # draft checkpoint, zero extra dispatches); "model" = a small draft
     # model passed to the engine (greedy draft dispatches per tick).
     spec_draft: str = "ngram"
     # Chunked prefill: prompt tokens scattered per tick per slot; 0 keeps
-    # the monolithic per-bucket prefill programs. Requires paged + device
-    # sampling.
+    # the monolithic per-bucket prefill programs.
     prefill_chunk: int = 0
     # Max slots simultaneously mid-chunked-prefill; further admissions are
     # DEFERRED (transient queue hold, not page exhaustion) until a
@@ -208,7 +193,7 @@ class EngineConfig:
     # a `model`-axis mesh of this many devices, attention heads + MLP
     # hidden sharded (parallel/sharding.py serve rules), paged pools split
     # by heads. 1 = today's single-device engine, bit-identical
-    # streams either way. Requires kv_layout="paged" + sampling="device".
+    # streams either way.
     tp: int = 1
     # Serving precision variants. weights_dtype="int8" quantizes every
     # attention/MLP matmul weight ONCE at engine build (per-output-channel
@@ -231,7 +216,6 @@ class EngineConfig:
     # table (refcount bumped) and prefills only the tail — the tail streams
     # through the chunked-prefill program starting at the cached boundary.
     # Streams stay bit-identical to cold prefill (pinned by tests).
-    # Requires kv_layout="paged" + sampling="device".
     prefix_cache: bool = False
     # Per-tenant page quota as a fraction of the pool (0 = unlimited): a
     # tenant whose PRIVATE (non-shared) page footprint would exceed
@@ -257,14 +241,15 @@ class EngineConfig:
                 f"prompt_buckets must be positive lengths, got "
                 f"{self.prompt_buckets!r}"
             )
-        if self.kv_layout not in ("dense", "paged"):
-            raise ValueError(
-                f"kv_layout must be dense/paged, got {self.kv_layout!r}"
-            )
-        if self.sampling not in ("host", "device"):
-            raise ValueError(
-                f"sampling must be host/device, got {self.sampling!r}"
-            )
+        for flag, given, only in (
+            ("--kv-layout", self.kv_layout, "paged"),
+            ("--sampling", self.sampling, "device"),
+        ):
+            if given != only:
+                raise ValueError(
+                    f"{flag} {given}: that path was removed in PR 31; the "
+                    f"engine serves {only!r} only"
+                )
         if self.page_size < 1:
             raise ValueError(f"page_size must be >= 1, got {self.page_size}")
         if self.spec_k < 0:
@@ -282,28 +267,8 @@ class EngineConfig:
                 f"prefill_concurrency must be >= 1, got "
                 f"{self.prefill_concurrency}"
             )
-        if self.spec_k > 0 or self.prefill_chunk > 0:
-            # both features ride the multi-token-query paged program and
-            # in-jit sampling; the dense/host combinations stay the plain
-            # baseline (that's what the A/B benches compare against)
-            if self.kv_layout != "paged":
-                raise ValueError(
-                    "spec_k/prefill_chunk require kv_layout='paged'"
-                )
-            if self.sampling != "device":
-                raise ValueError(
-                    "spec_k/prefill_chunk require sampling='device'"
-                )
         if self.tp < 1:
             raise ValueError(f"tp must be >= 1, got {self.tp}")
-        if self.tp > 1:
-            # sharding rides the paged multi-token-query programs and the
-            # in-jit sampler (one replicated [slots] int32 D2H per tick);
-            # the dense/host baselines stay single-device by design
-            if self.kv_layout != "paged":
-                raise ValueError("tp > 1 requires kv_layout='paged'")
-            if self.sampling != "device":
-                raise ValueError("tp > 1 requires sampling='device'")
         if self.weights_dtype not in ("float32", "bfloat16", "int8"):
             raise ValueError(
                 f"weights_dtype must be float32/bfloat16/int8, got "
@@ -313,19 +278,6 @@ class EngineConfig:
             raise ValueError(
                 f"kv_dtype must be float32/int8, got {self.kv_dtype!r}"
             )
-        if self.kv_dtype == "int8" and self.kv_layout != "paged":
-            raise ValueError(
-                "kv_dtype='int8' requires kv_layout='paged' (the dense "
-                "cache has no scale-pool layout)"
-            )
-        if self.prefix_cache:
-            # cache hits prefill their tail through the multi-token-query
-            # chunk program with in-jit sampling, same substrate as
-            # spec_k/prefill_chunk
-            if self.kv_layout != "paged":
-                raise ValueError("prefix_cache requires kv_layout='paged'")
-            if self.sampling != "device":
-                raise ValueError("prefix_cache requires sampling='device'")
         if not 0.0 <= self.tenant_page_quota <= 1.0:
             raise ValueError(
                 f"tenant_page_quota must be in [0, 1], got "
@@ -340,14 +292,13 @@ class EngineConfig:
             raise ValueError(
                 f"flight_capacity must be >= 1, got {self.flight_capacity}"
             )
-        if self.kv_layout == "paged" and self.num_pages > 0:
-            if self.num_pages < self.pages_per_slot + 1:
-                raise ValueError(
-                    f"num_pages {self.num_pages} cannot hold even one "
-                    f"worst-case request ({self.pages_per_slot} pages + the "
-                    f"reserved null page) — a lone request would wait on "
-                    f"pages forever"
-                )
+        if 0 < self.num_pages < self.pages_per_slot + 1:
+            raise ValueError(
+                f"num_pages {self.num_pages} cannot hold even one "
+                f"worst-case request ({self.pages_per_slot} pages + the "
+                f"reserved null page) — a lone request would wait on "
+                f"pages forever"
+            )
 
     @property
     def cache_len(self) -> int:
@@ -382,21 +333,6 @@ def _check_tp_divisible(cfg, tp: int, role: str) -> None:
                 f"attention heads and the MLP hidden dim shard over the "
                 f"model axis, so each shard needs an equal slice"
             )
-
-
-def _patch_index_vars(cache, value):
-    """Set every ``cache_index``/``pos_index`` leaf (the dense flax cache's
-    scalar position state) to ``value`` — the one place the dense engine
-    steers WHERE the next token lands and WHICH position embedding it gets.
-    (The paged layout has no such leaves: positions travel as explicit
-    ``position_ids``/``context_len`` operands.)"""
-    def fix(path, leaf):
-        key = getattr(path[-1], "key", None)
-        if key in ("cache_index", "pos_index"):
-            return jnp.asarray(value).astype(leaf.dtype)
-        return leaf
-
-    return jax.tree_util.tree_map_with_path(fix, cache)
 
 
 @dataclasses.dataclass
@@ -549,18 +485,12 @@ class DecodeEngine:
             self._repl = jax.sharding.NamedSharding(
                 self._mesh, jax.sharding.PartitionSpec()
             )
-        paged = config.kv_layout == "paged"
-        dcfg = dataclasses.replace(cfg, decode=True, kv_layout=config.kv_layout)
-        if paged:
-            dcfg = dataclasses.replace(
-                dcfg,
-                kv_page_size=config.page_size,
-                kv_num_pages=config.total_pages,
-                paged_attention_impl=config.paged_attention_impl,
-                kv_cache_dtype=(
-                    "int8" if config.kv_dtype == "int8" else "auto"
-                ),
-            )
+        dcfg = dataclasses.replace(
+            cfg, decode=True, kv_layout="paged",
+            kv_page_size=config.page_size,
+            kv_num_pages=config.total_pages,
+            kv_cache_dtype="int8" if config.kv_dtype == "int8" else "auto",
+        )
         self._decode_model = type(model)(dcfg)
         # routed experts (a share of them held here): the decode step also
         # returns its routing counts, fetched with the sampled ids
@@ -571,10 +501,8 @@ class DecodeEngine:
         # A separate view — not a flag flip on _decode_model — so the
         # chunk==1 decode program and its bitwise pins are untouched.
         self._mq_model = None
-        if paged and (
-            config.spec_k > 0 or config.prefill_chunk > 0
-            or config.prefix_cache
-        ):
+        if (config.spec_k > 0 or config.prefill_chunk > 0
+                or config.prefix_cache):
             self._mq_model = type(model)(
                 dataclasses.replace(dcfg, paged_multiquery=True)
             )
@@ -616,7 +544,6 @@ class DecodeEngine:
                 dmc, decode=True, kv_layout="paged",
                 kv_page_size=config.page_size,
                 kv_num_pages=config.total_pages,
-                paged_attention_impl=config.paged_attention_impl,
                 scan_layers=False,
                 kv_cache_dtype=(
                     "int8" if config.kv_dtype == "int8" else "auto"
@@ -665,69 +592,53 @@ class DecodeEngine:
         # Runtime guards (analysis/guards.py): each compiled entry point is
         # wrapped so a retrace after its warm-up compile — one prefill per
         # bucket, one decode step — is a recorded violation, and warm calls
-        # run under the implicit-transfer guard. In device-sampling mode the
-        # WHOLE tick additionally runs under ``transfer_scope`` once every
-        # program is warm (strict mode: the single token-id device_get is
-        # the only D2H a tick is allowed).
+        # run under the implicit-transfer guard. The WHOLE tick
+        # additionally runs under ``transfer_scope`` once every program is
+        # warm (strict mode: the single token-id device_get is the only
+        # D2H a tick is allowed).
         self._guards = guards or GuardSet(
             mode=guard_mode_from_env(), registry=registry
         )
 
+        # Page pools are shaped by config, not by the init input; the
+        # abstract init only discovers the cache tree structure. The
+        # block_table/context_len placeholder leaves are per-call
+        # operands, not resident state — strip them.
+        shapes = jax.eval_shape(
+            lambda: self._decode_model.init(
+                jax.random.key(0),
+                jnp.ones((1, 1), jnp.int32),
+                position_ids=jnp.zeros((1, 1), jnp.int32),
+            )
+        )["cache"]
+        self._cache = self._place_pools(jax.tree.map(
+            lambda s: jnp.zeros(s.shape, s.dtype), strip_tables(shapes)
+        ))
+        self._pages = PageAllocator(
+            config.total_pages, config.page_size,
+            config.pages_per_slot, config.num_slots,
+        )
         # Shared-KV prefix cache (config.prefix_cache): trie over finished
-        # prompts' fully-written page runs, built beside the allocator below.
+        # prompts' fully-written page runs, beside the allocator.
         self._prefix = None
-        if paged:
-            # Page pools are shaped by config, not by the init input; the
-            # abstract init only discovers the cache tree structure. The
-            # block_table/context_len placeholder leaves are per-call
-            # operands, not resident state — strip them.
-            shapes = jax.eval_shape(
-                lambda: self._decode_model.init(
+        if config.prefix_cache:
+            from pytorch_distributed_training_tpu.serve.prefix_cache import (
+                PrefixCache,
+            )
+
+            self._prefix = PrefixCache(self._pages)
+        if self._draft_model is not None:
+            dshapes = jax.eval_shape(
+                lambda: self._draft_model.init(
                     jax.random.key(0),
                     jnp.ones((1, 1), jnp.int32),
                     position_ids=jnp.zeros((1, 1), jnp.int32),
                 )
             )["cache"]
-            self._cache = self._place_pools(jax.tree.map(
-                lambda s: jnp.zeros(s.shape, s.dtype), strip_tables(shapes)
+            self._draft_cache = self._place_pools(jax.tree.map(
+                lambda s: jnp.zeros(s.shape, s.dtype),
+                strip_tables(dshapes),
             ))
-            self._pages = PageAllocator(
-                config.total_pages, config.page_size,
-                config.pages_per_slot, config.num_slots,
-            )
-            if config.prefix_cache:
-                from pytorch_distributed_training_tpu.serve.prefix_cache import (  # noqa: E501
-                    PrefixCache,
-                )
-
-                self._prefix = PrefixCache(self._pages)
-            if self._draft_model is not None:
-                dshapes = jax.eval_shape(
-                    lambda: self._draft_model.init(
-                        jax.random.key(0),
-                        jnp.ones((1, 1), jnp.int32),
-                        position_ids=jnp.zeros((1, 1), jnp.int32),
-                    )
-                )["cache"]
-                self._draft_cache = self._place_pools(jax.tree.map(
-                    lambda s: jnp.zeros(s.shape, s.dtype),
-                    strip_tables(dshapes),
-                ))
-        else:
-            # Per-slot cache template comes from a batch-1 abstract init at
-            # the full cache length (no params materialized); the resident
-            # cache stacks it on a leading [num_slots] axis.
-            shapes = jax.eval_shape(
-                lambda: self._decode_model.init(
-                    jax.random.key(0),
-                    jnp.ones((1, config.cache_len), jnp.int32),
-                )
-            )["cache"]
-            self._cache = self._put(jax.tree.map(
-                lambda s: jnp.zeros((config.num_slots,) + s.shape, s.dtype),
-                shapes,
-            ))
-            self._pages = None
         self._slots: list[Optional[_Slot]] = [None] * config.num_slots
         self._prefill_fns: dict[int, object] = {}   # bucket -> jitted fn
         self._decode_fn = None
@@ -755,9 +666,8 @@ class DecodeEngine:
         self.decode_tokens = 0          # tokens emitted by decode-phase work
         self.prefill_chunks = 0         # chunk dispatches executed
         # prefix-cache accounting. prefill_tokens counts REAL prompt tokens
-        # actually pushed through a prefill program (monolithic or chunk) —
-        # the bench's cached-vs-cold reduction numerator — and is kept even
-        # with the cache off so A/B runs compare like with like.
+        # actually pushed through a prefill program (monolithic or chunk),
+        # cache on or off: what the cache saves is read against it.
         self.prefill_tokens = 0
         # prompt tokens admissions took from cached pages instead
         self.prefix_cached_tokens = 0
@@ -773,9 +683,6 @@ class DecodeEngine:
         self._tenant_pages: dict[str, int] = {}  # tenant -> private pages
         self._slot_charge: dict[int, tuple] = {}  # slot -> (tenant, pages)
         self._match_scratch = None      # (req_id, PrefixMatch) from accept
-        self._last_logits = np.zeros(
-            (config.num_slots, cfg.vocab_size), np.float32
-        )
         self.ticks = 0
         self.busy_ticks = 0         # ticks that admitted/decoded work — the
         # clock serve-scoped fault injection counts in
@@ -937,8 +844,6 @@ class DecodeEngine:
             )
         else:
             manifest = serve_manifest(1, name=name)
-        if self._pages is None:
-            return manifest
         # the same compiled text also answers whether a resident pool is
         # rewritten whole (kv_pool_relayout_ops): hand the audit the
         # per-device element counts of the pools (a tp shard holds 1/N)
@@ -964,95 +869,43 @@ class DecodeEngine:
         """Jitted prefill-into-slot for one prompt bucket. Compiles once per
         bucket (the queue only produces configured buckets).
 
-        Unified signature across layouts/sampling modes — the sampling
-        operands (seed/temperature/top_k) are traced inputs even in host
-        mode (jit drops unused inputs; keeping ONE signature keeps the
-        call sites and donation audits identical):
-
-        - paged: ``(params, pools, ids, real_len, bt_row, seed, temp, tk)``
-        - dense: ``(params, cache, slot, ids, real_len, seed, temp, tk)``
-
-        Returns ``(token_id | fp32 logits, new KV state)`` — a scalar int32
-        when sampling on device, the last position's ``[vocab]`` logits
-        when sampling on host.
+        ``(params, pools, ids, real_len, bt_row, seed, temp, top_k)``;
+        returns ``(token id, new pools)``: the first token, a scalar int32
+        sampled in-trace from the last real position's logits.
         """
         fn = self._prefill_fns.get(bucket)
         if fn is not None:
             return fn
-        device = self.config.sampling == "device"
 
-        def sample_or_logits(last, seed, temp, top_k):
-            if not device:
-                return last
-            return device_sample(
+        def prefill(params, pools, ids, real_len, bt_row, seed, temp,
+                    top_k):
+            # weight-only int8: dequantize in-trace (identity on fp32
+            # trees) — XLA folds the broadcast multiply into the
+            # matmuls, so only int8 kernels + scales stay resident
+            params = dequantize_serve_params(params)
+            # fresh sequence: context_len 0, K/V scattered straight
+            # into the slot's pages through its block-table row
+            cache = with_tables(
+                pools, bt_row, jnp.zeros((1,), jnp.int32)
+            )
+            logits, vars_ = self._decode_model.apply(
+                {"params": params, "cache": cache},
+                ids,
+                position_ids=jnp.arange(bucket, dtype=jnp.int32)[None],
+                mutable=["cache"],
+            )
+            new_pools = strip_tables(vars_["cache"])
+            last = jnp.take_along_axis(
+                logits, (real_len - 1)[None, None, None], axis=1
+            )[0, 0, :].astype(jnp.float32)
+            token = device_sample(
                 last[None], seed[None], jnp.zeros((1,), jnp.int32),
                 temp[None], top_k[None],
             )[0]
+            return token, new_pools
 
-        if self._pages is not None:
-
-            def prefill(params, pools, ids, real_len, bt_row, seed, temp,
-                        top_k):
-                # weight-only int8: dequantize in-trace (identity on fp32
-                # trees) — XLA folds the broadcast multiply into the
-                # matmuls, so only int8 kernels + scales stay resident
-                params = dequantize_serve_params(params)
-                # fresh sequence: context_len 0, K/V scattered straight
-                # into the slot's pages through its block-table row
-                cache = with_tables(
-                    pools, bt_row, jnp.zeros((1,), jnp.int32)
-                )
-                logits, vars_ = self._decode_model.apply(
-                    {"params": params, "cache": cache},
-                    ids,
-                    position_ids=jnp.arange(bucket, dtype=jnp.int32)[None],
-                    mutable=["cache"],
-                )
-                new_pools = strip_tables(vars_["cache"])
-                last = jnp.take_along_axis(
-                    logits, (real_len - 1)[None, None, None], axis=1
-                )[0, 0, :].astype(jnp.float32)
-                return sample_or_logits(last, seed, temp, top_k), new_pools
-
-        else:
-
-            def prefill(params, cache, slot, ids, real_len, seed, temp,
-                        top_k):
-                params = dequantize_serve_params(params)
-                # slot's private cache, position state reset for the new
-                # request
-                slot_cache = jax.tree.map(
-                    lambda g: jax.lax.dynamic_index_in_dim(
-                        g, slot, 0, keepdims=False
-                    ),
-                    cache,
-                )
-                slot_cache = _patch_index_vars(slot_cache, 0)
-                # right-padded prompt, no explicit mask: pads sit AFTER the
-                # real tokens, so causal-over-cache masking already hides
-                # them from every real query; pad K/V entries are
-                # overwritten by generated tokens one step before the
-                # causal mask would expose them
-                logits, vars_ = self._decode_model.apply(
-                    {"params": params, "cache": slot_cache},
-                    ids,
-                    mutable=["cache"],
-                )
-                new_slot = _patch_index_vars(vars_["cache"], real_len)
-                new_cache = jax.tree.map(
-                    lambda g, p: jax.lax.dynamic_update_slice(
-                        g, p[None], (slot,) + (0,) * p.ndim
-                    ),
-                    cache,
-                    new_slot,
-                )
-                last = jnp.take_along_axis(
-                    logits, (real_len - 1)[None, None, None], axis=1
-                )[0, 0, :].astype(jnp.float32)
-                return sample_or_logits(last, seed, temp, top_k), new_cache
-
-        # the resident KV state is rewritten every prefill: donate it so
-        # XLA updates pages/slots in place instead of holding a second full
+        # the resident pools are rewritten every prefill: donate them so
+        # XLA updates pages in place instead of holding a second full
         # copy alive across the call; audit_donation verifies
         # post-first-compile that XLA actually kept the aliasing
         fn = self._guards.wrap_jit(
@@ -1067,84 +920,45 @@ class DecodeEngine:
     def _decode_step_fn(self):
         """ONE jitted program advancing every slot a single token.
 
-        Unified signature (sampling operands traced in both modes):
-
-        - paged: ``(params, pools, tokens, bt, ctx, seeds, steps, temps,
-          top_ks)`` — batch-``num_slots`` apply with per-slot
-          ``position_ids``/``context_len``; idle slots' block-table rows
-          point at the null page, so their writes land there and their
-          outputs are discarded by the host (no freeze select needed).
-        - dense: ``(params, cache, tokens, active, seeds, steps, temps,
-          top_ks)`` — the slot-vmapped step; inactive slots compute too
-          (static shapes) but their cache is bit-frozen via
-          ``where(active, new, old)``.
-
-        Returns ``([slots] int32 token ids | [slots, vocab] fp32 logits,
-        new KV state)`` by sampling mode.
+        ``(params, pools, tokens, bt, ctx, seeds, steps, temps, top_ks)``:
+        batch-``num_slots`` apply with per-slot
+        ``position_ids``/``context_len``; idle slots' block-table rows
+        point at the null page, so their writes land there and their
+        outputs are discarded by the host (no freeze select needed).
+        Returns ``([slots] int32 token ids, new pools)``; a model with
+        routed experts returns ``(ids, routing counts)`` in the ids' place.
         """
         if self._decode_fn is not None:
             return self._decode_fn
-        device = self.config.sampling == "device"
         routed = self._routed
 
-        if self._pages is not None:
+        def decode(params, pools, tokens, bt, ctx, seeds, steps, temps,
+                   top_ks):
+            params = dequantize_serve_params(params)
+            cache = with_tables(pools, bt, ctx)
+            # a model with routed experts also hands back what the
+            # step routed where (counted over the live slots: an idle
+            # slot sits at context 0, which no live one does)
+            extra = (
+                {"token_mask": (ctx > 0)[:, None]} if routed else {}
+            )
+            logits, vars_ = self._decode_model.apply(
+                {"params": params, "cache": cache},
+                tokens[:, None],
+                position_ids=ctx[:, None],
+                mutable=["cache", "routing"] if routed else ["cache"],
+                **extra,
+            )
+            new_pools = strip_tables(vars_["cache"])
+            last = logits[:, 0, :].astype(jnp.float32)
+            out = device_sample(last, seeds, steps, temps, top_ks)
+            if routed:
+                out = (out, routing_totals(vars_["routing"]))
+            return out, new_pools
 
-            def decode(params, pools, tokens, bt, ctx, seeds, steps, temps,
-                       top_ks):
-                params = dequantize_serve_params(params)
-                cache = with_tables(pools, bt, ctx)
-                # a model with routed experts also hands back what the
-                # step routed where (counted over the live slots: an idle
-                # slot sits at context 0, which no live one does)
-                extra = (
-                    {"token_mask": (ctx > 0)[:, None]} if routed else {}
-                )
-                logits, vars_ = self._decode_model.apply(
-                    {"params": params, "cache": cache},
-                    tokens[:, None],
-                    position_ids=ctx[:, None],
-                    mutable=["cache", "routing"] if routed else ["cache"],
-                    **extra,
-                )
-                new_pools = strip_tables(vars_["cache"])
-                last = logits[:, 0, :].astype(jnp.float32)
-                if device:
-                    out = device_sample(last, seeds, steps, temps, top_ks)
-                    if routed:
-                        out = (out, routing_totals(vars_["routing"]))
-                    return out, new_pools
-                return last, new_pools
-
-        else:
-
-            def one(params, slot_cache, token, active):
-                logits, vars_ = self._decode_model.apply(
-                    {"params": params, "cache": slot_cache},
-                    jnp.reshape(token, (1, 1)),
-                    mutable=["cache"],
-                )
-                new_cache = jax.tree.map(
-                    lambda n, o: jnp.where(active, n, o), vars_["cache"],
-                    slot_cache,
-                )
-                return logits[0, 0, :].astype(jnp.float32), new_cache
-
-            def decode(params, cache, tokens, active, seeds, steps, temps,
-                       top_ks):
-                params = dequantize_serve_params(params)
-                logits, new_cache = jax.vmap(
-                    one, in_axes=(None, 0, 0, 0)
-                )(params, cache, tokens, active)
-                if device:
-                    return (
-                        device_sample(logits, seeds, steps, temps, top_ks),
-                        new_cache,
-                    )
-                return logits, new_cache
-
-        # KV state donated for the same reason as prefill: the decode tick
-        # consumes the whole resident cache/pools and returns the
-        # replacement (audited post-first-compile, like prefill)
+        # pools donated for the same reason as prefill: the decode tick
+        # consumes the whole resident pools and returns the replacement
+        # (audited post-first-compile, like prefill)
         self._decode_fn = self._guards.wrap_jit(
             "serve_decode",
             jax.jit(decode, donate_argnums=(1,)),
@@ -1155,8 +969,7 @@ class DecodeEngine:
 
     def _verify_fn(self):
         """ONE jitted program scoring all ``spec_k + 1`` positions per slot
-        and running exact-match acceptance on device (paged + device
-        sampling by config contract).
+        and running exact-match acceptance on device.
 
         ``(params, pools, tokens, bt, ctx, seeds, steps0, temps, top_ks)``
         with ``tokens`` [slots, k+1] int32 — row = [pending, d1..dk] — and
@@ -1397,16 +1210,14 @@ class DecodeEngine:
     def _warmup(self) -> None:
         """Compile every serving program (one prefill per bucket + the
         decode step) with null operands before the engine goes live.
-        Paged warm-up calls run against the reserved null page (all-zero
-        block tables); dense warm-up prefills slot 0 and decodes with
-        every slot inactive — both leave no state a real admit would see.
+        Warm-up calls run against the reserved null page (all-zero block
+        tables), so they leave no state a real admit would see.
         Also the precondition for strict tick-wide transfer scoping: after
         warm-up, ``_scope_ready()`` holds from the first real tick.
         One ``serve_setup.warmup.<program>`` phase per compiled program
         (compiles are synchronous at dispatch) and ``.drain`` for the null
         executions."""
         cfg = self.config
-        paged = self._pages is not None
         W = cfg.pages_per_slot
         draft = self._draft_model is not None
         outs = []
@@ -1415,27 +1226,19 @@ class DecodeEngine:
             return setup_phase(
                 "serve_setup.warmup." + program, registry=self._registry)
 
-        if paged and cfg.prefill_chunk > 0:
+        if cfg.prefill_chunk > 0:
             # ONE chunk program replaces the whole per-bucket prefill set
             with warm("chunk"):
                 outs.append(self._warm_chunk(draft))
         else:
             for bucket in cfg.prompt_buckets:
                 with warm(f"prefill_{bucket}"):
-                    if paged:
-                        ops = self._put((
-                            np.zeros((1, bucket), np.int32),
-                            np.int32(1),
-                            np.zeros((1, W), np.int32),
-                            np.int32(0), np.float32(0.0), np.int32(0),
-                        ))
-                    else:
-                        ops = self._put((
-                            np.int32(0),
-                            np.zeros((1, bucket), np.int32),
-                            np.int32(1),
-                            np.int32(0), np.float32(0.0), np.int32(0),
-                        ))
+                    ops = self._put((
+                        np.zeros((1, bucket), np.int32),
+                        np.int32(1),
+                        np.zeros((1, W), np.int32),
+                        np.int32(0), np.float32(0.0), np.int32(0),
+                    ))
                     out, self._cache = self._prefill_fn(bucket)(
                         self._params, self._cache, *ops
                     )
@@ -1448,7 +1251,7 @@ class DecodeEngine:
                         self._draft_cache = self._draft_prefill_fn(bucket)(
                             self._draft_params, self._draft_cache, *dops
                         )
-        if paged and cfg.prefix_cache:
+        if cfg.prefix_cache:
             if cfg.prefill_chunk == 0:
                 # cold prefills stay monolithic, but cache-hit TAILS stream
                 # through the chunk program — warm it too
@@ -1463,16 +1266,19 @@ class DecodeEngine:
                         self._draft_cache, *pg
                     )
         S = cfg.num_slots
-        if paged and cfg.spec_k > 0:
+        # (tokens, block table, context, seeds, steps, temperatures, top-ks)
+        # of the decode step; the verify program takes k+1 tokens a slot
+        step_ops = (
+            np.zeros((S, W), np.int32),
+            np.zeros((S,), np.int32),
+            np.zeros((S,), np.int32), np.zeros((S,), np.int32),
+            np.zeros((S,), np.float32), np.zeros((S,), np.int32),
+        )
+        if cfg.spec_k > 0:
             # verify replaces the single-token decode step entirely
             with warm("verify"):
-                ops = self._put((
-                    np.zeros((S, cfg.spec_k + 1), np.int32),
-                    np.zeros((S, W), np.int32),
-                    np.zeros((S,), np.int32),
-                    np.zeros((S,), np.int32), np.zeros((S,), np.int32),
-                    np.zeros((S,), np.float32), np.zeros((S,), np.int32),
-                ))
+                ops = self._put(
+                    (np.zeros((S, cfg.spec_k + 1), np.int32),) + step_ops)
                 out, self._cache = self._verify_fn()(
                     self._params, self._cache, *ops
                 )
@@ -1489,21 +1295,7 @@ class DecodeEngine:
                     outs.append(dout)
         else:
             with warm("decode"):
-                if paged:
-                    ops = self._put((
-                        np.zeros((S,), np.int32),
-                        np.zeros((S, W), np.int32),
-                        np.zeros((S,), np.int32),
-                        np.zeros((S,), np.int32), np.zeros((S,), np.int32),
-                        np.zeros((S,), np.float32), np.zeros((S,), np.int32),
-                    ))
-                else:
-                    ops = self._put((
-                        np.zeros((S,), np.int32),
-                        np.zeros((S,), bool),
-                        np.zeros((S,), np.int32), np.zeros((S,), np.int32),
-                        np.zeros((S,), np.float32), np.zeros((S,), np.int32),
-                    ))
+                ops = self._put((np.zeros((S,), np.int32),) + step_ops)
                 out, self._cache = self._decode_step_fn()(
                     self._params, self._cache, *ops
                 )
@@ -1515,12 +1307,9 @@ class DecodeEngine:
 
     def _scope_ready(self) -> bool:
         """True when the whole tick can run under the strict transfer
-        scope: device sampling (host sampling legitimately crosses D2H/H2D
-        in np/eager code) and every program compiled+warm (a cold compile
-        inside the scope would transfer its baked constants — that's what
+        scope: every program compiled+warm (a cold compile inside the
+        scope would transfer its baked constants — that's what
         ``warmup=True`` is for)."""
-        if self.config.sampling != "device":
-            return False
         required = []
         if self.config.spec_k > 0:
             required.append(self._verify_fn_)
@@ -1724,27 +1513,6 @@ class DecodeEngine:
         if ticket is not None:
             ticket.resolve(False, error=error, stage="tick")
 
-    # -------------------------------------------------------------- sampling
-
-    def _sample(self, req: GenRequest, logits: np.ndarray) -> int:
-        """Next token from fp32 logits, on the host (sampling="host").
-        Greedy mirrors generate()'s argmax (token-identical); temperature>0
-        draws from the request's own deterministic stream (seed folded with
-        the step index). ``serve/sampling.device_sample`` is the in-jit
-        mirror of exactly this function — the two are pinned bit-identical
-        by tests/test_paged.py."""
-        if req.temperature <= 0.0:
-            return int(np.argmax(logits))
-        scaled = logits / req.temperature
-        # clamp to vocab size: top_k >= vocab means "no truncation", and an
-        # oversized client value must not be able to crash the serve loop
-        k = min(req.top_k, scaled.shape[-1])
-        if k > 0:
-            kth = np.sort(scaled)[-k]
-            scaled = np.where(scaled < kth, np.finfo(np.float32).min, scaled)
-        key = jax.random.fold_in(jax.random.key(req.seed), len(req.tokens))
-        return int(jax.random.categorical(key, jnp.asarray(scaled)))
-
     # ------------------------------------------------------------ accounting
 
     def _emit_request_record(self, req: GenRequest) -> None:
@@ -1794,7 +1562,7 @@ class DecodeEngine:
         stamps (engine thread, at finish). The replica phases TILE the
         request exactly — queue is submit→admit, prefill is admit→first
         token, decode is first token→finish — so per-phase durations sum
-        to the serve span's total by construction (the bench's 5% gate).
+        to the serve span's total by construction (tests/test_obs.py).
         A request that never left the queue gets a queue span covering its
         whole life; ``admission`` (page reservation) nests under prefill;
         ``swap_overlap``/``brownout_clamp`` annotate what touched it."""
@@ -1828,8 +1596,7 @@ class DecodeEngine:
                        "tick": req.admit_tick},
             )
             if req.reserve_t is not None:
-                attrs = {"pages": self._pages_for(req)
-                         if self._pages is not None else 0}
+                attrs = {"pages": self._pages_for(req)}
                 if self._prefix is not None:
                     attrs["prefix_hit"] = req.prefix_hit
                     attrs["cached_tokens"] = req.cached_tokens
@@ -1914,19 +1681,15 @@ class DecodeEngine:
         return n / len(self._slots)
 
     def page_occupancy(self) -> float:
-        """Fraction of the KV page pool in use (0.0 under dense layout) —
-        an autoscaler pressure signal alongside queue depth."""
-        if self._pages is None:
-            return 0.0
+        """Fraction of the KV page pool in use — an autoscaler pressure
+        signal alongside queue depth."""
         total = self._pages.num_pages - 1
         return self._pages.pages_used / total if total > 0 else 0.0
 
     def page_split(self) -> tuple[int, int]:
         """(shared, free) page counts for /healthz — how much of the pool
         is multi-referenced (prefix cache + in-flight sharers) vs
-        immediately allocatable. (0, 0) under the dense layout."""
-        if self._pages is None:
-            return (0, 0)
+        immediately allocatable."""
         return (self._pages.pages_shared, self._pages.pages_free)
 
     def _free_slot(self) -> Optional[int]:
@@ -1936,10 +1699,9 @@ class DecodeEngine:
         return None
 
     def _evict(self, slot: int) -> None:
-        """Free ``slot`` for reuse; paged layout also returns its pages."""
+        """Free ``slot`` for reuse and return its pages."""
         self._slots[slot] = None
-        if self._pages is not None:
-            self._release_pages(slot)
+        self._release_pages(slot)
 
     def _release_pages(self, slot: int) -> None:
         """Drop ``slot``'s page references (shared pages survive in other
@@ -1987,8 +1749,7 @@ class DecodeEngine:
     def _admission_fits(self, req: GenRequest) -> bool:
         """Page-budget admission predicate (``RequestQueue.pop_ready``):
         the whole worst case must be allocatable up front, so an admitted
-        request can never starve mid-decode. Dense layout admits on slot
-        availability alone.
+        request can never starve mid-decode.
 
         With the prefix cache on, the trie match happens HERE (and is
         stashed for the admit that immediately follows a True return):
@@ -1997,8 +1758,6 @@ class DecodeEngine:
         quota is held without counting as page exhaustion, and page
         pressure first tries LRU-evicting cache-only runs before declaring
         the head blocked."""
-        if self._pages is None:
-            return True
         need = self._pages_for(req)
         match = None
         if self._prefix is not None:
@@ -2079,7 +1838,7 @@ class DecodeEngine:
         loop stream the prompt in ``prefill_chunk`` tokens at a time (the
         first dispatch happens on the SAME tick via ``_advance_prefills``
         order — admission itself is pure bookkeeping)."""
-        self._reserve(req, slot, self._pages_for(req))
+        self._reserve(req, slot)
         self._slots[slot] = _Slot(
             request=req, pending_token=-1, phase="prefill",
             prefill_pos=0, spec=self._slot_spec(req),
@@ -2158,14 +1917,14 @@ class DecodeEngine:
         self.admitted += 1
         self._registry.inc("serve/admitted")
 
-    def _reserve(self, req: GenRequest, slot: int, pages: int) -> None:
+    def _reserve(self, req: GenRequest, slot: int) -> None:
         """The bookkeeping of an admission: the request is running, its
         worst-case pages are the slot's and charged to its tenant."""
         self._mark_admitted(req)
-        if self._pages is not None:
-            self._pages.admit(slot, pages)
-            self._charge_tenant(slot, req.tenant, pages)
-            req.reserve_t = time.monotonic()
+        pages = self._pages_for(req)
+        self._pages.admit(slot, pages)
+        self._charge_tenant(slot, req.tenant, pages)
+        req.reserve_t = time.monotonic()
 
     def _admit(self, req: GenRequest, slot: int) -> None:
         """Prefill ``req`` into ``slot`` (reserved) and take its first
@@ -2175,34 +1934,24 @@ class DecodeEngine:
             phase.attrs = {"bucket": bucket, "prompt_len": req.prompt_len}
             padded = np.zeros((1, bucket), np.int32)
             padded[0, : req.prompt_len] = req.prompt_ids
-            paged = self._pages is not None
             try:
                 # ONE explicit H2D for all host-built operands (np →
                 # device); under the strict tick-wide transfer scope,
                 # explicit device_put/device_get are the only transfers a
                 # tick makes
-                sample_ops = (
+                ops = self._put((
+                    padded,
+                    np.int32(req.prompt_len),
+                    self._pages.block_table[slot : slot + 1],
                     np.int32(req.seed),
                     np.float32(req.temperature),
                     np.int32(min(req.top_k, np.iinfo(np.int32).max)),
-                )
-                if paged:
-                    ops = self._put((
-                        padded,
-                        np.int32(req.prompt_len),
-                        self._pages.block_table[slot : slot + 1],
-                    ) + sample_ops)
-                else:
-                    ops = self._put((
-                        np.int32(slot),
-                        padded,
-                        np.int32(req.prompt_len),
-                    ) + sample_ops)
+                ))
                 with watchdog_guard("serve_prefill"):
                     out, self._cache = self._prefill_fn(bucket)(
                         self._params, self._cache, *ops
                     )
-                    if paged and self._draft_model is not None:
+                    if self._draft_model is not None:
                         # mirror the prompt into the draft pools (same
                         # block-table row, draft-side K/V) so the draft
                         # lane shares the slot's committed context from its
@@ -2218,25 +1967,18 @@ class DecodeEngine:
                     # transfer — the exact pattern the transfer guard
                     # disallows on chips)
                     with self._phase("prefill_wait", req.id):
-                        fetched = jax.device_get(out)
+                        token = int(jax.device_get(out))
             except BaseException:
                 # failed admissions must not leak the pages just reserved
-                if paged:
-                    self._release_pages(slot)
+                self._release_pages(slot)
                 raise
             self.prefill_tokens += req.prompt_len
-            if paged:
-                # index the prompt's full pages BEFORE any release below:
-                # the cache's own reference keeps them alive past the slot
-                self._insert_prefix(slot, req)
-            if self.config.sampling == "device":
-                token = int(fetched)
-            else:
-                token = self._sample(req, fetched)
+            # index the prompt's full pages BEFORE any release below:
+            # the cache's own reference keeps them alive past the slot
+            self._insert_prefix(slot, req)
             self._emit_token(req, token)
             if self._is_terminal(req, token):
-                if paged:
-                    self._release_pages(slot)
+                self._release_pages(slot)
                 return
             self._slots[slot] = _Slot(
                 request=req, pending_token=token, spec=self._slot_spec(req)
@@ -2418,8 +2160,8 @@ class DecodeEngine:
         positions, accept the leading exact-match run on device, emit the
         accepted tokens plus the first divergence's stream sample.
         Non-spec slots ride the same dispatch with their acceptance forced
-        to 0 — they emit exactly the one token the legacy decode step
-        would. Rollback is implicit: the slot's context cursor only
+        to 0 — they emit exactly the one token the decode step would.
+        Rollback is implicit: the slot's context cursor only
         advances past what was accepted; rejected drafts' K/V lanes die by
         masking and are overwritten when their positions are legitimately
         reached (zero allocator churn, pinned by tests)."""
@@ -2526,11 +2268,11 @@ class DecodeEngine:
         params and the loop keeps serving — a bad swap must degrade the
         weights version, not availability.
 
-        Transfer discipline: once every program is warm and sampling runs
-        on device, the WHOLE tick body executes under
-        ``GuardSet.transfer_scope`` — in strict mode any implicit
-        host<->device copy raises; the tick's only transfers are the
-        explicit operand ``device_put`` and the token-id ``device_get``.
+        Transfer discipline: once every program is warm, the WHOLE tick
+        body executes under ``GuardSet.transfer_scope`` — in strict mode
+        any implicit host<->device copy raises; the tick's only transfers
+        are the explicit operand ``device_put`` and the token-id
+        ``device_get``.
         """
         with self._swap_lock:
             pending, self._pending_swap = self._pending_swap, None
@@ -2619,16 +2361,16 @@ class DecodeEngine:
                     self._finish(s.request, "expired", "deadline")
                     worked = True
 
-        # admissions: fill free slots in scheduler order; under the paged
-        # layout the FIFO head must also fit the page budget (a blocked
-        # head blocks the queue — no-bypass backpressure, requests behind
-        # it wait for pages to free rather than starving it)
+        # admissions: fill free slots in scheduler order; the FIFO head
+        # must also fit the page budget (a blocked head blocks the queue —
+        # no-bypass backpressure, requests behind it wait for pages to
+        # free rather than starving it)
         self._page_blocked = False
         # "streaming" engines park admitted prompts in prefill phase and
         # advance them chunk-by-chunk: chunked prefill always, and any
         # prefix-cache engine (cache-hit tails stream from the cached
         # boundary even when cold prefills stay monolithic)
-        chunked = self._pages is not None and self.config.prefill_chunk > 0
+        chunked = self.config.prefill_chunk > 0
         streaming = chunked or self._prefix is not None
         while True:
             req = None
@@ -2658,11 +2400,7 @@ class DecodeEngine:
                     elif chunked:
                         self._admit_chunked(req, slot)
                     else:
-                        self._reserve(
-                            req, slot,
-                            self._pages_for(req)
-                            if self._pages is not None else 0,
-                        )
+                        self._reserve(req, slot)
                         monolithic = True
                 if monolithic:
                     self._admit(req, slot)
@@ -2692,14 +2430,13 @@ class DecodeEngine:
                 i for i, s in enumerate(self._slots)
                 if s is not None and s.phase == "decode"
             ]
-        if active and self._pages is not None and self.config.spec_k > 0:
+        if active and self.config.spec_k > 0:
             self._verify_tick(active)
             worked = True
         elif active:
             with self._phase("operands"):
                 S = self.config.num_slots
                 tokens = np.zeros((S,), np.int32)
-                mask = np.zeros((S,), bool)
                 ctx = np.zeros((S,), np.int32)
                 seeds = np.zeros((S,), np.int32)
                 steps = np.zeros((S,), np.int32)
@@ -2709,57 +2446,43 @@ class DecodeEngine:
                     s = self._slots[i]
                     r = s.request
                     tokens[i] = s.pending_token
-                    mask[i] = True
                     ctx[i] = r.prompt_len + s.steps_done
                     seeds[i] = np.int32(r.seed)
                     steps[i] = s.steps_done + 1   # == len(r.tokens) at sample
                     temps[i] = r.temperature
                     top_ks[i] = min(r.top_k, np.iinfo(np.int32).max)
-                sample_ops = (seeds, steps, temps, top_ks)
-                if self._pages is not None:
-                    if streaming:
-                        # mid-prefill slots hold real pages but are not in
-                        # this dispatch — null their rows so the decode
-                        # scatter can't stomp a streaming prompt's K/V
-                        bt = np.zeros_like(self._pages.block_table)
-                        for i in active:
-                            bt[i] = self._pages.block_table[i]
-                    else:
-                        bt = self._pages.block_table
-                    ops = self._put((tokens, bt, ctx) + sample_ops)
+                if streaming:
+                    # mid-prefill slots hold real pages but are not in
+                    # this dispatch — null their rows so the decode
+                    # scatter can't stomp a streaming prompt's K/V
+                    bt = np.zeros_like(self._pages.block_table)
+                    for i in active:
+                        bt[i] = self._pages.block_table[i]
                 else:
-                    ops = self._put((tokens, mask) + sample_ops)
+                    bt = self._pages.block_table
+                ops = self._put(
+                    (tokens, bt, ctx, seeds, steps, temps, top_ks))
             with watchdog_guard("serve_decode"):
                 with self._phase("dispatch"):
                     out, self._cache = self._decode_step_fn()(
                         self._params, self._cache, *ops
                     )
-                # the tick's single D2H: [slots] int32 ids (device
-                # sampling) or [slots, vocab] fp32 logits (host sampling)
+                # the tick's single D2H: [slots] int32 ids
                 with self._phase("decode_wait"):
-                    fetched = jax.device_get(out)
+                    sampled = jax.device_get(out)
             with self._phase("emit"):
                 # the device operands and the output are done with: freed
                 # here, inside a phase, not at the tick's return
                 del ops, out
-                if self._routed and self.config.sampling == "device":
+                if self._routed:
                     # (ids, (tokens a held expert, pairs to absent ones))
-                    fetched, (held, absent) = fetched
+                    sampled, (held, absent) = sampled
                     moe_attrs = self._count_routing(held, int(absent))
-                if self.config.sampling == "device":
-                    sampled = fetched
-                else:
-                    self._last_logits = fetched
-                    sampled = None
                 for i in active:
                     s = self._slots[i]
                     s.steps_done += 1
                     s.request.decode_ticks += 1
-                    if sampled is not None:
-                        token = int(sampled[i])
-                    else:
-                        token = self._sample(
-                            s.request, self._last_logits[i])
+                    token = int(sampled[i])
                     self._emit_token(s.request, token)
                     if self._is_terminal(s.request, token):
                         self._evict(i)      # slot + pages free for reuse
@@ -2784,11 +2507,10 @@ class DecodeEngine:
             self._registry.gauge("serve/queue_depth", depth)
             self._registry.gauge(
                 "serve/slot_occupancy", self.slot_occupancy())
-            if self._pages is not None:
-                self._registry.gauge(
-                    "serve/kv_pages_used", self._pages.pages_used)
-                self._registry.gauge(
-                    "serve/kv_pages_free", self._pages.pages_free)
+            self._registry.gauge(
+                "serve/kv_pages_used", self._pages.pages_used)
+            self._registry.gauge(
+                "serve/kv_pages_free", self._pages.pages_free)
             if self._prefix is not None:
                 lookups = self._prefix.hits + self._prefix.misses
                 self._registry.gauge(
@@ -2854,10 +2576,7 @@ class DecodeEngine:
                     slots_active=sum(1 for s in self._slots if s is not None),
                     prefill_resident=self._prefill_resident(),
                     decode_active=len(active),
-                    pages_used=(
-                        self._pages.pages_used
-                        if self._pages is not None else 0
-                    ),
+                    pages_used=self._pages.pages_used,
                     brownout=(
                         self.brownout.level
                         if self.brownout is not None else 0
@@ -2941,9 +2660,9 @@ class DecodeEngine:
 
     def _kv_pool_relayout_ops(self) -> Optional[int]:
         """Whole-pool copy/transpose/convert instructions in the compiled
-        hot program, from its comm audit's record (None before warm-up,
-        without one, or on the dense layout; 0 when every pool keeps one
-        device layout from parameter to donated result)."""
+        hot program, from its comm audit's record (None before warm-up or
+        without one; 0 when every pool keeps one device layout from
+        parameter to donated result)."""
         return self._hot_audit().get("kv_pool_relayout_ops")
 
     def _hot_audit(self) -> dict:
@@ -2951,7 +2670,6 @@ class DecodeEngine:
         return getattr(hot, "comm_record", None) or {}
 
     def stats(self) -> dict:
-        paged = self._pages is not None
         return {
             "ticks": self.ticks,
             "busy_ticks": self.busy_ticks,
@@ -2982,15 +2700,13 @@ class DecodeEngine:
             "weights_dtype": self.config.weights_dtype,
             "kv_dtype": self.config.kv_dtype,
             "variant": self.variant,
-            "kv_bytes_per_token": (
-                self._kv_bytes_per_token() if paged else None
-            ),
-            "kv_page_size": self.config.page_size if paged else None,
-            "kv_pages_total": self._pages.num_pages - 1 if paged else None,
-            "kv_pages_used": self._pages.pages_used if paged else None,
-            "kv_pages_free": self._pages.pages_free if paged else None,
-            "kv_pages_shared": self._pages.pages_shared if paged else None,
-            "kv_pages_peak": self._pages.peak_used if paged else None,
+            "kv_bytes_per_token": self._kv_bytes_per_token(),
+            "kv_page_size": self.config.page_size,
+            "kv_pages_total": self._pages.num_pages - 1,
+            "kv_pages_used": self._pages.pages_used,
+            "kv_pages_free": self._pages.pages_free,
+            "kv_pages_shared": self._pages.pages_shared,
+            "kv_pages_peak": self._pages.peak_used,
             "page_exhausted": self.page_exhausted,
             "prefill_tokens": self.prefill_tokens,
             "prefix_cached_tokens": self.prefix_cached_tokens,

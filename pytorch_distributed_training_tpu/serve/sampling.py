@@ -1,7 +1,8 @@
 """On-device batched sampling for the decode engine.
 
-``device_sample`` is the in-jit mirror of ``DecodeEngine._sample`` (the
-host path): greedy argmax at temperature<=0, temperature + top-k
+``device_sample`` is the in-jit mirror of ``host_sample`` (numpy, below:
+the reference the tests compare against; the engine never calls it):
+greedy argmax at temperature<=0, temperature + top-k
 ``jax.random.categorical`` otherwise, with the per-request stream derived
 exactly the same way — ``fold_in(key(seed), step)`` where ``step`` is the
 number of tokens already emitted for the request. Folding sampling into
@@ -25,6 +26,7 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 
 def device_sample(logits, seeds, steps, temps, top_ks):
@@ -84,6 +86,24 @@ def device_sample(logits, seeds, steps, temps, top_ks):
         lambda: jnp.zeros_like(greedy),
     )
     return jnp.where(temps <= 0.0, greedy, sampled)
+
+
+def host_sample(logits, *, temperature, top_k, seed, step) -> int:
+    """Next token from one row of fp32 ``[vocab]`` logits, on the host:
+    the reference ``device_sample`` is pinned bit-identical against
+    (tests/test_paged.py). Greedy mirrors generate()'s argmax; temperature>0
+    draws from the request's own deterministic stream, ``seed`` folded with
+    ``step`` (tokens already emitted for the request)."""
+    if temperature <= 0.0:
+        return int(np.argmax(logits))
+    scaled = logits / temperature
+    # clamp to vocab size: top_k >= vocab means "no truncation"
+    k = min(top_k, scaled.shape[-1])
+    if k > 0:
+        kth = np.sort(scaled)[-k]
+        scaled = np.where(scaled < kth, np.finfo(np.float32).min, scaled)
+    key = jax.random.fold_in(jax.random.key(seed), step)
+    return int(jax.random.categorical(key, jnp.asarray(scaled)))
 
 
 def spec_accept(logits, draft, seeds, steps0, temps, top_ks):

@@ -1,8 +1,7 @@
-"""Seeded open-loop traffic traces: the storm the closed-loop bench can't send.
+"""Seeded open-loop traffic traces: the storm closed-loop clients can't send.
 
-Every earlier bench (``--serve``, ``--fleet``, ``--paged``, ``--spec``)
-drives CLOSED-LOOP clients: each thread waits for its answer before sending
-the next request, so the offered load self-throttles the moment the pool
+CLOSED-LOOP clients each wait for their answer before sending the next
+request, so the offered load self-throttles the moment the pool
 slows down — overload can never actually accumulate. Real traffic doesn't
 wait. This module generates an OPEN-LOOP arrival-time trace — requests fire
 at their scheduled wall-clock offsets whether or not earlier ones finished —
@@ -67,7 +66,7 @@ class TraceConfig:
     output_tokens_max: int = 64
     interactive_deadline_s: float = 30.0
     batch_deadline_s: float = 120.0
-    #: multi-tenant shared-system-prompt mix (bench.py --prefix): 0 keeps
+    #: multi-tenant shared-system-prompt mix: 0 keeps
     #: the legacy single-tenant trace BIT-IDENTICAL (no extra rng draws).
     #: With N tenants, each event is assigned a tenant uniformly and its
     #: prompt becomes [tenant's shared prefix of ``shared_prefix_len``

@@ -1,7 +1,7 @@
 """Metric primitives: counters, gauges, timer histograms, one registry.
 
 The reference repo's observability is rank-0 ``print`` (SURVEY.md §5); every
-BENCH_*/HISTORY_* artifact in this repo was hand-assembled from it. The
+HISTORY_* artifact in this repo was hand-assembled from it. The
 registry is the in-process half of the replacement: instrumentation sites
 (loaders, checkpointer, supervisor, the train loop) record into whatever
 registry is installed — cheap enough to stay on unconditionally — and the

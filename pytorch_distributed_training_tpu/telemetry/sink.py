@@ -2,7 +2,7 @@
 
 One record per line, appended and flushed as they happen, so a crashed or
 preempted run leaves a readable stream up to its last completed step — the
-machine-readable replacement for hand-assembling BENCH_*/HISTORY_* artifacts
+machine-readable replacement for hand-assembling HISTORY_* artifacts
 from rank-0 prints. Record types written by the framework:
 
 - ``run_meta``   — one header per (re)started run: mesh shape, chip/process

@@ -4,7 +4,7 @@ Two independent levers against cold-start latency:
 
 - ``enable_compile_cache()`` — called first by every entry point that
   compiles (the Trainer for the three train CLIs, ``serve_lm``,
-  ``generate_lm``, ``bench.py``, ``chip_smoke.py``) — turns on JAX's
+  ``generate_lm``, ``chip_smoke.py``) — turns on JAX's
   persistent compilation cache so a second run of the same program loads
   compiled executables instead of re-invoking XLA. WHERE the cache lives
   is decided outside the program: ``JAX_COMPILATION_CACHE_DIR`` when the

@@ -152,12 +152,6 @@ class ModelConfig:
     # harmless). Must be set > 0 before building a paged decode model —
     # the serving engine computes it from its slot/budget config.
     kv_num_pages: int = 0
-    # Paged decode-attention implementation (ops/paged_attention.py):
-    # "auto" = the kernel dispatch gate chooses at trace time (the
-    # page-walk kernel on one TPU device, the XLA formula elsewhere);
-    # "reference" = XLA gather+einsum (bitwise-pinned against the dense
-    # cache path) and "pallas" = the page-walk kernels, pinned by tests.
-    paged_attention_impl: str = "auto"
     # Storage dtype of the paged K/V pools (paged layout only): "auto"
     # stores pages in the compute dtype (the classic layout); "int8" stores
     # symmetric per-entry-per-head quantized pages plus fp32 ``k_scales``/
@@ -244,11 +238,6 @@ class ModelConfig:
         if self.kv_layout not in ("dense", "paged"):
             raise ValueError(
                 f"kv_layout must be dense/paged, got {self.kv_layout!r}"
-            )
-        if self.paged_attention_impl not in ("auto", "reference", "pallas"):
-            raise ValueError(
-                f"paged_attention_impl must be auto/reference/pallas, got "
-                f"{self.paged_attention_impl!r}"
             )
         if self.kv_cache_dtype not in ("auto", "int8"):
             raise ValueError(

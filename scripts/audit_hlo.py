@@ -57,7 +57,7 @@ GLOBAL, SEQ = 96, 128
 def build_step(micro, model_name="bert-large-cased", seq=None, global_batch=None):
     """(jitted train step, sharded state, one global batch) of the
     production recipe on the current mesh; ATTN / MATMUL / QUANT_DELAYED
-    in the environment pick the variants ``bench.py`` ships."""
+    in the environment pick the variants."""
     import os as _os
 
     import jax

@@ -3,7 +3,7 @@
 Reads the stream written by ``--metrics-dir`` (telemetry/sink.py) and prints
 one row per epoch: throughput (samples/sec/chip), where the step time went
 (data-wait %), and which host was slowest — the questions every perf PR has
-so far answered by hand-assembling BENCH_*/HISTORY_* artifacts.
+so far answered by hand-assembling HISTORY_* artifacts.
 
 Serving streams (cli/serve_lm.py ``--metrics-dir``) get their own table:
 when ``serve_request`` records are present the summary carries a ``serve``

@@ -62,27 +62,19 @@ import pytest  # noqa: E402
 
 def pytest_collection_modifyitems(config, items):
     """tpu-marked tests run only on the real chip (PDT_TPU_TESTS=1 tier);
-    everything else runs only on the CPU mesh — one suite, two tiers.
-    perf-marked benchmarks are opt-in (-m perf): they assert on wall-clock
-    comparisons, which would make tier-1 flaky under load."""
+    everything else runs only on the CPU mesh — one suite, two tiers."""
     skip_tpu = pytest.mark.skip(
         reason="on-TPU tier: run with PDT_TPU_TESTS=1 -m tpu on the chip"
     )
     skip_cpu = pytest.mark.skip(
         reason="CPU-mesh test: run without PDT_TPU_TESTS"
     )
-    skip_perf = pytest.mark.skip(
-        reason="timing benchmark: opt in with -m perf"
-    )
-    want_perf = "perf" in (config.getoption("-m") or "")
     for item in items:
         is_tpu = "tpu" in item.keywords
         if is_tpu and not _TPU_TIER:
             item.add_marker(skip_tpu)
         elif not is_tpu and _TPU_TIER:
             item.add_marker(skip_cpu)
-        if "perf" in item.keywords and not want_perf:
-            item.add_marker(skip_perf)
 
 
 @pytest.fixture(scope="session")

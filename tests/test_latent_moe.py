@@ -10,6 +10,7 @@ logits of spread 1.6 agree to 1e-4; the faults below read 0.5 to 9.
 """
 
 import dataclasses
+import functools
 import importlib
 import os
 import sys
@@ -65,7 +66,7 @@ def world():
     cfg = lm.preset("latent-moe-tiny")
     model = lm.LatentMoELM(cfg)
     ids = np.random.RandomState(0).randint(0, 512, (1, SEQ)).astype(np.int32)
-    params = model.init(jax.random.key(0), ids[:, :8])["params"]
+    params = jax.jit(model.init)(jax.random.key(0), ids[:, :8])["params"]
     params = adapters.install(params, source, family.of(CONFIG))
     kept = {}
     logits = ref.forward(MODEL, source, ids[0], np.arange(SEQ), keep=kept)
@@ -85,15 +86,36 @@ def _paged(world, pages=48, **over):
     return model, pools
 
 
+class _Static:
+    """A model as a static argument of `jax.jit`, hashed by identity (its
+    config dataclass does not hash); jit's cache keeps it alive."""
+
+    def __init__(self, model):
+        self.model = model
+
+    def __hash__(self):
+        return id(self.model)
+
+    def __eq__(self, other):
+        return self.model is other.model
+
+
+@functools.partial(jax.jit, static_argnums=0)
+def _apply(held, params, cache, ids, position_ids, kw):
+    # compiled once a model and shape: an eager apply costs 8-10 s a call
+    return held.model.apply(
+        {"params": params, "cache": cache}, ids, position_ids=position_ids,
+        mutable=["cache", "selection", "routing"], **kw)
+
+
 def _step(model, params, pools, ids, ctx0, bt_rows, **kw):
     """`ids` [b, n] appended at `ctx0` [b]; returns (logits, pools, vars)."""
     ids = jnp.asarray(ids)
     ctx0 = jnp.asarray(ctx0, jnp.int32)
     cache = with_tables(pools, jnp.asarray(bt_rows), ctx0)
-    logits, vars_ = model.apply(
-        {"params": params, "cache": cache}, ids,
-        position_ids=ctx0[:, None] + jnp.arange(ids.shape[1])[None],
-        mutable=["cache", "selection", "routing"], **kw)
+    logits, vars_ = _apply(
+        _Static(model), params, cache, ids,
+        ctx0[:, None] + jnp.arange(ids.shape[1])[None], kw)
     return logits, strip_tables(vars_["cache"]), vars_
 
 
@@ -143,8 +165,9 @@ def test_chosen_positions_equal_the_reference_exactly(world):
     """(b) float32, every layer: a full layer's positions are the
     reference's, a shared layer holds its full layer's; contexts pass
     index_topk, so the choice is a real one."""
-    _, vars_ = world["model"].apply(
-        {"params": world["params"]}, world["ids"], mutable=["selection"])
+    _, vars_ = jax.jit(lambda params, ids: world["model"].apply(
+        {"params": params}, ids, mutable=["selection"]))(
+            world["params"], world["ids"])
     masks = [_mask_of(vars_["selection"][f"layer_{i}"]["attention"], SEQ)
              for i in range(4)]
     for i in range(4):

@@ -45,7 +45,7 @@ def world():
     cfg = model_preset("latent-moe-tiny")
     assert isinstance(cfg, lm.LatentMoEConfig)
     model = lm.LatentMoELM(cfg)
-    params = model.init(
+    params = jax.jit(model.init)(
         jax.random.key(0), jnp.ones((1, 8), jnp.int32))["params"]
     params = adapters.install(params, source, family.of(CONFIG))
     rng = np.random.default_rng(4)
@@ -53,12 +53,17 @@ def world():
     prompts = [np.concatenate([prefix, rng.integers(1, 512, n).astype(np.int32)])
                for n in (9, 5, 12)]
 
+    # one compiled forward at one padded length (causal: the padding after
+    # a position cannot reach it), not an eager apply at every length
+    forward = jax.jit(lambda ids: model.apply({"params": params}, ids))
+    padded = max(len(p) for p in prompts) + NEW
+
     def greedy(prompt):
-        seq = list(prompt)
-        for _ in range(NEW):
-            logits = model.apply({"params": params}, np.asarray([seq], np.int32))
-            seq.append(int(jnp.argmax(logits[0, -1])))
-        return np.asarray(seq[len(prompt):], np.int32)
+        seq = np.zeros((1, padded), np.int32)
+        seq[0, :len(prompt)] = prompt
+        for n in range(len(prompt), len(prompt) + NEW):
+            seq[0, n] = int(jnp.argmax(forward(seq)[0, n - 1]))
+        return seq[0, len(prompt):len(prompt) + NEW]
 
     return dict(source=source, model=model, params=params, prompts=prompts,
                 want=[greedy(p) for p in prompts])
@@ -150,9 +155,10 @@ def test_every_engine_variant_serves_the_models_own_greedy_tokens(world, engine)
     (dict(sampling="host"), "--sampling host"),
 ])
 def test_unsupported_flags_are_refused_by_name_at_build(world, engine, flag):
-    config = EngineConfig(num_slots=2, prompt_buckets=(16,), max_new_tokens=4,
-                          **engine)
+    # the last two by ``EngineConfig`` itself, for every family
     with pytest.raises(ValueError, match=flag):
+        config = EngineConfig(
+            num_slots=2, prompt_buckets=(16,), max_new_tokens=4, **engine)
         InferenceServer(world["model"], world["params"], config)
 
 
@@ -164,12 +170,19 @@ def test_unsupported_flags_are_refused_by_name_at_build(world, engine, flag):
     (["--kv-layout", "dense"], "--kv-layout dense"),
     (["--sampling", "host"], "--sampling host"),
 ])
-def test_cli_refuses_them_at_start_up_before_anything_loads(argv, flag):
+def test_cli_refuses_them_at_start_up_before_anything_loads(
+        argv, flag, capsys):
     from pytorch_distributed_training_tpu.cli import serve_lm
 
     with pytest.raises(SystemExit) as e:
         serve_lm.main(["--model", "latent-moe-tiny", *argv])
-    assert flag in str(e.value.code)
+    if e.value.code == 2:
+        # argparse's own exit: the flag has one choice left, and the
+        # message on stderr names it and the value given
+        said = capsys.readouterr().err
+        assert f"argument {argv[0]}: invalid choice: '{argv[1]}'" in said
+    else:
+        assert flag in str(e.value.code)
 
 
 def test_cli_help_names_the_preset_flags():

@@ -3,16 +3,12 @@ serve/sampling.py, ops/paged_attention.py and their engine integration):
 allocator lifecycle, page-budget admission backpressure, block-table
 attention pins (reference vs dense formula, pallas-interpret vs reference),
 the device-sampler's bit-exactness pin against the host sampler, engine
-token-identity (paged vs dense vs one-shot generate, device vs host
-sampling), mixed-context serving below dense-equivalent memory, and the
-strict tick-wide transfer scope. CPU, tier-1 (except the perf-marked
-BENCH_paged gate).
+token-identity (greedy against one-shot generate; sampled streams of every
+engine variant against the host sampler over the plain model's no-cache
+logits), mixed-context serving below dense-equivalent memory, and the
+strict tick-wide transfer scope. CPU, tier-1.
 """
 
-import json
-import os
-import subprocess
-import sys
 import time
 
 import jax
@@ -34,15 +30,16 @@ from pytorch_distributed_training_tpu.serve.paged_cache import (
     strip_tables,
     with_tables,
 )
-from pytorch_distributed_training_tpu.serve.sampling import device_sample
+from pytorch_distributed_training_tpu.serve.sampling import (
+    device_sample,
+    host_sample,
+)
 from pytorch_distributed_training_tpu.serve.server import wait_until
 from pytorch_distributed_training_tpu.utils.config import model_preset
 
 from kv_pools import fold_heads  # sibling module (pytest sys.path)
 
 pytestmark = pytest.mark.serve
-
-REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 class ListSink:
@@ -340,13 +337,10 @@ def test_paged_attention_validates_shapes():
 
 
 def test_device_sample_bitwise_matches_host_sampler():
-    """serve/sampling.device_sample is the in-jit mirror of the engine's
-    host ``_sample``: same token id for every (temperature, top_k, seed,
+    """serve/sampling.device_sample is the in-jit mirror of
+    ``host_sample``: same token id for every (temperature, top_k, seed,
     step) cell, including greedy ties, k=0 (no truncation), k=1 and
     k >= vocab."""
-    from pytorch_distributed_training_tpu.serve.engine import DecodeEngine
-    from pytorch_distributed_training_tpu.serve.queue import GenRequest
-
     vocab = 32
     rng = np.random.default_rng(0)
     cases = [
@@ -369,38 +363,39 @@ def test_device_sample_bitwise_matches_host_sampler():
                 jnp.asarray(temps), jnp.asarray(top_ks),
             ))
             for i, (temp, top_k) in enumerate(cases):
-                req = GenRequest(
-                    id="x", prompt_ids=np.ones(1, np.int32),
-                    max_new_tokens=8, temperature=temp, top_k=top_k,
-                    seed=seed,
+                want = host_sample(
+                    logits[i], temperature=temp, top_k=top_k, seed=seed,
+                    step=step,
                 )
-                req.tokens = [0] * step     # host folds in len(req.tokens)
-                want = DecodeEngine._sample(None, req, logits[i])
                 assert int(got[i]) == want, (temp, top_k, seed, step)
 
 
 # --------------------------------------------------------- engine identity
 
 
-def _run_server(model, params, prompts, T, *, kv_layout, sampling,
-                temperature=0.0, top_k=0, seed=0, **cfg_kw):
+def _run_server(model, params, prompts, T, *, temperature=0.0, top_k=0,
+                seed=0, first_alone=False, **cfg_kw):
+    """Serve ``prompts`` (request ``i`` under ``seed + i``) through a
+    2-slot engine; ``first_alone`` finishes the first before the rest are
+    submitted (it seeds a prefix cache the others can hit)."""
     reg, sink = _registry()
     server = InferenceServer(
         model, params,
         EngineConfig(
             num_slots=2, prompt_buckets=(4, 8, 16), max_new_tokens=T,
-            kv_layout=kv_layout, sampling=sampling, **cfg_kw,
+            **cfg_kw,
         ),
         queue_depth=16, registry=reg,
     ).start()
     try:
-        reqs = [
-            server.submit(
+        reqs = []
+        for i, p in enumerate(prompts):
+            reqs.append(server.submit(
                 p, max_new_tokens=T, temperature=temperature, top_k=top_k,
                 seed=seed + i,
-            )
-            for i, p in enumerate(prompts)
-        ]
+            ))
+            if first_alone and i == 0:
+                assert wait_until(reqs[0].done.is_set, timeout=120)
         assert wait_until(
             lambda: all(r.done.is_set() for r in reqs), timeout=120
         )
@@ -410,10 +405,10 @@ def _run_server(model, params, prompts, T, *, kv_layout, sampling,
     return [np.asarray(r.tokens, np.int32) for r in reqs], server.stats()
 
 
-def test_paged_greedy_token_identical_to_dense_and_generate(lm):
-    """Acceptance pin: the paged engine's greedy continuations are
-    bit-identical to the dense engine's AND to one-shot generate() at the
-    exact prompt length."""
+def test_paged_greedy_token_identical_to_generate(lm):
+    """Acceptance pin: the engine's greedy continuations are bit-identical
+    to one-shot generate() (dense flax cache, lockstep: another program)
+    at the exact prompt length."""
     model, params = lm
     T = 5
     prompts = _prompts(model, [3, 6, 9, 14, 5], seed=7)
@@ -423,35 +418,82 @@ def test_paged_greedy_token_identical_to_dense_and_generate(lm):
         ]
         for p in prompts
     ]
-    paged, pstats = _run_server(
-        model, params, prompts, T, kv_layout="paged", sampling="device",
-    )
-    dense, dstats = _run_server(
-        model, params, prompts, T, kv_layout="dense", sampling="host",
-    )
-    for i, (p_toks, d_toks, ref) in enumerate(zip(paged, dense, want)):
+    paged, pstats = _run_server(model, params, prompts, T)
+    for i, (p_toks, ref) in enumerate(zip(paged, want)):
         np.testing.assert_array_equal(p_toks, ref, err_msg=f"paged req {i}")
-        np.testing.assert_array_equal(d_toks, ref, err_msg=f"dense req {i}")
     assert pstats["kv_layout"] == "paged" and pstats["kv_pages_peak"] > 0
-    assert dstats["kv_layout"] == "dense" and dstats["kv_pages_total"] is None
+    assert pstats["sampling"] == "device"
 
 
-def test_sampled_device_matches_host_under_fixed_seed(lm):
-    """Fixed-key sampled decode is exact across the sampling location AND
-    the cache layout: paged+device == dense+host, token for token."""
+def _no_cache_logits(model, params, seqs):
+    """fp32 ``[n, L, vocab]`` logits of the plain model (no cache, no
+    engine) over ``seqs``: ONE jitted forward at one padded length
+    (causal: padding after a position cannot reach it)."""
+    ids = np.zeros((len(seqs), max(len(q) for q in seqs)), np.int32)
+    for i, q in enumerate(seqs):
+        ids[i, : len(q)] = q
+    forward = jax.jit(lambda p, x: model.apply({"params": p}, x))
+    return np.asarray(forward(params, ids), np.float32)
+
+
+def assert_streams_are_host_samples(model, params, prompts, streams, *,
+                                    temperature, top_k, seed):
+    """Every served token ``t`` of request ``i`` is ``host_sample`` of the
+    plain model's own logits at that position under ``(seed + i, step=t)``
+    (``step``: tokens already emitted): the reference is independent of
+    any engine, and holds the per-slot seed/step/temperature/top-k
+    operands and the ``fold_in(key(seed), step)`` stream to it."""
+    logits = _no_cache_logits(
+        model, params, [np.concatenate([p, q]) for p, q in zip(prompts, streams)]
+    )
+    for i, (p, stream) in enumerate(zip(prompts, streams)):
+        for t, token in enumerate(stream):
+            want = host_sample(
+                logits[i, len(p) - 1 + t], temperature=temperature,
+                top_k=top_k, seed=seed + i, step=t,
+            )
+            assert int(token) == want, f"request {i} token {t}"
+
+
+@pytest.mark.parametrize("engine", [
+    dict(),
+    dict(prefill_chunk=4),
+    dict(prefix_cache=True, page_size=4),
+    dict(spec_k=3),
+], ids=["bucket", "prefill_chunk", "prefix_cache", "spec_k3"])
+def test_sampled_stream_is_the_host_samplers_under_fixed_seed(lm, engine):
+    """Fixed-key sampled decode is exact across the sampling location in
+    every engine variant: per-bucket prefill, chunked prefill, a
+    prefix-cache hit (tail through the chunk program) and the k+1-position
+    verify block each emit the host sampler's token at every position."""
     model, params = lm
     T = 6
-    prompts = _prompts(model, [3, 7, 12], seed=3)
+    rng = np.random.default_rng(3)
+    shared = rng.integers(1, model.config.vocab_size, 9).astype(np.int32)
+    prompts = [
+        np.concatenate([
+            shared[:n], rng.integers(1, model.config.vocab_size, m),
+        ]).astype(np.int32)
+        for n, m in ((9, 3), (9, 5), (0, 7))    # 12, 14 (shared 9), 7
+    ]
     kw = dict(temperature=0.8, top_k=5, seed=11)
-    device_toks, _ = _run_server(
-        model, params, prompts, T, kv_layout="paged", sampling="device", **kw
+    streams, stats = _run_server(
+        model, params, prompts, T, first_alone=True, **kw, **engine
     )
-    host_toks, _ = _run_server(
-        model, params, prompts, T, kv_layout="dense", sampling="host", **kw
+    assert all(len(q) == T for q in streams)
+    if "prefix_cache" in engine:
+        # the second prompt mapped the first's pages: two full ones and a
+        # copy of the third, which they share up to mid-page
+        assert stats["prefix_cache"]["prefix_hits"] == 1
+        assert stats["prefix_cached_tokens"] == 9
+        assert stats["prefix_cache"]["cow_copies"] == 1
+    if "spec_k" in engine:
+        assert stats["spec_dispatches"] > 0
+    if "prefill_chunk" in engine:
+        assert stats["prefill_chunks"] > len(prompts)
+    assert_streams_are_host_samples(
+        model, params, prompts, streams, **kw
     )
-    for i, (d, h) in enumerate(zip(device_toks, host_toks)):
-        assert len(d) == T
-        np.testing.assert_array_equal(d, h, err_msg=f"request {i}")
 
 
 def test_page_exhaustion_backpressure_never_hangs(lm):
@@ -474,7 +516,7 @@ def test_page_exhaustion_backpressure_never_hangs(lm):
         model, params,
         EngineConfig(
             num_slots=4, prompt_buckets=(8,), max_new_tokens=T,
-            kv_layout="paged", sampling="device", page_size=4, num_pages=5,
+            page_size=4, num_pages=5,
         ),
         queue_depth=8, registry=reg,
     ).start()
@@ -498,11 +540,10 @@ def test_page_exhaustion_backpressure_never_hangs(lm):
 
 
 def test_mixed_context_pool_below_dense_equivalent(lm):
-    """One paged engine admits a 1x-8x mixed-context workload through a
-    pool SMALLER than num_slots x longest-context — the shape the dense
-    layout cannot configure at equal memory (it charges every slot the
-    longest context) — and stays greedy-exact including the longest
-    request."""
+    """One engine admits a 1x-8x mixed-context workload through a pool
+    SMALLER than num_slots x longest-context (what a buffer a slot would
+    need: it charges every slot the longest context) and stays
+    greedy-exact including the longest request."""
     model, params = lm
     T = 4
     lengths = [3, 4, 26, 32, 4, 20]
@@ -522,7 +563,6 @@ def test_mixed_context_pool_below_dense_equivalent(lm):
         model, params,
         EngineConfig(
             num_slots=4, prompt_buckets=(4, 32), max_new_tokens=T,
-            kv_layout="paged", sampling="device",
             page_size=page_size, num_pages=num_pages,
         ),
         queue_depth=8, registry=reg,
@@ -569,7 +609,7 @@ def test_strict_tick_scope_two_buckets_zero_implicit_transfers(lm):
         model, params,
         EngineConfig(
             num_slots=2, prompt_buckets=(4, 8), max_new_tokens=4,
-            kv_layout="paged", sampling="device", warmup=True,
+            warmup=True,
         ),
         queue_depth=16, registry=reg, guards=gs,
     ).start()
@@ -642,42 +682,37 @@ def test_periodic_lock_summary_emits_on_cadence_and_stops():
         start_periodic_summary(0.0, registry=reg, lock_registry=lr)
 
 
-# ------------------------------------------------------------ perf gate
+# ------------------------------------------------ one layout, one sampler
 
 
-@pytest.mark.perf
-def test_paged_bench_device_sampling_beats_dense_host(tmp_path):
-    """bench.py --paged: on the UNIFORM workload the paged cache + on-device
-    sampling must sustain at least the dense cache + host sampling's
-    tokens/sec (the PR's perf acceptance gate), and the mixed workload must
-    run through a page pool smaller than the dense-equivalent allocation."""
-    out = tmp_path / "BENCH_paged.json"
-    proc = subprocess.run(
-        [
-            sys.executable, os.path.join(REPO_ROOT, "bench.py"),
-            "--paged", "--paged-out", str(out),
-        ],
-        capture_output=True, text=True, timeout=1200, cwd=REPO_ROOT,
-    )
-    assert proc.returncode == 0, proc.stderr[-3000:]
-    result = json.loads(out.read_text())
+@pytest.mark.parametrize("field,old,flag", [
+    ("kv_layout", "dense", "--kv-layout dense"),
+    ("sampling", "host", "--sampling host"),
+])
+def test_engine_config_refuses_the_removed_path_by_its_flag(field, old, flag):
+    with pytest.raises(ValueError, match=f"{flag}: .*removed in PR 31"):
+        EngineConfig(**{field: old})
+    # the value that is left passes under its old name (the benchmark's
+    # configurations still give it)
+    only = getattr(EngineConfig(), field)
+    assert getattr(EngineConfig(**{field: only}), field) == only
 
-    uni = result["uniform"]
-    assert uni["dense_host"]["kv_layout"] == "dense"
-    assert uni["paged_device"]["kv_layout"] == "paged"
-    # same workload on both sides
-    assert uni["dense_host"]["tokens"] == uni["paged_device"]["tokens"]
-    # the gate: paged + device sampling >= dense + host sampling
-    assert (
-        uni["paged_device"]["tokens_per_s"]
-        >= uni["dense_host"]["tokens_per_s"]
-    ), result
-    assert uni["speedup"] >= 1.0
 
-    mixed = result["mixed"]
-    assert mixed["pool_below_dense_equiv"] is True
-    assert mixed["paged_device"]["requests"] == 16
-    for block in ("ttft_s", "tpot_s"):
-        stats = mixed["paged_device"][block]
-        assert stats["count"] > 0
-        assert stats["p50"] <= stats["p95"] <= stats["p99"]
+@pytest.mark.parametrize("flag,old,only", [
+    ("--kv-layout", "dense", "paged"), ("--sampling", "host", "device"),
+])
+def test_clis_refuse_the_removed_value_before_anything_loads(
+        flag, old, only, capsys):
+    from pytorch_distributed_training_tpu.cli import fleet_lm, serve_lm
+
+    with pytest.raises(SystemExit) as e:
+        serve_lm.main(["--model", "gpt2-tiny", flag, old])
+    assert e.value.code == 2
+    assert f"argument {flag}: invalid choice: '{old}'" in capsys.readouterr().err
+    dest = flag[2:].replace("-", "_")
+    assert getattr(serve_lm.build_parser().parse_args([flag, only]), dest) == only
+    # the fleet front end forwards neither flag: it has none to forward
+    with pytest.raises(SystemExit) as e:
+        fleet_lm.build_parser().parse_args([flag, only])
+    assert e.value.code == 2
+    assert "unrecognized arguments" in capsys.readouterr().err

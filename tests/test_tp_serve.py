@@ -6,14 +6,9 @@ head-divisibility rejection, paged-pool sharding arithmetic (page axis
 whole, head axis split, allocator unchanged), sharded hot-swap with zero
 retraces under strict guards, the per-layer all-reduce comm manifest on
 the hot program, and the deviation path when weights are deliberately
-replicated. Runs on the suite's 8 virtual CPU devices; tier-1 except the
-perf-marked BENCH_tp gate.
+replicated. Runs on the suite's 8 virtual CPU devices; tier-1.
 """
 
-import json
-import os
-import subprocess
-import sys
 import time
 
 import jax
@@ -39,7 +34,6 @@ from pytorch_distributed_training_tpu.utils.config import model_preset
 
 pytestmark = [pytest.mark.serve, pytest.mark.tp]
 
-REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 # gpt2-tiny: 2 layers, hidden 64, 4 heads (tp=2 -> 2 heads per shard)
 LAYERS, HIDDEN, HEADS = 2, 64, 4
@@ -209,7 +203,7 @@ def test_tp_head_divisibility_rejected(lm):
 
 
 def test_tp_requires_paged_device_sampling():
-    with pytest.raises(ValueError, match="kv_layout"):
+    with pytest.raises(ValueError, match="--kv-layout dense: .*PR 31"):
         EngineConfig(
             num_slots=2, prompt_buckets=(8,), max_new_tokens=4,
             kv_layout="dense", sampling="host", tp=2,
@@ -375,45 +369,3 @@ def test_tp_manifest_moved_bytes_ceiling():
     }
     deviations = manifest.check(big)
     assert any("moved-bytes ceiling" in d for d in deviations), deviations
-
-
-# ------------------------------------------------------------ perf gate
-
-
-@pytest.mark.perf
-def test_tp_bench_gate(tmp_path):
-    """bench.py --tp: tp=2 must emit BIT-IDENTICAL token streams to tp=1
-    (with and without speculation), sustain throughput, and its hot
-    programs' compile-time comm audits must conform to serve_tp_manifest
-    with the exact per-tick collective footprint — the PR's acceptance
-    gate."""
-    out = tmp_path / "BENCH_tp.json"
-    proc = subprocess.run(
-        [
-            sys.executable, os.path.join(REPO_ROOT, "bench.py"),
-            "--tp", "--tp-out", str(out),
-        ],
-        capture_output=True, text=True, timeout=1200, cwd=REPO_ROOT,
-    )
-    assert proc.returncode == 0, proc.stderr[-3000:]
-    result = json.loads(out.read_text())
-
-    assert result["streams_identical"] is True, result["stream_digests"]
-    assert result["comm_audit_ok"] is True
-    slots = 4
-    for name, q in (("tp2", 1), ("tp2_spec", 7 + 1)):
-        v = result[name]
-        assert v["tp"] == 2 and v["tokens_per_s"] > 0
-        assert v["page_exhausted"] == 0
-        audits = {a["name"]: a for a in v["comm_audits"]}
-        hot = "serve_verify" if q > 1 else "serve_decode"
-        a = audits[hot]
-        assert a["ok"] is True and a["deviations"] == []
-        ar = a["by_kind"]["all-reduce"]
-        assert ar["count"] == 2 * LAYERS
-        # per-tick payload: 2 ARs/layer x [slots, q, hidden] f32
-        assert a["total_bytes"] == 2 * LAYERS * (slots * q * HIDDEN * 4)
-        assert "all-gather" not in a["by_kind"]
-    for name in ("tp1", "tp1_spec"):
-        assert result[name]["tp"] == 1
-        assert result[name]["tokens_per_s"] > 0

@@ -414,11 +414,11 @@ def test_page_walk_matches_reference_at_the_decode_cell_on_chip(pool_dtype):
     assert dispatch.DISPATCH_PATHS["paged_attn:direct"] == 1
 
 
-def test_decode_step_time_follows_the_fill_on_chip():
+def test_decode_step_time_follows_the_fill_on_chip(monkeypatch):
     """The decode program of `gpt2_medium_decode` (gpt2-medium, 48 slots,
     3,073 bf16 pages of 16, device sampling), timed at four fills with the
-    page walk (the default on one chip) and with the XLA formula pinned:
-    the kernel's step grows with what is live and stays under the
+    page walk (the gate's choice on one chip) and with the XLA formula
+    pinned: the kernel's step grows with what is live and stays under the
     formula's, which costs the same whatever the fill. The times go to
     ``chiprun_out/paged_decode_step_fills.json`` for PERF.md."""
     import json
@@ -426,6 +426,8 @@ def test_decode_step_time_follows_the_fill_on_chip():
     import time
 
     from pytorch_distributed_training_tpu.models.gpt2 import GPT2LMModel
+    from pytorch_distributed_training_tpu.ops import dispatch
+    from pytorch_distributed_training_tpu.ops import paged_attention as pa
     from pytorch_distributed_training_tpu.serve.engine import (
         DecodeEngine,
         EngineConfig,
@@ -440,11 +442,18 @@ def test_decode_step_time_follows_the_fill_on_chip():
     fills = {"4x200": (4, 200), "24x200": (24, 200), "48x200": (48, 200),
              "48x1024": (48, 1024)}
     step_ms = {}
+
+    class FormulaGate:
+        """``dispatch`` as the paged-attention gate reads it while the
+        formula engine traces: mode "off" there (its XLA formula), every
+        other op's gate as it is."""
+        mode = staticmethod(lambda: "off")
+        note_path = staticmethod(dispatch.note_path)
+
     for impl in ("auto", "reference"):
         econf = EngineConfig(
             num_slots=48, prompt_buckets=(64, 128, 256, 512),
-            max_new_tokens=512, page_size=16, kv_layout="paged",
-            sampling="device", warmup=False, paged_attention_impl=impl,
+            max_new_tokens=512, page_size=16, warmup=False,
         )
         queue = RequestQueue(
             max_depth=16, prompt_buckets=econf.prompt_buckets,
@@ -453,19 +462,26 @@ def test_decode_step_time_follows_the_fill_on_chip():
         engine = DecodeEngine(model, params, econf, queue)
         fn, pools = engine._decode_step_fn(), engine._cache
         zeros = np.zeros((48,), np.int32)
-        for name, (busy, tokens) in fills.items():
-            table, lengths = _cell_fill(busy, tokens)
-            # the program appends one token at ctx, then reads ctx + 1
-            ctx = (lengths - 1).astype(np.int32)
-            ops = (zeros, table, ctx, zeros, zeros,
-                   np.zeros((48,), np.float32), zeros)
-            times = []
-            for _ in range(12):
-                t0 = time.perf_counter()
-                ids, pools = fn(engine._params, pools, *ops)
-                jax.block_until_ready(ids)
-                times.append((time.perf_counter() - t0) * 1e3)
-            step_ms[f"{impl}:{name}"] = float(np.median(times[2:]))
+        dispatch.DISPATCH_PATHS.clear()
+        with monkeypatch.context() as patch:
+            if impl == "reference":
+                patch.setattr(pa, "dispatch", FormulaGate)
+            # the first call traces (one program serves every fill)
+            for name, (busy, tokens) in fills.items():
+                table, lengths = _cell_fill(busy, tokens)
+                # the program appends one token at ctx, then reads ctx + 1
+                ctx = (lengths - 1).astype(np.int32)
+                ops = (zeros, table, ctx, zeros, zeros,
+                       np.zeros((48,), np.float32), zeros)
+                times = []
+                for _ in range(12):
+                    t0 = time.perf_counter()
+                    ids, pools = fn(engine._params, pools, *ops)
+                    jax.block_until_ready(ids)
+                    times.append((time.perf_counter() - t0) * 1e3)
+                step_ms[f"{impl}:{name}"] = float(np.median(times[2:]))
+        took = "xla" if impl == "reference" else "direct"
+        assert dict(dispatch.DISPATCH_PATHS).get(f"paged_attn:{took}") == 24
         del engine, fn, pools
     print("paged_decode_step_fills", json.dumps(step_ms))
     os.makedirs("chiprun_out", exist_ok=True)
