@@ -223,6 +223,32 @@ def scope_instructions(hlo_text: str, scopes) -> dict:
     return out
 
 
+# `%gather.3 = bf16[48,1,2048,2560]{...} gather(%pool, %rows), ...,
+# slice_sizes={1,2560}, metadata={op_name=".../sparse_attn.gather/gather"}`
+_GATHER_RE = re.compile(
+    r"^\s*(?:ROOT\s+)?%?[\w.-]+\s*=\s*\S+\s+gather\(.*"
+    r"slice_sizes=\{(?P<sizes>[0-9,]*)\}.*"
+    r"metadata=\{[^}]*op_name=\"(?P<op>[^\"]*)\"")
+
+
+def count_row_gathers(hlo_text: str, scope: str, row_width: int) -> int:
+    """How many ``gather`` instructions of a compiled program, fused or
+    not, run under ``jax.named_scope(scope)`` and fetch slices whose last
+    extent is a whole multiple of ``row_width`` values: the gathers of
+    cached rows, one or several layers' side by side, and not the scalar
+    look-ups beside them. Part of what XLA:TPU's gather costs is per
+    slice fetched, so this is how many times the program walks its chosen
+    rows."""
+    count = 0
+    for line in hlo_text.splitlines():
+        m = _GATHER_RE.match(line)
+        if not m or scope not in m.group("op").split("/"):
+            continue
+        last = int(m.group("sizes").rsplit(",", 1)[-1] or 0)
+        count += bool(last) and last % row_width == 0
+    return count
+
+
 def count_relayouts(hlo_text: str, element_counts) -> int:
     """How many ``copy``/``transpose``/``convert`` instructions of a
     compiled program produce a buffer of one of ``element_counts``

@@ -28,6 +28,7 @@ from pytorch_distributed_training_tpu.analysis.spmd.hlo import (
     COLLECTIVE_KINDS,
     CostModel,
     count_relayouts,
+    count_row_gathers,
     count_space_moves,
     extract_collectives,
     scope_instructions,
@@ -64,6 +65,10 @@ class CommManifest:
     # each, so that a device trace (events named by instruction, no
     # metadata) can be read scope by scope
     trace_scopes: tuple = ()
+    # values of one cached latent row (0: the program has none): the audit
+    # counts the program's gathers of such rows under ``sparse_attn.gather``
+    # (``latent_row_gathers``: one a selection group in a decode step)
+    latent_row: int = 0
 
     def __post_init__(self):
         for kind in tuple(self.allowed) + tuple(self.required):
@@ -287,6 +292,9 @@ def comm_audit(
         record["kv_pool_space_moves"] = count_space_moves(
             text, manifest.kv_pool_elements
         )
+    if manifest.latent_row:
+        record["latent_row_gathers"] = count_row_gathers(
+            text, "sparse_attn.gather", manifest.latent_row)
     registry.emit(record)
     if manifest.trace_scopes:
         registry.emit({

@@ -22,14 +22,20 @@ The block the GPT-2 family lacks, by mechanism:
   experts' part plus the shared expert.
 
 Serving contract (``serve/engine.py``, ``serve/paged_cache.py``): the same
-flax "cache" collection pattern as ``models/bert.py::_paged_attend``. Every
-attention layer keeps a ``latent_pages`` pool ``[pages, page_size, 640]``
-(lane-dense row ``[c_kv 512 | k_rope 64 | 0]``), the ``full`` layers an
-``index_pages`` pool ``[pages, page_size, 128]`` beside it; one block table
-addresses both, so a prefix-cache hit maps them together and copy-on-write
-copies both. Prefill (fresh sequence), decode (one token a sequence) and
-the multi-token-query view (prefill chunks at a nonzero context) all write
-first and read after.
+flax "cache" collection pattern as ``models/bert.py::_paged_attend``. A
+selection GROUP (a ``full`` layer and the ``shared`` layers after it up to
+the next ``full`` one: ``LatentMoEConfig.selection_groups``) keeps ONE
+``latent_pages`` pool ``[pages, page_size, G * 640]`` (``LatentPool``, the
+cache node ``latents_<g>``): layer ``j`` of the group owns columns ``[j *
+640, (j + 1) * 640)``, a lane-dense row ``[c_kv 512 | k_rope 64 | 0]``.
+The ``full`` layers keep an ``index_pages`` pool ``[pages, page_size,
+128]`` of their own; one block table addresses all, so a prefix-cache hit
+maps them together and copy-on-write copies all. Every step writes first
+and reads after. The decode step (one token a sequence, seen from the
+input's shape) gathers a group's chosen rows ONCE, in its choosing layer,
+and hands them on with the ``Selection``; a bucket prefill and a prefill
+chunk gather a layer's own columns layer by layer, because their tokens
+attend to rows their own chunk writes.
 """
 
 from __future__ import annotations
@@ -122,6 +128,19 @@ class LatentMoEConfig:
     @property
     def num_layers(self) -> int:
         return len(self.mlp_layer_types)
+
+    @property
+    def selection_groups(self) -> tuple:
+        """(group, place in it) of every layer, and the groups' sizes: a
+        group is a ``full`` layer and the ``shared`` ones that reuse its
+        selection."""
+        places, sizes = [], []
+        for kind in self.indexer_types:
+            if kind == "full":
+                sizes.append(0)
+            places.append((len(sizes) - 1, sizes[-1]))
+            sizes[-1] += 1
+        return tuple(places), tuple(sizes)
 
     @property
     def latent_row(self) -> int:
@@ -257,16 +276,46 @@ class ExpertLayer(nn.Module):
             return (routed + shared).reshape(*lead, h)
 
 
+class LatentPool(nn.Module):
+    """One selection group's latent rows: the cache variables
+    ``latent_pages`` [pages, page_size, layers * latent_row], the group's
+    layers side by side in a token's row, and the block table every pool
+    of the group is read through. Made by the model, which hands the two
+    variables to the group's layers."""
+
+    config: LatentMoEConfig
+    layers: int
+
+    @nn.compact
+    def __call__(self):
+        cfg = self.config
+        if cfg.kv_num_pages < 2:
+            raise ValueError(
+                "paged serving needs kv_num_pages >= 2 (page 0 is the "
+                f"reserved null page), got {cfg.kv_num_pages}")
+        shape = (cfg.kv_num_pages, cfg.kv_page_size,
+                 self.layers * cfg.latent_row)
+        pages = self.variable(
+            "cache", "latent_pages", lambda: jnp.zeros(shape, _cdt(cfg)))
+        # a placeholder: the engine supplies it per call (with_tables)
+        block_table = self.variable(
+            "cache", "block_table", lambda: jnp.zeros((1, 1), jnp.int32))
+        return pages, block_table
+
+
 class LatentAttention(nn.Module):
     """MLA with an optional indexer (``indexer=True``: a layer typed
-    ``full``). Returns (output, selection): the selection is this layer's
-    own where it has an indexer, the one it was handed otherwise."""
+    ``full``) at ``place`` in its selection group, whose ``pool`` (the
+    variables of a ``LatentPool``) it is handed when serving. Returns
+    (output, selection): the selection is this layer's own where it has an
+    indexer, the one it was handed otherwise."""
 
     config: LatentMoEConfig
     indexer: bool
+    place: int = 0
 
     @nn.compact
-    def __call__(self, x, positions, selection):
+    def __call__(self, x, positions, selection, pool=None):
         cfg = self.config
         dt = _cdt(cfg)
         h, heads = cfg.hidden_size, cfg.num_attention_heads
@@ -314,13 +363,12 @@ class LatentAttention(nn.Module):
 
         serving = cfg.decode and not self.is_initializing()
         if serving:
-            pools = self._write(ckv, k_rope, ki, positions)
-        if serving:
             # the decode step, a prefill chunk and a bucket prefill alike
             # (a bucket is one chunk at context 0)
+            row, index = self._write(pool, ckv, k_rope, ki, positions)
             ctx, selection = self._paged(
-                pools, q_nope, q_rope, qi, wi, positions, selection,
-                kv_b_k, kv_b_v, scale)
+                pool, index, row, q_nope, q_rope, qi, wi, positions,
+                selection, kv_b_k, kv_b_v, scale)
         else:
             # no cache (training, evaluation, the tests' comparisons, and
             # declaring the cache's shapes): the sequence's own latents are
@@ -328,8 +376,8 @@ class LatentAttention(nn.Module):
             ctx, selection = self._fresh(
                 q_nope, q_rope, ckv, k_rope, qi, ki, wi, positions,
                 selection, kv_b_k, kv_b_v, scale)
-            if cfg.decode and not serving:
-                self._pools()   # initializing: declare the cache's shapes
+            if cfg.decode:
+                self._index_pool()   # initializing: declare the cache's shapes
         out = jnp.einsum("bqhv,hvd->bqd", ctx.astype(dt), o_w.astype(dt),
                          preferred_element_type=jnp.float32)
         if self.is_mutable_collection("selection"):
@@ -362,55 +410,49 @@ class LatentAttention(nn.Module):
 
     # ---------------------------------------------------------- paged path
 
-    def _pools(self):
+    def _index_pool(self):
+        """The indexer keys' pool of a ``full`` layer (None in a ``shared``
+        one), under the group's block table."""
         cfg = self.config
-        if cfg.kv_num_pages < 2:
-            raise ValueError(
-                "paged serving needs kv_num_pages >= 2 (page 0 is the "
-                f"reserved null page), got {cfg.kv_num_pages}")
-        dt = _cdt(cfg)
-        shape = (cfg.kv_num_pages, cfg.kv_page_size)
-        latent = self.variable(
-            "cache", "latent_pages",
-            lambda: jnp.zeros(shape + (cfg.latent_row,), dt))
-        index = None
-        if self.indexer:
-            index = self.variable(
-                "cache", "index_pages",
-                lambda: jnp.zeros(shape + (cfg.index_head_dim,), dt))
-        # placeholders: the engine supplies both per call (with_tables)
-        bt = self.variable(
-            "cache", "block_table", lambda: jnp.zeros((1, 1), jnp.int32))
-        cl = self.variable(
-            "cache", "context_len", lambda: jnp.zeros((1,), jnp.int32))
-        return latent, index, bt, cl
+        if not self.indexer:
+            return None
+        return self.variable(
+            "cache", "index_pages",
+            lambda: jnp.zeros((cfg.kv_num_pages, cfg.kv_page_size,
+                               cfg.index_head_dim), _cdt(cfg)))
 
-    def _write(self, ckv, k_rope, ki, positions):
+    def _write(self, pool, ckv, k_rope, ki, positions):
         """This step's rows into the pools, through the block table: the
-        latent row ``[c_kv | k_rope | 0]`` and, with an indexer, its key."""
+        latent row ``[c_kv | k_rope | 0]`` into this layer's columns of
+        the group's pool and, with an indexer, its key. Returns the rows
+        and the indexer pool."""
         cfg = self.config
         dt = ckv.dtype
-        latent, index, bt, cl = self._pools()
+        index = self._index_pool()
         batch, chunk = positions.shape
         pad = cfg.latent_row - cfg.kv_lora_rank - cfg.qk_rope_head_dim
         rows = jnp.concatenate(
             [ckv, k_rope, jnp.zeros((batch, chunk, pad), dt)], axis=-1)
-        latent.value = la.write_rows(latent.value, bt.value, positions, rows)
+        latent, bt = pool
+        latent.value = la.write_rows(
+            latent.value, bt.value, positions, rows,
+            self.place * cfg.latent_row)
         if self.indexer:
             index.value = la.write_rows(index.value, bt.value, positions, ki)
-        cl.value = cl.value + chunk
-        return latent, index, bt
+        return rows, index
 
-    def _paged(self, pools, q_nope, q_rope, qi, wi, positions, selection,
-               kv_b_k, kv_b_v, scale):
+    def _paged(self, pool, index, row, q_nope, q_rope, qi, wi, positions,
+               selection, kv_b_k, kv_b_v, scale):
         """Select, gather and attend in the latent through the block
-        table, after the step's own rows are written: ABSORB. One code
-        path for the decode step (one token a sequence), a prefill chunk
-        at a nonzero context and a bucket prefill at context 0."""
+        table, after the step's own rows are written: ABSORB. The decode
+        step (one token a sequence) gathers the group's rows once, in the
+        choosing layer; a prefill chunk at a nonzero context and a bucket
+        prefill at context 0 gather this layer's columns."""
         cfg = self.config
         dt = q_nope.dtype
-        latent, index, bt = pools
+        latent, bt = pool
         batch, chunk = positions.shape
+        column = self.place * cfg.latent_row
         pad = cfg.latent_row - cfg.kv_lora_rank - cfg.qk_rope_head_dim
         # the absorbed query, laid out as a pool row
         q_lat = jnp.einsum("bqhd,chd->bqhc", q_nope, kv_b_k.astype(dt),
@@ -425,12 +467,34 @@ class LatentAttention(nn.Module):
                 "bqhc,chv->bqhv", ctx[..., :cfg.kv_lora_rank].astype(dt),
                 kv_b_v.astype(dt), preferred_element_type=jnp.float32)
 
+        def select(qi, wi, positions):
+            return la.look_up_rows(la.select_topk(
+                la.index_scores(qi, wi, index.value, bt.value, positions),
+                cfg.index_topk, positions), bt.value, cfg.kv_page_size)
+
+        if chunk == 1:
+            # every later layer of the group finds its rows in the choosing
+            # layer's one gather, all but the row it has just written
+            if self.indexer:
+                selection = select(qi, wi, positions)
+                selection = selection._replace(
+                    group_rows=la.gather_rows(latent.value, selection))
+                rows = la.group_slice(selection, column, cfg.latent_row)
+            else:
+                rows = la.group_slice(
+                    selection, column, cfg.latent_row, row, positions)
+            ctx = la.latent_attention(q_row, rows, selection.valid, scale)
+            return values(ctx), selection
+
+        # a multi-token step: its tokens attend to rows of their own chunk,
+        # which the group's later layers have yet to write, so each layer
+        # gathers for itself, out of its own columns
+        own = la.layer_pool(latent.value, column, cfg.latent_row)
+
         def block(q_row, qi, wi, positions, selection):
             if self.indexer:
-                selection = la.look_up_rows(la.select_topk(
-                    la.index_scores(qi, wi, index.value, bt.value, positions),
-                    cfg.index_topk, positions), bt.value, cfg.kv_page_size)
-            rows = la.gather_rows(latent.value, selection)
+                selection = select(qi, wi, positions)
+            rows = la.gather_rows(own, selection)
             ctx = la.latent_attention(q_row, rows, selection.valid, scale)
             return values(ctx), selection
 
@@ -465,14 +529,16 @@ class DecoderLayer(nn.Module):
     config: LatentMoEConfig
     sparse: bool
     indexer: bool
+    place: int = 0
 
     @nn.compact
-    def __call__(self, x, positions, selection, token_mask):
+    def __call__(self, x, positions, selection, token_mask, pool=None):
         cfg = self.config
         pdt = _pdt(cfg)
         h = RMSNorm(cfg.rms_norm_eps, pdt, name="attention_norm")(x)
-        a, selection = LatentAttention(cfg, self.indexer, name="attention")(
-            h, positions, selection)
+        a, selection = LatentAttention(
+            cfg, self.indexer, self.place, name="attention")(
+            h, positions, selection, pool)
         x = x + a
         h = RMSNorm(cfg.rms_norm_eps, pdt, name="mlp_norm")(x)
         if self.sparse:
@@ -518,11 +584,17 @@ class LatentMoELM(nn.Module):
                           (cfg.hidden_size, cfg.vocab_size), _pdt(cfg))
         x = embed[input_ids].astype(dt)
         selection: Optional[la.Selection] = None
+        places, sizes = cfg.selection_groups
+        pools = [LatentPool(cfg, size, name=f"latents_{g}")()
+                 for g, size in enumerate(sizes)] if cfg.decode else None
         for i, (mlp, idx) in enumerate(
                 zip(cfg.mlp_layer_types, cfg.indexer_types)):
+            group, place = places[i]
             x, selection = DecoderLayer(
-                cfg, mlp == "sparse", idx == "full", name=f"layer_{i}")(
-                x, position_ids, selection, token_mask)
+                cfg, mlp == "sparse", idx == "full", place,
+                name=f"layer_{i}")(
+                x, position_ids, selection, token_mask,
+                pools[group] if pools else None)
         x = RMSNorm(cfg.rms_norm_eps, _pdt(cfg), name="final_norm")(x)
         return _mm(x, head)
 

@@ -15,7 +15,8 @@ apart:
   equal scores the lower position first (``select_topk``: exact, without
   sorting the context);
 - ``sparse_attn.gather``: those positions' latent rows, fetched through the
-  block table token by token;
+  block table token by token: ONE gather a selection group in the decode
+  step (``Selection.group_rows``), one a layer in a multi-token step;
 - ``sparse_attn.attend``: attention over the gathered rows only, in the
   latent (the key up-projection absorbed into the query, the value
   up-projection applied to the output).
@@ -35,8 +36,15 @@ Pool layout: rows are lane-dense. The latent row is ``[c_kv | k_rope |
 0...]`` padded up to a whole number of 128-lane tiles (576 -> 640), the
 indexer key is exactly one tile: a pool's default TPU layout is then the
 row-major one the write and the gathers run in, and no program re-lays a
-pool out (PERF.md, PR 26). ``kv_pool_relayout_ops`` reads 1 for the decode
-program on the chip all the same: XLA:TPU fetches the last full layer's
+pool out (PERF.md, PR 26). The layers that share a ``Selection`` (a
+choosing layer and those after it up to the next: a GROUP of ``G``) keep
+their rows SIDE BY SIDE in one pool ``[pages, page_size, G * 640]``, layer
+``j`` of the group in columns ``[j * 640, (j + 1) * 640)``: of the 1.53 ms
+XLA:TPU's gather takes for 98,304 rows of 640, 0.65 is per row fetched
+whatever its width (PERF.md, PR 32), so the decode step fetches each
+chosen token's ``G`` rows as one wide row where ``G`` gathers walked the
+same row ids. ``kv_pool_relayout_ops`` reads 1 for the decode program on
+the chip all the same: XLA:TPU fetches the last full layer's
 48 MB indexer pool into on-chip memory ahead of its scores and writes it
 back, in the one layout (``kv_pool_space_moves`` 1 says that it is that).
 """
@@ -59,11 +67,15 @@ class Selection(NamedTuple):
     positions exist yet) and, on the paged path, ``rows`` [batch, q, k]
     int32: where those tokens live in a pool viewed as [pages * page_size,
     width], looked up through the block table ONCE by the choosing layer
-    and handed on with the positions."""
+    and handed on with the positions. In the decode step it also carries
+    ``group_rows`` [batch, 1, k, G * width]: the chosen tokens' rows of
+    every layer of the group, side by side as the group's pool holds them,
+    gathered once by the choosing layer (``gather_rows``)."""
 
     positions: jax.Array
     valid: jax.Array
     rows: jax.Array | None = None
+    group_rows: jax.Array | None = None
 
 
 def lane_pad(width: int) -> int:
@@ -97,11 +109,23 @@ def page_slots(block_table, positions, page_size: int):
     return pages, positions % page_size
 
 
-def write_rows(pool, block_table, positions, rows):
+def write_rows(pool, block_table, positions, rows, column: int = 0):
     """Scatter ``rows`` [batch, n, width] at token ``positions`` [batch, n]
-    into ``pool`` [pages, page_size, width]."""
+    into columns ``[column, column + width)`` of ``pool`` [pages,
+    page_size, >= column + width].
+
+    Where the pool is wider than the rows (a group's pool: other layers'
+    columns beside these), the tokens' whole rows are read, these columns
+    replaced and the whole rows written back: ``batch * n`` more rows to
+    fetch, and the scatter stays the row scatter XLA:TPU runs as one
+    operation (a scatter at a column offset it unrolls into a loop of one
+    update a row: PERF.md, PR 32)."""
     pages, offs = page_slots(block_table, positions, pool.shape[1])
-    return pool.at[pages, offs].set(rows.astype(pool.dtype))
+    rows = rows.astype(pool.dtype)
+    if rows.shape[-1] != pool.shape[-1]:
+        rows = pool[pages, offs].at[
+            ..., column:column + rows.shape[-1]].set(rows)
+    return pool.at[pages, offs].set(rows)
 
 
 def index_scores(qi, weights, index_pages, block_table, q_positions):
@@ -215,12 +239,41 @@ def look_up_rows(sel: Selection, block_table, page_size: int) -> Selection:
             rows=(pages * page_size + offs).reshape(batch, q, k))
 
 
+def layer_pool(latent_pages, column: int, width: int):
+    """Columns ``[column, column + width)`` of a group's pool as a pool of
+    one layer [pages, page_size, width]: a copy where the group has more
+    layers than one, made once a layer by a multi-token step, whose many
+    gathers then fetch narrow rows (XLA:TPU unrolls a gather at a column
+    offset into a loop of one slice a row: PERF.md, PR 32)."""
+    return latent_pages[:, :, column:column + width]
+
+
 def gather_rows(latent_pages, sel: Selection):
-    """The selected tokens' latent rows [batch, q, k, width], token by
-    token from the pool (``sel.rows``: ``look_up_rows``)."""
+    """The selected tokens' rows [batch, q, k, width], token by token from
+    the pool (``sel.rows``: ``look_up_rows``). Handed a group's pool whole
+    (the decode step, once a group: ``Selection.group_rows``) the rows are
+    those of every layer of the group, side by side; a layer reads its own
+    columns with ``group_slice``."""
     pages, page_size, width = latent_pages.shape
     with jax.named_scope("sparse_attn.gather"):
         return latent_pages.reshape(pages * page_size, width)[sel.rows]
+
+
+def group_slice(sel: Selection, column: int, width: int, fresh=None,
+                positions=None):
+    """One layer's rows [batch, 1, k, width] out of ``sel.group_rows``,
+    columns ``[column, column + width)``. The group's gather ran before a
+    later layer of the group wrote its row of the CURRENT token
+    (``positions`` [batch, 1]): where that token is among the chosen, such
+    a layer's row is ``fresh`` [batch, 1, width], the row this step
+    writes, not the pool's."""
+    with jax.named_scope("sparse_attn.attend"):
+        rows = sel.group_rows[..., column:column + width]
+        if fresh is None:
+            return rows
+        current = sel.positions == positions[..., None]
+        return jnp.where(
+            current[..., None], fresh[:, :, None, :].astype(rows.dtype), rows)
 
 
 def latent_attention(q_latent, rows, valid, scale: float):

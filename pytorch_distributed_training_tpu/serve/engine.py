@@ -853,10 +853,10 @@ class DecodeEngine:
                 math.prod(leaf.sharding.shard_shape(leaf.shape))
                 for leaf in jax.tree.leaves(self._cache)
             })),
-            # the model's own named scopes, for the audit's map from
-            # compiled instructions to scopes (``program_scopes`` record)
-            trace_scopes=tuple(
-                getattr(self._decode_model, "trace_scopes", ())),
+            # the model's own named scopes (``program_scopes`` record) and
+            # a latent model's row width (``latent_row_gathers``)
+            trace_scopes=getattr(self._decode_model, "trace_scopes", ()),
+            latent_row=getattr(self._decode_model.config, "latent_row", 0),
         )
 
     def _hot_program(self) -> str:
@@ -2680,6 +2680,10 @@ class DecodeEngine:
             # and back, in one layout (analysis/spmd/hlo.count_space_moves)
             "kv_pool_space_moves": self._hot_audit().get(
                 "kv_pool_space_moves"),
+            # gathers of cached latent rows in the hot program (None for a
+            # model without a latent pool): one a selection group
+            "latent_row_gathers": self._hot_audit().get(
+                "latent_row_gathers"),
             "queue_depth": self._queue.depth(),
             "queue_depth_by_tier": self._queue.depth_by_tier(),
             "slot_occupancy": self.slot_occupancy(),

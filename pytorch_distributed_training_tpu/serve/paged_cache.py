@@ -236,8 +236,8 @@ def with_tables(pools: Mapping[str, Any], block_table: Any,
     """Rebuild a full cache tree from engine-resident ``pools`` by injecting
     ``block_table``/``context_len`` beside every attention layer's page
     pools: the node that holds ``k_pages`` (per-head K/V pools) or
-    ``latent_pages`` (a latent pool, with or without an indexer pool
-    beside it: two kinds of pool under the one table). Used at TRACE level
+    ``latent_pages`` (a selection group's latent pool; the group's layers
+    read its table for their indexer pools too). Used at TRACE level
     inside the jitted programs."""
     def walk(node):
         if isinstance(node, Mapping):

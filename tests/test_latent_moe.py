@@ -15,6 +15,7 @@ import importlib
 import os
 import sys
 
+import flax.linen as nn
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -139,10 +140,18 @@ def test_tree_has_no_indexer_in_shared_layers(world):
     assert "index_q" in p["layer_0"]["attention"]
     assert "index_q" not in p["layer_1"]["attention"]
     _, pools = _paged(world)
-    assert set(pools["layer_0"]["attention"]) == {"latent_pages", "index_pages"}
-    assert set(pools["layer_1"]["attention"]) == {"latent_pages"}
-    # lane-dense rows: the latent row pads to whole 128-lane tiles
-    assert pools["layer_1"]["attention"]["latent_pages"].shape == (48, PAGE, 128)
+    # one latent pool a selection group (full, shared | full, shared), an
+    # indexer pool only in the layers that choose
+    assert set(pools) == {"latents_0", "latents_1", "layer_0", "layer_2"}
+    for g, full in enumerate(("layer_0", "layer_2")):
+        assert set(pools[f"latents_{g}"]) == {"latent_pages"}
+        assert set(pools[full]["attention"]) == {"index_pages"}
+        # lane-dense rows: the latent row pads to whole 128-lane tiles,
+        # the group's two layers side by side
+        assert pools[f"latents_{g}"]["latent_pages"].shape == (
+            48, PAGE, 2 * 128)
+    assert world["cfg"].selection_groups == (
+        ((0, 0), (0, 1), (1, 0), (1, 1)), (2, 2))
 
 
 def test_prefill_then_decode_through_pools_matches_reference(world):
@@ -159,6 +168,163 @@ def test_prefill_then_decode_through_pools_matches_reference(world):
             model, world["params"], pools, tokens, [0, t], bt,
             token_mask=jnp.array([[False], [True]]))
         assert float(jnp.abs(logits[1, 0] - want[t]).max()) < TOL
+
+
+@pytest.fixture(scope="module")
+def served(world):
+    """One paged model and its empty pools for the decode-step tests below
+    (one model: `_step` compiles once a model and shape)."""
+    return _paged(world)
+
+
+def _chosen(vars_, layer):
+    """The valid chosen positions of `layer`'s one query."""
+    sel = vars_["selection"][f"layer_{layer}"]["attention"]
+    pos, valid = np.asarray(sel["positions"][0]), np.asarray(sel["valid"][0])
+    return set(pos[0, 0][valid[0, 0]].tolist())
+
+
+@pytest.mark.parametrize(
+    "case", ["context_below_topk", "current_chosen", "current_not_chosen"])
+def test_decode_through_the_group_pool_matches_reference(world, served, case):
+    """The decode step reads a group's rows out of ONE gather that ran
+    before the group's later layers wrote the current token's row: the
+    reference's logits where that token is among the chosen (always, while
+    the context is under index_topk; by the indexer's choice past it) and
+    where it is not."""
+    model, pools = served
+    bt, ids, want = _table(), world["ids"], world["ref_logits"]
+    topk = MODEL["index_topk"]
+    _, pools, _ = _step(model, world["params"], pools, ids[:, :4], [0], bt[1:2])
+    met = False
+    for t in range(4, SEQ):
+        logits, pools, vars_ = _step(
+            model, world["params"], pools, ids[:, t:t + 1], [t], bt[1:2])
+        assert float(jnp.abs(logits[0, 0] - want[t]).max()) < TOL, t
+        inside = [t in _chosen(vars_, layer) for layer in range(4)]
+        assert inside[1] == inside[0] and inside[3] == inside[2]
+        if t < topk:
+            assert all(inside)      # every seen position is chosen
+            met = met or case == "context_below_topk"
+        elif case == "current_chosen":
+            met = met or all(inside)
+        elif case == "current_not_chosen":
+            met = met or not any(inside)
+        if met and t >= topk:
+            break
+    assert met, case
+
+
+def test_stale_rows_of_the_current_token_never_reach_the_logits(world, served):
+    """The pin of the substitution: NaN in the shared layers' columns at
+    the row the step is about to write. The group's gather fetches that row
+    before those layers write it (the token is among the chosen: context
+    under index_topk), and the logits equal the clean step's bit for bit:
+    the fresh row, not the pool, supplies it. The step then writes it."""
+    model, pools = served
+    bt, ids, t = _table(), world["ids"], 5
+    _, pools, _ = _step(model, world["params"], pools, ids[:, :t], [0], bt[1:2])
+    page, off = bt[1, t // PAGE], t % PAGE
+    width = world["cfg"].latent_row
+    dirty = dict(pools)
+    for g in (0, 1):
+        leaf = pools[f"latents_{g}"]["latent_pages"]
+        dirty[f"latents_{g}"] = {
+            "latent_pages": leaf.at[page, off, width:].set(jnp.nan)}
+    clean, clean_pools, vars_ = _step(
+        model, world["params"], pools, ids[:, t:t + 1], [t], bt[1:2])
+    assert all(t in _chosen(vars_, layer) for layer in range(4))
+    got, got_pools, _ = _step(
+        model, world["params"], dirty, ids[:, t:t + 1], [t], bt[1:2])
+    assert np.array_equal(np.asarray(got), np.asarray(clean))
+    assert not np.isnan(np.asarray(got)).any()
+    for a, b in zip(jax.tree.leaves(got_pools), jax.tree.leaves(clean_pools)):
+        assert np.array_equal(np.asarray(a), np.asarray(b))
+
+
+def test_group_pool_columns_hold_each_layers_rows(world, served):
+    """The write path: after a prefill of 6 and 14 decode steps, layer j of
+    a group finds in columns [j * 128, (j + 1) * 128) of the group's pool,
+    at its tokens' page slots, the row a pool of its own held before:
+    `[c_kv | k_rope | 0]`, made here from the plain forward's normed
+    latents and rotated keys. Nothing else in the pool is touched."""
+    from pytorch_distributed_training_tpu.ops import latent_attention as la
+
+    model, pools = served
+    cfg, params, ids, bt = world["cfg"], world["params"], world["ids"], _table()
+    n, width = 20, world["cfg"].latent_row
+    _, pools, _ = _step(model, params, pools, ids[:, :6], [0], bt[1:2])
+    for t in range(6, n):
+        _, pools, _ = _step(model, params, pools, ids[:, t:t + 1], [t], bt[1:2])
+    _, kept = jax.jit(lambda p, i: world["model"].apply(
+        {"params": p}, i, capture_intermediates=True))(params, ids[:, :n])
+    kept = kept["intermediates"]
+    cos, sin = la.rope_angles(
+        jnp.arange(n)[None], cfg.qk_rope_head_dim, cfg.rope_theta)
+    places, _ = cfg.selection_groups
+    pages, offs = bt[1, np.arange(n) // PAGE], np.arange(n) % PAGE
+    for layer, (group, place) in enumerate(places):
+        at = kept[f"layer_{layer}"]
+        ckv = at["attention"]["kv_a_norm"]["__call__"][0]
+        x = at["attention_norm"]["__call__"][0]
+        k_rope = la.apply_rope(
+            x @ params[f"layer_{layer}"]["attention"]["kv_a_rope"], cos, sin)
+        want = jnp.concatenate(
+            [ckv, k_rope, jnp.zeros((1, n, width - 40))], -1)[0]
+        pool = np.asarray(pools[f"latents_{group}"]["latent_pages"])
+        got = pool[pages, offs, place * width:(place + 1) * width]
+        assert float(np.abs(got - np.asarray(want)).max()) < TOL, layer
+        assert float(np.abs(got).max()) > 0.1
+    for g in (0, 1):
+        pool = np.array(pools[f"latents_{g}"]["latent_pages"])
+        pool[pages, offs] = 0.0
+        assert not pool.any()
+
+
+@pytest.mark.parametrize("indexer_types,groups", [
+    (("full", "shared", "full", "shared"), 2),
+    (("full", "shared", "shared", "shared"), 1),
+    (("full", "full", "full", "full"), 4),
+], ids=["two_groups", "one_group", "every_layer_chooses"])
+def test_decode_program_gathers_latent_rows_once_a_group(
+        world, indexer_types, groups):
+    """The counter that says the group gather engaged: the compiled decode
+    program holds one gather of latent rows a selection group, which is one
+    a layer (the program as it was) when every layer chooses. The chunk
+    program keeps one a layer."""
+    from pytorch_distributed_training_tpu.analysis.spmd.manifest import (
+        CommManifest, comm_audit)
+    from pytorch_distributed_training_tpu.telemetry.registry import (
+        MetricsRegistry)
+
+    model, pools = _paged(world, indexer_types=indexer_types)
+    assert len(model.config.selection_groups[1]) == groups
+    shapes = jax.eval_shape(lambda: model.init(
+        jax.random.key(0), jnp.ones((1, 1), jnp.int32),
+        position_ids=jnp.zeros((1, 1), jnp.int32)))["params"]
+
+    def program(chunk):
+        def step(params, pools, ids, bt, ctx):
+            cache = with_tables(pools, bt, ctx)
+            return model.apply(
+                {"params": params, "cache": cache}, ids,
+                position_ids=ctx[:, None] + jnp.arange(chunk)[None],
+                mutable=["cache"])
+        return jax.jit(step).lower(
+            shapes, pools, jnp.zeros((2, chunk), jnp.int32),
+            jnp.zeros((2, 12), jnp.int32), jnp.zeros((2,), jnp.int32)).compile()
+
+    manifest = CommManifest("decode", latent_row=model.config.latent_row)
+    record = comm_audit("decode", program(1), manifest,
+                        registry=MetricsRegistry(), mode="record")
+    assert record["latent_row_gathers"] == groups
+    record = comm_audit("chunk", program(3), manifest,
+                        registry=MetricsRegistry(), mode="record")
+    assert record["latent_row_gathers"] == 4
+    # a program without a latent pool is not asked
+    assert "latent_row_gathers" not in comm_audit(
+        "decode", program(1), CommManifest("decode"),
+        registry=MetricsRegistry(), mode="record")
 
 
 def test_chosen_positions_equal_the_reference_exactly(world):
@@ -278,15 +444,24 @@ def test_absorbed_and_expanded_attention_agree(world):
     dcfg = dataclasses.replace(
         cfg, decode=True, kv_num_pages=16, kv_page_size=PAGE,
         paged_multiquery=True)
-    layer = lm.LatentAttention(dcfg, True)
+
+    class Layer(nn.Module):
+        @nn.compact
+        def __call__(self, x, pos):
+            return lm.LatentAttention(dcfg, True, name="attention")(
+                x, pos, None, lm.LatentPool(dcfg, 1, name="latents")())
+
     cache = {
-        "latent_pages": jnp.zeros((16, PAGE, cfg.latent_row)),
-        "index_pages": jnp.zeros((16, PAGE, cfg.index_head_dim)),
-        "block_table": jnp.arange(1, 9, dtype=jnp.int32)[None],
-        "context_len": jnp.zeros((1,), jnp.int32),
+        "latents": {
+            "latent_pages": jnp.zeros((16, PAGE, cfg.latent_row)),
+            "block_table": jnp.arange(1, 9, dtype=jnp.int32)[None],
+            "context_len": jnp.zeros((1,), jnp.int32)},
+        "attention": {
+            "index_pages": jnp.zeros((16, PAGE, cfg.index_head_dim))},
     }
-    (absorbed, sel_a), _ = layer.apply(
-        {"params": p, "cache": cache}, x, pos, None, mutable=["cache"])
+    (absorbed, sel_a), _ = Layer().apply(
+        {"params": {"attention": p}, "cache": cache}, x, pos,
+        mutable=["cache"])
     assert float(jnp.abs(absorbed - expanded).max()) < 1e-5
     assert (jnp.sort(jnp.where(sel_a.valid, sel_a.positions, -1), -1)
             == jnp.sort(jnp.where(sel_e.valid, sel_e.positions, -1), -1)).all()

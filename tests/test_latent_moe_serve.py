@@ -123,6 +123,9 @@ def test_every_engine_variant_serves_the_models_own_greedy_tokens(world, engine)
         3 * (NEW - 1) * 3 * MODEL["num_experts_per_tok"])
     cfg = world["model"].config
     assert stats["kv_bytes_per_token"] == 4 * (4 * cfg.latent_row + 2 * 16)
+    # the warmed engine audits its decode program: one gather of latent
+    # rows a selection group (full, shared | full, shared), not one a layer
+    assert stats["latent_row_gathers"] == (2 if engine.get("warmup") else None)
     ticks = [r for r in records if r.get("record") == "serve_tick"
              and r.get("decode_active")]
     assert ticks and all(

@@ -495,6 +495,96 @@ def test_decode_step_time_follows_the_fill_on_chip(monkeypatch):
     ), step_ms
 
 
+def test_glm52_decode_step_and_row_gather_widths_on_chip():
+    """`glm52_agent_decode`'s decode program alone (the `glm-5.2-share16`
+    preset in bf16, 48 slots over 1,184-page block tables, 11,777 pages of
+    16, every slot at a context of 18,000 over seeded pools), timed beside
+    the 32.8 ms its module took before the latent pools were grouped
+    (PERF.md, PR 29-31), and what XLA:TPU's row gather costs at the three
+    row widths the grouping chose between: 98,304 random rows of 640, 1,280
+    and 2,560 bf16 out of 188,432. The times go to
+    ``chiprun_out/glm52_decode_step.json`` for PERF.md."""
+    import json
+    import os
+    import time
+
+    from pytorch_distributed_training_tpu.models import latent_moe as lm
+    from pytorch_distributed_training_tpu.serve.engine import (
+        DecodeEngine,
+        EngineConfig,
+    )
+    from pytorch_distributed_training_tpu.serve.queue import RequestQueue
+    from pytorch_distributed_training_tpu.utils.config import model_preset
+
+    out = {"parent_module_ms": 32.8}
+    rows, chosen = 11777 * 16, 48 * 2048
+    ids = jax.random.randint(jax.random.key(1), (48, 1, 2048), 0, rows)
+    gather = jax.jit(lambda pool, ids: pool[ids])
+    for width in (640, 1280, 2560):
+        pool = jax.random.normal(jax.random.key(width), (rows, width),
+                                 jnp.bfloat16)
+        gather(pool, ids).block_until_ready()
+        t0 = time.perf_counter()
+        for _ in range(20):     # queued: the device's time, not the host's
+            got = gather(pool, ids)
+        got.block_until_ready()
+        ms = (time.perf_counter() - t0) / 20 * 1e3
+        out[f"gather_{width}_ms"] = ms
+        out[f"gather_{width}_ns_a_row"] = ms * 1e6 / chosen
+        del pool, got
+
+    model = lm.LatentMoELM(model_preset("glm-5.2-share16"))
+    params = jax.jit(
+        lambda: model.init(jax.random.key(0), jnp.ones((1, 8), jnp.int32))
+    )()["params"]
+    econf = EngineConfig(
+        num_slots=48, prompt_buckets=(16896,), max_new_tokens=2048,
+        page_size=16, num_pages=11777, prefill_chunk=512, prefix_cache=True,
+        weights_dtype="bfloat16", warmup=False,
+    )
+    queue = RequestQueue(
+        max_depth=16, prompt_buckets=econf.prompt_buckets,
+        max_new_tokens=econf.max_new_tokens,
+    )
+    engine = DecodeEngine(model, params, econf, queue)
+    assert set(engine._cache) == {
+        "latents_0", "latents_1", "layer_0", "layer_4"}
+    assert engine._cache["latents_0"]["latent_pages"].shape == (
+        11777, 16, 2560)
+    fn = engine._decode_step_fn()
+    leaves, tree = jax.tree.flatten(engine._cache)
+    pools = jax.tree.unflatten(tree, [
+        jax.device_put(jax.random.normal(jax.random.key(i), x.shape, x.dtype),
+                       x.sharding)
+        for i, x in enumerate(leaves)])
+    width = econf.pages_per_slot
+    table = (1 + (np.arange(48)[:, None] * 245 + np.arange(width)[None])
+             % 11776).astype(np.int32)
+    zeros = np.zeros((48,), np.int32)
+    ops = (zeros, table, np.full((48,), 18000, np.int32), zeros, zeros,
+           np.zeros((48,), np.float32), zeros)
+    waited = []
+    for _ in range(8):
+        t0 = time.perf_counter()
+        got, pools = fn(engine._params, pools, *ops)
+        jax.block_until_ready(got)
+        waited.append((time.perf_counter() - t0) * 1e3)
+    t0 = time.perf_counter()
+    for _ in range(10):
+        got, pools = fn(engine._params, pools, *ops)
+    jax.block_until_ready(got)
+    out["decode_step_queued_ms"] = (time.perf_counter() - t0) / 10 * 1e3
+    out["decode_step_waited_ms"] = float(np.median(waited[2:]))
+    print("glm52_decode_step", json.dumps(out))
+    os.makedirs("chiprun_out", exist_ok=True)
+    with open("chiprun_out/glm52_decode_step.json", "w") as f:
+        json.dump(out, f, indent=1)
+    # wider rows cost more, and less than as many narrow ones
+    assert out["gather_640_ms"] < out["gather_1280_ms"] < out["gather_2560_ms"]
+    assert out["gather_2560_ms"] < 4 * out["gather_640_ms"]
+    assert out["decode_step_queued_ms"] < out["parent_module_ms"]
+
+
 def test_dispatch_paths_at_model_shapes_on_chip():
     """Which path each fused op takes on ONE chip at the shapes the two
     smoke models run: bert-large's block tails (micro 8 x seq 128 x 1024)
