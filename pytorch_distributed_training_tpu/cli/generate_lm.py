@@ -122,14 +122,16 @@ def load_model_and_params(args, tok):
             f"tokenizer vocab {tok.vocab_size} exceeds model vocab "
             f"{mcfg.vocab_size}"
         )
-    from pytorch_distributed_training_tpu.models import latent_moe
+    from pytorch_distributed_training_tpu.models import latent_moe, sambay
 
-    if isinstance(mcfg, latent_moe.LatentMoEConfig):
+    own_family = {latent_moe.LatentMoEConfig: latent_moe.LatentMoELM,
+                  sambay.SambaYConfig: sambay.SambaYLM}.get(type(mcfg))
+    if own_family is not None:
         if getattr(args, "weights_dtype", None) == "bfloat16":
             # created in the resident type: a model sized to the chip has
             # no room for a float32 tree beside its bfloat16 one
             mcfg = dataclasses.replace(mcfg, param_dtype="bfloat16")
-        model = latent_moe.LatentMoELM(mcfg)
+        model = own_family(mcfg)
     else:
         model = GPT2LMModel(mcfg)
 
@@ -152,9 +154,10 @@ def load_model_and_params(args, tok):
         log0("no checkpoint given: generating from RANDOM weights (demo)")
         init = lambda key: model.init(  # noqa: E731
             key, np.ones((1, 8), np.int32))["params"]
-        if isinstance(model, latent_moe.LatentMoELM):
+        if own_family is not None:
             # one compiled program: op by op, init would run the expert
-            # layers' loops and compile every distinct shape on its own
+            # layers' loops (or a scan over tokens) and compile every
+            # distinct shape on its own
             init = jax.jit(init)
         params = init(jax.random.key(args.seed))
     return model, params, ckpt_step

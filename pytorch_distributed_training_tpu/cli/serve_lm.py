@@ -36,6 +36,16 @@ work over their pools. They refuse, at start-up and by the flag's name:
 Size ``--num-pages`` yourself where prompts share prefixes (the default
 reserves every slot a whole context).
 
+State-space hybrid presets (``--model phi-4-mini-flash``: Mamba layers,
+window attention, one full-attention layer whose K/V page pool seven cross
+layers read, Gated Memory Units; ``models/sambay.py``) serve through the
+same server, tick, allocator and sampling, with a recurrent state and a
+window ring a slot beside the one page pool. ``--weights-dtype bfloat16``
+at the published widths; ``--prefill-chunk`` works (a prompt's upper half
+runs for its last token alone). They refuse, by the flag's name: ``--tp``,
+``--spec-k``, ``--prefix-cache``, ``--weights-dtype int8``, ``--kv-dtype
+int8``.
+
 Live reload: with ``--checkpoint-dir`` the server exposes ``POST /swap``
 (swap to a named step) and ``--hotswap-poll-s N`` additionally watches the
 directory, hot-swapping each newly published manifest-verified step into

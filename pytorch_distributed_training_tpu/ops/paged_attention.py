@@ -320,10 +320,10 @@ def _page_tiles(pages) -> bool:
 def _page_walk_kernel(
     bt_ref,  # scalar-prefetch: [B, W] int32
     len_ref,  # scalar-prefetch: [B] int32
-    q_ref,  # [1, 1, H*D]
+    q_ref,  # [1, 1, H*D]; rows mode (head_dim None): [1, H, lanes]
     k_hbm,  # [num_pages, P, H*D], in HBM
     v_hbm,
-    o_ref,  # [1, 1, H*D]
+    o_ref,  # [1, 1, H*D]; rows mode: [1, H, lanes] float32
     kbuf,  # [2, C*P, H*D]: the block being computed and the one in flight
     vbuf,
     sems,  # DMA semaphores [K/V, buffer]
@@ -341,7 +341,7 @@ def _page_walk_kernel(
 ):
     b = pl.program_id(0)
     block_tokens = block_pages * page_size
-    lanes = heads * head_dim
+    lanes = kbuf.shape[2]
     # float32 pools: every pass of the MXU, as the reference's products are
     # exact float32; bf16 operands are exact in one
     precision = (
@@ -407,12 +407,19 @@ def _page_walk_kernel(
     l_ref[...] = jnp.zeros_like(l_ref)
     acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    # Qbd: the slot's query, one head a row, each in its own lanes
-    row = jax.lax.broadcasted_iota(jnp.int32, (heads, lanes), 0)
-    lane = jax.lax.broadcasted_iota(jnp.int32, (heads, lanes), 1)
-    own = (lane >= row * head_dim) & (lane < (row + 1) * head_dim)
-    q = jnp.broadcast_to(q_ref[0].astype(jnp.float32), (heads, lanes))
-    qbd = jnp.where(own, q, 0.0).astype(k_hbm.dtype)
+    if head_dim is None:
+        # rows mode: the caller laid the queries out, one a row, each in
+        # the lanes of the key head it scores against (fewer K/V heads
+        # than query heads, two softmaxes over one value: the caller's to
+        # say), and takes every row's weighted values over ALL lanes
+        qbd = q_ref[0]
+    else:
+        # Qbd: the slot's query, one head a row, each in its own lanes
+        row = jax.lax.broadcasted_iota(jnp.int32, (heads, lanes), 0)
+        lane = jax.lax.broadcasted_iota(jnp.int32, (heads, lanes), 1)
+        own = (lane >= row * head_dim) & (lane < (row + 1) * head_dim)
+        q = jnp.broadcast_to(q_ref[0].astype(jnp.float32), (heads, lanes))
+        qbd = jnp.where(own, q, 0.0).astype(k_hbm.dtype)
 
     def block_step(j, carry):
         buf = (first + j) % 2
@@ -463,25 +470,28 @@ def _page_walk_kernel(
     # all-masked degenerate case from producing NaN
     l = l_ref[...][:, :1]
     out = acc_ref[...] / jnp.where(l > 0.0, l, 1.0)
-    # head n keeps lanes n*head_dim ..: one row of lanes again
-    o_ref[0] = jnp.sum(
-        jnp.where(own, out, 0.0), axis=0, keepdims=True
-    ).astype(o_ref.dtype)
+    if head_dim is None:
+        o_ref[0] = out.astype(o_ref.dtype)
+    else:
+        # head n keeps lanes n*head_dim ..: one row of lanes again
+        o_ref[0] = jnp.sum(
+            jnp.where(own, out, 0.0), axis=0, keepdims=True
+        ).astype(o_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("scale", "interpret"))
-def _paged_page_walk(q, k_pages, v_pages, block_table, lengths, *, scale,
-                     interpret):
-    """Jitted, so that a model's layers, which call it with the same
-    shapes, share ONE trace and one lowering of the kernel (traced 24
-    times, gpt2-medium's decode program took 34 s longer to build)."""
-    batch, heads, head_dim = q.shape
-    _, page_size, lanes = k_pages.shape
+def _page_walk_call(q_rows, k_pages, v_pages, block_table, lengths, *,
+                    scale, interpret, heads, head_dim, out_dtype, name):
+    """The page-walk kernel over ``q_rows`` [batch, rows a slot, lanes]:
+    one row holding every head's query (``head_dim`` given: the kernel
+    unfolds it, one head a row, and folds the output back), or ``heads``
+    rows the caller laid out (``head_dim`` None: rows mode)."""
+    batch, rows, lanes = q_rows.shape
+    page_size = k_pages.shape[1]
     windows = block_table.shape[1]
     block_pages = max(1, _BLOCK_TOKENS // page_size)
-    slot_row = pl.BlockSpec((1, 1, lanes), lambda b, bt, ln: (b, 0, 0))
+    slot_row = pl.BlockSpec((1, rows, lanes), lambda b, bt, ln: (b, 0, 0))
     block = (2, block_pages * page_size, lanes)
-    out = pl.pallas_call(
+    return pl.pallas_call(
         functools.partial(
             _page_walk_kernel,
             scale=scale,
@@ -510,15 +520,42 @@ def _paged_page_walk(q, k_pages, v_pages, block_table, lengths, *, scale,
                 pltpu.VMEM((heads, lanes), jnp.float32),
             ],
         ),
-        out_shape=jax.ShapeDtypeStruct((batch, 1, lanes), v_pages.dtype),
+        out_shape=jax.ShapeDtypeStruct((batch, rows, lanes), out_dtype),
         # the slots run in order: each starts the next one's first copies
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)
         ),
-        name="paged_attn",
+        name=name,
         interpret=interpret,
-    )(block_table, lengths, q.reshape(batch, 1, lanes), k_pages, v_pages)
+    )(block_table, lengths, q_rows, k_pages, v_pages)
+
+
+@functools.partial(jax.jit, static_argnames=("scale", "interpret"))
+def _paged_page_walk(q, k_pages, v_pages, block_table, lengths, *, scale,
+                     interpret):
+    """Jitted, so that a model's layers, which call it with the same
+    shapes, share ONE trace and one lowering of the kernel (traced 24
+    times, gpt2-medium's decode program took 34 s longer to build)."""
+    batch, heads, head_dim = q.shape
+    lanes = k_pages.shape[2]
+    out = _page_walk_call(
+        q.reshape(batch, 1, lanes), k_pages, v_pages, block_table, lengths,
+        scale=scale, interpret=interpret, heads=heads, head_dim=head_dim,
+        out_dtype=v_pages.dtype, name="paged_attn")
     return out.reshape(q.shape)
+
+
+@functools.partial(jax.jit, static_argnames=("scale", "interpret"))
+def _rows_page_walk(q_rows, k_pages, v_pages, block_table, lengths, *, scale,
+                    interpret):
+    """``paged_attn_rows``: the same walk in rows mode. ``q_rows`` [batch,
+    rows, lanes] in the pools' dtype; returns, float32, each row's softmax
+    over the slot's live tokens times V over all lanes. One trace for all
+    the layers that read one pool."""
+    return _page_walk_call(
+        q_rows, k_pages, v_pages, block_table, lengths, scale=scale,
+        interpret=interpret, heads=q_rows.shape[1], head_dim=None,
+        out_dtype=jnp.float32, name="paged_attn_rows")
 
 
 # ------------------------------------------- pallas: one page a grid step
@@ -836,3 +873,171 @@ def _paged_pallas_mq(q, k_pages, v_pages, block_table, lengths, scale,
         interpret=_interpreting(),
     )(*operands)
     return out
+
+
+# ------------------------------------- differential attention, grouped heads
+#
+# Query heads pair up (``2p``, ``2p + 1``); two pairs share a K/V group
+# ``g = p // 2`` of two heads. Query head ``n = 4g + 2j + i`` (pair ``j`` of
+# its group, softmax ``i`` of its pair) scores against key head ``2g + i``
+# and weighs the group's 2 x head_dim values ``[v_2g; v_2g+1]``; a pair's
+# output is its first softmax's less ``lam`` times its second's. Keys carry
+# no positions in the family that uses this (models/sambay.py), so a mask is
+# all a key's position is for.
+#
+# ``both``: the two softmaxes' weighted values, float32 ``[..., groups, 2
+# (pair), 2 (softmax), 2 * head_dim]``, which ``differential_combine``
+# turns into the pairs' outputs. Two ways to it: the XLA formula over
+# gathered or dense keys (``differential_scores_attention``: every
+# multi-token step, and the decode step off the chip), and the page-walk
+# kernel ``paged_attn_rows`` (the decode step's read of a page pool, or of
+# a ring seen as a slot's fixed run of pages, where ``dispatch.mode()`` is
+# ``"direct"`` and a page is whole tiles), which costs what is live.
+
+
+def differential_scores_attention(q, k, v, q_pos, k_pos, scale, *,
+                                  window=None, block: int = 128):
+    """``q`` [b, t, heads, d]; ``k``, ``v`` [b, s, kv_heads, d], heads = 2
+    kv_heads; ``q_pos`` [b, t], ``k_pos`` [b, s]: a key is seen where ``0
+    <= k_pos <= q_pos`` (and ``k_pos > q_pos - window``). In blocks of
+    ``block`` queries. Returns ``both`` [b, t, groups, 2, 2, 2d]."""
+    b, t, heads, d = q.shape
+    s, groups = k.shape[1], k.shape[2] // 2
+    kg = k.reshape(b, s, groups, 2, d)
+    vg = v.reshape(b, s, groups, 2 * d)
+
+    def rows(qb, qp):
+        qg = qb.reshape(b, qb.shape[1], groups, 2, 2, d)
+        scores = jnp.einsum(
+            "brgjid,bsgid->bgjirs", qg, kg,
+            preferred_element_type=jnp.float32) * scale
+        seen = (k_pos[:, None, :] <= qp[:, :, None]) & (k_pos[:, None, :] >= 0)
+        if window is not None:
+            seen &= k_pos[:, None, :] > qp[:, :, None] - window
+        scores = jnp.where(seen[:, None, None, None], scores, _NEG_INF)
+        probs = jax.nn.softmax(scores, axis=-1).astype(v.dtype)
+        return jnp.einsum("bgjirs,bsge->brgjie", probs, vg,
+                          preferred_element_type=jnp.float32)
+
+    if t <= block:
+        return rows(q, q_pos)
+    n = -(-t // block)
+
+    def split(x):
+        x = jnp.pad(x, [(0, 0), (0, n * block - t)] + [(0, 0)] * (x.ndim - 2),
+                    mode="edge")
+        return jnp.moveaxis(x.reshape(b, n, block, *x.shape[2:]), 1, 0)
+
+    out = jax.lax.map(lambda a: rows(*a), (split(q), split(q_pos)))
+    return jnp.moveaxis(out, 0, 1).reshape(b, n * block, *out.shape[3:])[:, :t]
+
+
+def differential_combine(both, lam, gain, eps: float, post: float):
+    """``RMSNorm(first - lam * second; gain, eps) * post`` of each pair:
+    ``both`` [..., groups, 2, 2, 2d] -> [..., 2 * groups, 2d] float32."""
+    o = both[..., 0, :] - lam * both[..., 1, :]
+    o = o.reshape(*o.shape[:-3], o.shape[-3] * 2, o.shape[-1])
+    o = o * jax.lax.rsqrt(jnp.mean(o * o, axis=-1, keepdims=True) + eps)
+    return o * gain * post
+
+
+def _differential_kernel_fits(q, pages) -> bool:
+    return (
+        q.dtype == pages.dtype
+        and dispatch.mode() == "direct"
+        and _page_tiles(pages)
+    )
+
+
+def _differential_rows(q, kv_heads: int):
+    """The decode step's queries laid out for ``paged_attn_rows``: ``q``
+    [b, heads, d] -> [b, rows, kv_heads * d], head ``n = 4g + 2j + i`` in
+    row ``n``, lanes of key head ``2g + i``, zeros elsewhere; rows padded
+    to whole sublane tiles."""
+    b, heads, d = q.shape
+    n = jnp.arange(heads)
+    key_head = 2 * (n // 4) + n % 2
+    own = (key_head[:, None] == jnp.arange(kv_heads)[None, :])   # [heads, K]
+    rows = jnp.where(own[None, :, :, None], q[:, :, None, :], 0)
+    rows = rows.reshape(b, heads, kv_heads * d)
+    return jnp.pad(rows, ((0, 0), (0, -heads % 16), (0, 0)))
+
+
+def _differential_both(out, heads: int, d: int):
+    """``paged_attn_rows``'s output [b, rows, lanes] float32 -> ``both`` [b,
+    groups, 2, 2, 2d]: head ``n`` keeps the lanes of its group's values."""
+    b, _, lanes = out.shape
+    groups = lanes // (2 * d)
+    per_group = out[:, :heads].reshape(b, heads, groups, 2 * d)
+    group = (jnp.arange(heads) // 4)[None, :, None, None]
+    mine = jnp.take_along_axis(per_group, group, axis=2)[:, :, 0]
+    return mine.reshape(b, groups, 2, 2, 2 * d)
+
+
+def differential_paged_chunk(q, k_pages, v_pages, block_table, context,
+                             scale, *, block: int = 128):
+    """A block of query tokens over a page pool, the XLA formula: ``q`` [b,
+    t, heads, d] at positions ``context .. context + t - 1`` of sequences
+    whose rows up to there are written; gathers each sequence's pages and
+    attends causally. Returns ``both`` [b, t, groups, 2, 2, 2d]."""
+    b, t, _, d = q.shape
+    kv_heads = k_pages.shape[2] // d
+    tokens = block_table.shape[1] * k_pages.shape[1]
+    keys = _gather_dequant(k_pages, None, block_table, b, tokens, kv_heads, d)
+    values = _gather_dequant(v_pages, None, block_table, b, tokens, kv_heads, d)
+    q_pos = context[:, None] + jnp.arange(t, dtype=jnp.int32)[None]
+    k_pos = jnp.broadcast_to(jnp.arange(tokens, dtype=jnp.int32), (b, tokens))
+    return differential_scores_attention(
+        q, keys, values, q_pos, k_pos, scale, block=block)
+
+
+def differential_paged_decode(q, k_pages, v_pages, block_table, lengths,
+                              scale):
+    """One query token a sequence over a page pool: ``q`` [b, heads, d],
+    pools [pages, page_size, kv_heads * d], ``lengths`` [b] inclusive of
+    the token. Returns ``both`` [b, groups, 2, 2, 2d]."""
+    b, heads, d = q.shape
+    kv_heads = k_pages.shape[2] // d
+    kernel = _differential_kernel_fits(q, k_pages)
+    dispatch.note_path("paged_attn_rows", "direct" if kernel else "xla")
+    if kernel:
+        out = _rows_page_walk(
+            _differential_rows(q, kv_heads), k_pages, v_pages, block_table,
+            lengths, scale=scale, interpret=_interpreting())
+        return _differential_both(out, heads, d)
+    return differential_paged_chunk(
+        q[:, None], k_pages, v_pages, block_table, lengths - 1, scale)[:, 0]
+
+
+def differential_ring_decode(q, k_ring, v_ring, slot, live, scale,
+                             page_size: int):
+    """One query token a sequence over its slot's ring: rings [slots, ring,
+    kv_heads * d]; ``slot`` [b] (None: row ``b`` is slot ``b``); ``live``
+    [b]: the ring's first ``live`` rows are the keys (keys carry no
+    positions: a ring that has wrapped is all live, one that has not is
+    live from row 0). Returns ``both`` [b, groups, 2, 2, 2d]."""
+    b, heads, d = q.shape
+    slots, ring, width = k_ring.shape
+    kv_heads = width // d
+    live = jnp.maximum(live, 1)
+    rows = jnp.arange(b, dtype=jnp.int32) if slot is None else slot
+    paged = ring % page_size == 0
+    pages = k_ring.reshape(-1, page_size, width) if paged else k_ring
+    kernel = paged and _differential_kernel_fits(q, pages)
+    dispatch.note_path("paged_attn_rows", "direct" if kernel else "xla")
+    if kernel:
+        # a slot's ring is a fixed run of pages: no table is kept, this is it
+        run = ring // page_size
+        table = rows[:, None] * run + jnp.arange(run, dtype=jnp.int32)[None]
+        out = _rows_page_walk(
+            _differential_rows(q, kv_heads), pages,
+            v_ring.reshape(-1, page_size, width), table, live,
+            scale=scale, interpret=_interpreting())
+        return _differential_both(out, heads, d)
+    keys = (k_ring if slot is None else k_ring[slot]).reshape(
+        b, ring, kv_heads, d)
+    values = (v_ring if slot is None else v_ring[slot]).reshape(
+        b, ring, kv_heads, d)
+    k_pos = jnp.broadcast_to(jnp.arange(ring, dtype=jnp.int32), (b, ring))
+    return differential_scores_attention(
+        q[:, None], keys, values, (live - 1)[:, None], k_pos, scale)[:, 0]

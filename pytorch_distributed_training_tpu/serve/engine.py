@@ -491,6 +491,15 @@ class DecodeEngine:
             kv_num_pages=config.total_pages,
             kv_cache_dtype="int8" if config.kv_dtype == "int8" else "auto",
         )
+        # What the model keeps per sequence (paged_cache.SlotMemory), where
+        # the family declares it: pages for the allocator, rings and states
+        # sized here by num_slots, once. A family that declares nothing
+        # (the empty tuple) keeps page pools only, which ``with_tables``
+        # then finds by their leaves' names.
+        self._memory = ()
+        if hasattr(dcfg, "slot_memory"):
+            dcfg = dataclasses.replace(dcfg, kv_num_slots=config.num_slots)
+            self._memory = dcfg.slot_memory()
         self._decode_model = type(model)(dcfg)
         # routed experts (a share of them held here): the decode step also
         # returns its routing counts, fetched with the sampled ids
@@ -765,6 +774,45 @@ class DecodeEngine:
         mix device-0-committed arrays with mesh-committed ones."""
         return jax.device_put(tree, self._repl)
 
+    def _tables(self, pools, block_table, context_len, slot_ops=()):
+        """``with_tables`` over what the model declared: each memory's
+        node is handed its operands by its path, with a prefill's
+        ``slot_ops`` (``_slot_ops``) where rings and states are kept by
+        slot. A family that declared nothing has its page pools found by
+        their leaves' names."""
+        slot, chunk_len = slot_ops or (None, None)
+        return with_tables(
+            pools, block_table, context_len, memory=self._memory,
+            slot=slot, chunk_len=chunk_len)
+
+    def _slot_ops(self, slot: int, real: int) -> tuple:
+        """The operands a batch-1 prefill needs beside its block-table row
+        where rings and states are kept by slot: which slot it fills and
+        how many of the step's tokens are real. None for a family whose
+        memory is pages alone."""
+        if all(m.kind == "pages" for m in self._memory):
+            return ()
+        return (np.asarray([slot], np.int32), np.asarray([real], np.int32))
+
+    def _row_logits(self, model, params, cache, ids, positions, index):
+        """Float32 logits [vocab] of row ``index()`` of a batch-1 prefill
+        step, and the step's variables. A model that says it takes
+        ``logit_index`` is told the row, and runs what keeps nothing for
+        that row alone. (``index`` is a function so that the older families'
+        programs compute it where they always did, after the model.)"""
+        variables = {"params": params, "cache": cache}
+        if getattr(model, "takes_logit_index", False):
+            logits, vars_ = model.apply(
+                variables, ids, position_ids=positions, mutable=["cache"],
+                logit_index=index()[None])
+            return logits[0, 0, :].astype(jnp.float32), vars_
+        logits, vars_ = model.apply(
+            variables, ids, position_ids=positions, mutable=["cache"])
+        last = jnp.take_along_axis(
+            logits, index()[None, None, None], axis=1
+        )[0, 0, :].astype(jnp.float32)
+        return last, vars_
+
     def _shardings_for(self, params):
         """What ``device_put`` places a serving params tree onto: the
         engine's one device, or per-leaf tp shardings over the mesh."""
@@ -869,8 +917,8 @@ class DecodeEngine:
         """Jitted prefill-into-slot for one prompt bucket. Compiles once per
         bucket (the queue only produces configured buckets).
 
-        ``(params, pools, ids, real_len, bt_row, seed, temp, top_k)``;
-        returns ``(token id, new pools)``: the first token, a scalar int32
+        ``(params, pools, ids, real_len, bt_row, seed, temp, top_k,
+        *slot_ops)``; returns ``(token id, new pools)``: the first token, a scalar int32
         sampled in-trace from the last real position's logits.
         """
         fn = self._prefill_fns.get(bucket)
@@ -878,26 +926,22 @@ class DecodeEngine:
             return fn
 
         def prefill(params, pools, ids, real_len, bt_row, seed, temp,
-                    top_k):
+                    top_k, *slot_ops):
             # weight-only int8: dequantize in-trace (identity on fp32
             # trees) — XLA folds the broadcast multiply into the
             # matmuls, so only int8 kernels + scales stay resident
             params = dequantize_serve_params(params)
             # fresh sequence: context_len 0, K/V scattered straight
             # into the slot's pages through its block-table row
-            cache = with_tables(
-                pools, bt_row, jnp.zeros((1,), jnp.int32)
+            cache = self._tables(
+                pools, bt_row, jnp.zeros((1,), jnp.int32), slot_ops
             )
-            logits, vars_ = self._decode_model.apply(
-                {"params": params, "cache": cache},
-                ids,
-                position_ids=jnp.arange(bucket, dtype=jnp.int32)[None],
-                mutable=["cache"],
+            last, vars_ = self._row_logits(
+                self._decode_model, params, cache, ids,
+                jnp.arange(bucket, dtype=jnp.int32)[None],
+                lambda: real_len - 1,
             )
             new_pools = strip_tables(vars_["cache"])
-            last = jnp.take_along_axis(
-                logits, (real_len - 1)[None, None, None], axis=1
-            )[0, 0, :].astype(jnp.float32)
             token = device_sample(
                 last[None], seed[None], jnp.zeros((1,), jnp.int32),
                 temp[None], top_k[None],
@@ -935,7 +979,7 @@ class DecodeEngine:
         def decode(params, pools, tokens, bt, ctx, seeds, steps, temps,
                    top_ks):
             params = dequantize_serve_params(params)
-            cache = with_tables(pools, bt, ctx)
+            cache = self._tables(pools, bt, ctx)
             # a model with routed experts also hands back what the
             # step routed where (counted over the live slots: an idle
             # slot sits at context 0, which no live one does)
@@ -1020,7 +1064,7 @@ class DecodeEngine:
         monolithic-prefill padding).
 
         ``(params, pools, ids, ctx0, sample_idx, bt_row, seed, temp,
-        top_k)`` — ids [1, C] int32, ctx0 [1] int32 (tokens already
+        top_k, *slot_ops)`` — ids [1, C] int32, ctx0 [1] int32 (tokens already
         scattered), sample_idx scalar int32 (chunk-local row of the
         prompt's LAST real token; only the final chunk's sample is used by
         the host). Returns ``(token_id, new pools)``.
@@ -1030,20 +1074,15 @@ class DecodeEngine:
         C = self._chunk_size
 
         def chunk(params, pools, ids, ctx0, sample_idx, bt_row, seed, temp,
-                  top_k):
+                  top_k, *slot_ops):
             params = dequantize_serve_params(params)
-            cache = with_tables(pools, bt_row, ctx0)
-            logits, vars_ = self._mq_model.apply(
-                {"params": params, "cache": cache},
-                ids,
-                position_ids=ctx0[:, None]
-                + jnp.arange(C, dtype=jnp.int32)[None, :],
-                mutable=["cache"],
+            cache = self._tables(pools, bt_row, ctx0, slot_ops)
+            last, vars_ = self._row_logits(
+                self._mq_model, params, cache, ids,
+                ctx0[:, None] + jnp.arange(C, dtype=jnp.int32)[None, :],
+                lambda: sample_idx,
             )
             new_pools = strip_tables(vars_["cache"])
-            last = jnp.take_along_axis(
-                logits, sample_idx[None, None, None], axis=1
-            )[0, 0, :].astype(jnp.float32)
             token = device_sample(
                 last[None], seed[None], jnp.zeros((1,), jnp.int32),
                 temp[None], top_k[None],
@@ -1192,6 +1231,8 @@ class DecodeEngine:
             np.int32(0),
             np.zeros((1, W), np.int32),
             np.int32(0), np.float32(0.0), np.int32(0),
+            # slot 0, no real token: neither its state nor its rings move
+            *self._slot_ops(0, 0),
         ))
         out, self._cache = self._chunk_fn()(
             self._params, self._cache, *ops
@@ -1238,6 +1279,7 @@ class DecodeEngine:
                         np.int32(1),
                         np.zeros((1, W), np.int32),
                         np.int32(0), np.float32(0.0), np.int32(0),
+                        *self._slot_ops(0, 0),
                     ))
                     out, self._cache = self._prefill_fn(bucket)(
                         self._params, self._cache, *ops
@@ -1946,6 +1988,7 @@ class DecodeEngine:
                     np.int32(req.seed),
                     np.float32(req.temperature),
                     np.int32(min(req.top_k, np.iinfo(np.int32).max)),
+                    *self._slot_ops(slot, req.prompt_len),
                 ))
                 with watchdog_guard("serve_prefill"):
                     out, self._cache = self._prefill_fn(bucket)(
@@ -2031,6 +2074,7 @@ class DecodeEngine:
                     np.int32(req.seed),
                     np.float32(req.temperature),
                     np.int32(min(req.top_k, np.iinfo(np.int32).max)),
+                    *self._slot_ops(i, end - start),
                 ))
                 out, self._cache = self._chunk_fn()(
                     self._params, self._cache, *ops
@@ -2344,6 +2388,7 @@ class DecodeEngine:
         admitted0, prefill_tokens0 = self.admitted, self.prefill_tokens
         chunks0, cached0 = self.prefill_chunks, self.prefix_cached_tokens
         moe_attrs = {}
+        live_tokens = 0     # the active slots' contexts at dispatch, summed
 
         with self._phase("expire"):
             for req in self._queue.expire_overdue():
@@ -2451,6 +2496,7 @@ class DecodeEngine:
                     steps[i] = s.steps_done + 1   # == len(r.tokens) at sample
                     temps[i] = r.temperature
                     top_ks[i] = min(r.top_k, np.iinfo(np.int32).max)
+                live_tokens = int(ctx.sum())
                 if streaming:
                     # mid-prefill slots hold real pages but are not in
                     # this dispatch — null their rows so the decode
@@ -2501,6 +2547,7 @@ class DecodeEngine:
                 "prefill_tokens": self.prefill_tokens - prefill_tokens0,
                 "cached_tokens": self.prefix_cached_tokens - cached0,
                 "chunks": self.prefill_chunks - chunks0,
+                "live_tokens": live_tokens,
                 **moe_attrs,
             }
             depth = self._queue.depth()
@@ -2644,6 +2691,9 @@ class DecodeEngine:
         the capacity arithmetic behind the int8 cache's concurrency win
         (at head_dim 64 and fp32 compute, int8 pools cost (64+4)/256 of
         the fp32 bytes per token)."""
+        if self._memory:
+            # the model declared its memories: pages are what a token costs
+            return sum(m.bytes_per_token for m in self._memory)
         mcfg = self._decode_model.config
         values = getattr(mcfg, "cache_values_per_token", None)
         if values is not None:
@@ -2657,6 +2707,22 @@ class DecodeEngine:
                 mcfg.head_dim * jnp.dtype(mcfg.compute_dtype).itemsize
             )
         return 2 * mcfg.num_layers * mcfg.num_heads * per_head
+
+    def _slot_memory_stats(self) -> dict:
+        """What a slot keeps beside its pages (a family that declares no
+        memories keeps nothing), and how many layers read the page pool
+        that has the most readers."""
+        memory = self._memory
+
+        def per_slot(kind):
+            return sum(m.bytes_per_slot for m in memory if m.kind == kind)
+
+        return {
+            "state_bytes_per_slot": per_slot("state"),
+            "ring_bytes_per_slot": per_slot("ring"),
+            "kv_pool_readers": max(
+                (m.readers for m in memory if m.kind == "pages"), default=1),
+        }
 
     def _kv_pool_relayout_ops(self) -> Optional[int]:
         """Whole-pool copy/transpose/convert instructions in the compiled
@@ -2684,6 +2750,14 @@ class DecodeEngine:
             # model without a latent pool): one a selection group
             "latent_row_gathers": self._hot_audit().get(
                 "latent_row_gathers"),
+            # what a slot keeps beside its pages, and the pool's readers
+            **self._slot_memory_stats(),
+            # share of the busy ticks that held a prefill chunk (a token gap
+            # over such a tick is a lump, not a plain decode step)
+            "chunk_tick_share": (
+                self.prefill_chunks / self.busy_ticks
+                if self.busy_ticks else None
+            ),
             "queue_depth": self._queue.depth(),
             "queue_depth_by_tier": self._queue.depth_by_tier(),
             "slot_occupancy": self.slot_occupancy(),

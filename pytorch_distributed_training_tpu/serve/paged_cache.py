@@ -24,15 +24,60 @@ tick loop); the allocator itself takes no locks.
 
 from __future__ import annotations
 
+import dataclasses
 from collections.abc import Mapping
 from typing import Any
 
 import numpy as np
 
 # Cache-collection keys injected/stripped around jitted calls: the engine's
-# resident cache tree holds page POOLS only; block_table/context_len are
-# per-call traced operands.
-_TABLE_KEYS = ("block_table", "context_len")
+# resident cache tree holds page POOLS (and, for a family that declares
+# them, rings and states) only; block_table/context_len, and slot/chunk_len
+# beside a ring or a state, are per-call traced operands.
+_TABLE_KEYS = ("block_table", "context_len", "slot", "chunk_len")
+
+
+@dataclasses.dataclass(frozen=True)
+class SlotMemory:
+    """One piece of per-sequence memory a model keeps on the device while
+    serving, as the model itself declares it (``config.slot_memory()``; a
+    family that declares none keeps page pools only, found by their leaves'
+    names as before). Three kinds:
+
+    ========  ====================  =======================  ================
+    kind      a layer stores        addressed by             on admission
+    ========  ====================  =======================  ================
+    pages     rows a TOKEN, in a    the slot's block-table   fresh pages from
+              pool shared by all    row (``PageAllocator``)  the allocator;
+              slots                                          lanes past the
+                                                             context masked
+    ring      a bounded run of      slot index, position     nothing: a row
+              rows a SLOT           modulo the ring          whose position
+                                                             would be negative
+                                                             is masked
+    state     a fixed block a SLOT  slot index               nothing: a step
+                                                             at context 0
+                                                             starts from zeros
+    ========  ====================  =======================  ================
+
+    Only ``pages`` grow with a sequence, so only they are the allocator's:
+    rings and states are sized by ``num_slots`` when the engine is built
+    and never grow. ``path`` is the cache node that holds the memory;
+    ``with_tables`` hands that node its per-call operands. ``readers``
+    counts the layers that read it each step (a pool shared down the stack
+    is kept once and read by several). The engine's ``kv_bytes_per_token``
+    sums ``bytes_per_token``; ``ring_bytes_per_slot`` and
+    ``state_bytes_per_slot`` sum ``bytes_per_slot`` by kind."""
+
+    kind: str
+    path: tuple
+    bytes_per_token: int = 0
+    bytes_per_slot: int = 0
+    readers: int = 1
+
+    def __post_init__(self):
+        if self.kind not in ("pages", "ring", "state"):
+            raise ValueError(f"unknown kind of slot memory {self.kind!r}")
 
 
 class PageAllocator:
@@ -232,13 +277,39 @@ class PageAllocator:
 
 
 def with_tables(pools: Mapping[str, Any], block_table: Any,
-                context_len: Any) -> dict[str, Any]:
+                context_len: Any, *, memory=(), slot: Any = None,
+                chunk_len: Any = None) -> dict[str, Any]:
     """Rebuild a full cache tree from engine-resident ``pools`` by injecting
     ``block_table``/``context_len`` beside every attention layer's page
     pools: the node that holds ``k_pages`` (per-head K/V pools) or
     ``latent_pages`` (a selection group's latent pool; the group's layers
     read its table for their indexer pools too). Used at TRACE level
-    inside the jitted programs."""
+    inside the jitted programs.
+
+    ``memory`` (a family's declared ``SlotMemory`` entries): nothing is
+    guessed from names; each declared node gets ``context_len``, a
+    ``pages`` node the block table too, and every node ``slot`` and
+    ``chunk_len`` where the step has them (a prefill: which slot its one
+    batch row is, how many of its tokens are real)."""
+    if memory:
+        step = {"context_len": context_len}
+        if slot is not None:
+            step["slot"] = slot
+        if chunk_len is not None:
+            step["chunk_len"] = chunk_len
+
+        def declared(node, path, operands):
+            if not path:
+                return {**node, **operands}
+            return {**node, path[0]: declared(node[path[0]], path[1:], operands)}
+
+        out = pools
+        for m in memory:
+            out = declared(out, m.path, (
+                {**step, "block_table": block_table} if m.kind == "pages"
+                else step))
+        return out
+
     def walk(node):
         if isinstance(node, Mapping):
             out = {k: walk(v) for k, v in node.items()}

@@ -313,17 +313,19 @@ _MODEL_PRESETS: dict[str, dict[str, Any]] = {
 
 
 def model_preset(name: str, **overrides: Any):
-    """The preset's configuration: a ``ModelConfig``, or the latent
-    expert family's own (``models/latent_moe.py::LatentMoEConfig``, which
-    is no set of ``ModelConfig`` fields: another block, other sizes)."""
+    """The preset's configuration: a ``ModelConfig``, or a family's own
+    (``models/latent_moe.py::LatentMoEConfig``, ``models/sambay.py::
+    SambaYConfig``, which are no sets of ``ModelConfig`` fields: other
+    blocks, other sizes)."""
     if name not in _MODEL_PRESETS:
-        from pytorch_distributed_training_tpu.models import latent_moe
+        from pytorch_distributed_training_tpu.models import latent_moe, sambay
 
-        if name in latent_moe.PRESETS:
-            return latent_moe.preset(name, **overrides)
-        raise KeyError(
-            f"unknown model preset {name!r}; have "
-            f"{sorted(_MODEL_PRESETS) + sorted(latent_moe.PRESETS)}")
+        families = (latent_moe, sambay)
+        for family in families:
+            if name in family.PRESETS:
+                return family.preset(name, **overrides)
+        have = sorted({*_MODEL_PRESETS, *(p for f in families for p in f.PRESETS)})
+        raise KeyError(f"unknown model preset {name!r}; have {have}")
     kwargs = dict(_MODEL_PRESETS[name])
     kwargs.update(overrides)
     return ModelConfig(**kwargs)

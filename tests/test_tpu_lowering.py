@@ -226,3 +226,33 @@ def test_paged_attn_lowers_at_the_decode_cells_geometry(one_chip):
     )
     assert took_kernel("paged_attn")
 
+
+
+@pytest.mark.parametrize("memory", ["pool", "ring"])
+def test_paged_attn_rows_lowers_at_the_reasoning_cells_geometry(one_chip, memory):
+    """The page walk in rows mode as `phi4flash_reason_decode` runs it: 40
+    query heads over 20 K/V heads of 64 (two softmaxes over one 128-wide
+    value), 64 slots; over the shared 18,433-page pool through 288-page
+    block tables, and over a window ring read as a slot's 32 pages. The
+    gate answers as one chip does and takes the kernel for both."""
+    slots, heads, kv_heads, page_size = 64, 40, 20, 16
+    q = S((slots, heads, HEAD_DIM), BF16)
+    if memory == "pool":
+        windows = 288
+        pool = S((1 + slots * windows, page_size, kv_heads * HEAD_DIM), BF16)
+
+        def f(q, kp, vp, bt, ln):
+            return pa.differential_paged_decode(
+                q, kp, vp, bt, ln, HEAD_DIM ** -0.5)
+
+        lower_for_tpu(f, q, pool, pool, S((slots, windows), jnp.int32),
+                      S((slots,), jnp.int32))
+    else:
+        ring = S((slots, 512, kv_heads * HEAD_DIM), BF16)
+
+        def f(q, kr, vr, live):
+            return pa.differential_ring_decode(
+                q, kr, vr, None, live, HEAD_DIM ** -0.5, page_size)
+
+        lower_for_tpu(f, q, ring, ring, S((slots,), jnp.int32))
+    assert took_kernel("paged_attn_rows")
