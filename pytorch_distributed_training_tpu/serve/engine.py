@@ -38,6 +38,13 @@ batching a la Orca, block-structured KV a la vLLM's PagedAttention):
   program is warm. (``serve/sampling.host_sample`` is the numpy mirror
   the tests hold the device sampler to; the engine never calls it.)
 
+- **One step in flight**: the ids a decode step samples stay on the
+  device and the next step reads them there (``where(fresh, host tokens,
+  previous ids)`` in the same program), so the plain decode tick
+  dispatches step k while step k-1 still runs and only then fetches,
+  emits and retires step k-1: in the steady state the host's whole part
+  of a tick runs under the device's step (``DecodeEngine.tick``).
+
 Integration: prefill/decode dispatch+block run under
 ``faults.watchdog_guard``; each tick routes through
 ``FaultPlan.slow_host_delay``; per-request TTFT/TPOT/queue-wait,
@@ -73,8 +80,9 @@ a long prompt's prefill no longer stalls short requests' decode;
 
 Live weight hot-swap (serve/hotswap.py): ``request_swap(params, version)``
 queues a validated replacement params tree from any thread; the serve
-loop applies it at the START of the next tick (``swap_params`` — never
-mid-tick, so a tick is never torn between two weight versions) and the
+loop applies it at the START of the next tick, once the step in flight
+(old weights) is retired (``swap_params`` — never between two dispatches
+of one tick, so a tick is never torn between two weight versions) and the
 OLD params stay alive until the first post-swap tick completes cleanly
 (trial/commit; a trial-tick failure rolls back to them). The resident
 page pools are untouched by a swap — in-flight slots simply continue
@@ -340,8 +348,15 @@ class _Slot:
     """Engine-private per-slot state between ticks."""
 
     request: GenRequest
-    pending_token: int          # sampled, not yet fed through decode
-    steps_done: int = 0         # generated tokens already fed into the KV
+    # sampled, not yet fed through decode: the host's copy, current while
+    # no step of the slot is in flight (``steps_done == steps_retired``)
+    pending_token: int
+    # two counters a slot. Decode steps DISPATCHED (generated tokens fed
+    # into the KV): context, the sampler's step index and the stop by
+    # length are built from it. Decode steps RETIRED (their ids fetched and
+    # emitted): one behind while a step is in flight.
+    steps_done: int = 0
+    steps_retired: int = 0
     # chunked prefill: "prefill" while the prompt is still streaming into
     # the slot's pages (prefill_pos tokens scattered so far), "decode" once
     # the first token is sampled
@@ -350,6 +365,16 @@ class _Slot:
     # speculative lane membership (request opt-in/out resolved against the
     # engine default at admission; fixed for the slot's lifetime)
     spec: bool = False
+
+
+@dataclasses.dataclass
+class _InFlight:
+    """One dispatched decode step whose ids are not fetched yet."""
+
+    out: object                 # device: [slots] ids, or (ids, routing)
+    # the (slot index, _Slot) pairs of the dispatch: retirement walks
+    # THESE, and skips a pair whose slot has been given up since
+    pairs: list
 
 
 @dataclasses.dataclass
@@ -649,6 +674,13 @@ class DecodeEngine:
                 strip_tables(dshapes),
             ))
         self._slots: list[Optional[_Slot]] = [None] * config.num_slots
+        # the plain decode path keeps ONE step in flight across the tick
+        # boundary (``_run_tick``): the step not yet retired, and the ids
+        # of the newest dispatch, which the next step reads on the device
+        self._inflight: Optional[_InFlight] = None
+        self._prev_ids = self._put(np.zeros((config.num_slots,), np.int32))
+        self.overlapped_ticks = 0       # ticks that dispatched, THEN retired
+        self.discarded_slot_steps = 0   # dispatched, never emitted
         self._prefill_fns: dict[int, object] = {}   # bucket -> jitted fn
         self._decode_fn = None
         self._verify_fn_ = None         # spec_k > 0: the k+1-position program
@@ -964,11 +996,17 @@ class DecodeEngine:
     def _decode_step_fn(self):
         """ONE jitted program advancing every slot a single token.
 
-        ``(params, pools, tokens, bt, ctx, seeds, steps, temps, top_ks)``:
-        batch-``num_slots`` apply with per-slot
+        ``(params, pools, prev_ids, fresh, tokens, bt, ctx, seeds, steps,
+        temps, top_ks)``: batch-``num_slots`` apply with per-slot
         ``position_ids``/``context_len``; idle slots' block-table rows
         point at the null page, so their writes land there and their
         outputs are discarded by the host (no freeze select needed).
+        A slot's input token never waits for the host: ``prev_ids`` is the
+        ids array the step before returned, still on the device (not
+        donated: the host fetches it AFTER this step is dispatched), and
+        the host sends ``tokens`` only for the ``fresh`` slots, those with
+        no step in flight (a prefill or last chunk has just handed them
+        their first token, or their last step is retired already).
         Returns ``([slots] int32 token ids, new pools)``; a model with
         routed experts returns ``(ids, routing counts)`` in the ids' place.
         """
@@ -976,9 +1014,10 @@ class DecodeEngine:
             return self._decode_fn
         routed = self._routed
 
-        def decode(params, pools, tokens, bt, ctx, seeds, steps, temps,
-                   top_ks):
+        def decode(params, pools, prev_ids, fresh, tokens, bt, ctx, seeds,
+                   steps, temps, top_ks):
             params = dequantize_serve_params(params)
+            tokens = jnp.where(fresh, tokens, prev_ids)
             cache = self._tables(pools, bt, ctx)
             # a model with routed experts also hands back what the
             # step routed where (counted over the live slots: an idle
@@ -1308,8 +1347,9 @@ class DecodeEngine:
                         self._draft_cache, *pg
                     )
         S = cfg.num_slots
-        # (tokens, block table, context, seeds, steps, temperatures, top-ks)
-        # of the decode step; the verify program takes k+1 tokens a slot
+        # (block table, context, seeds, steps, temperatures, top-ks) of a
+        # step; before them the decode step takes the fresh mask and the
+        # tokens, the verify program k+1 tokens a slot
         step_ops = (
             np.zeros((S, W), np.int32),
             np.zeros((S,), np.int32),
@@ -1337,9 +1377,12 @@ class DecodeEngine:
                     outs.append(dout)
         else:
             with warm("decode"):
-                ops = self._put((np.zeros((S,), np.int32),) + step_ops)
+                # every slot fresh: the previous ids are read by none
+                ops = self._put((
+                    np.ones((S,), np.bool_), np.zeros((S,), np.int32),
+                ) + step_ops)
                 out, self._cache = self._decode_step_fn()(
-                    self._params, self._cache, *ops
+                    self._params, self._cache, self._prev_ids, *ops
                 )
                 outs.append(out)
         # ONE sync for the whole warm-up batch (compiles are synchronous at
@@ -2039,9 +2082,13 @@ class DecodeEngine:
 
     # ------------------------------------------------------- chunked prefill
 
-    def _advance_prefills(self) -> bool:
+    def _advance_prefills(self, attrs: dict) -> bool:
         """Stream one ``prefill_chunk``-token chunk into every mid-prefill
         slot (one batch-1 dispatch each through the shared chunk program).
+        A prompt's last chunk blocks the tick for its first token, so the
+        decode step in flight is retired just before it (``_retire``, which
+        writes to the tick record's ``attrs``); a chunk before the last
+        fetches nothing and is only queued behind that step.
         The final chunk is ragged: ids are zero-padded, the prompt's last
         real token's row is sampled, and the pad lanes are dead by the
         causal horizon now and by ``context_len`` forever after — the same
@@ -2060,6 +2107,8 @@ class DecodeEngine:
             ids = np.zeros((1, C), np.int32)
             ids[0, : end - start] = req.prompt_ids[start:end]
             is_last = end >= req.prompt_len
+            if is_last:
+                self._retire(attrs)
             sample_idx = (
                 np.int32(req.prompt_len - 1 - start) if is_last
                 else np.int32(0)
@@ -2304,8 +2353,33 @@ class DecodeEngine:
         any work happened (the serve loop idles on the queue condition
         otherwise).
 
-        Swap protocol: a queued ``request_swap`` is installed HERE, at the
-        boundary between ticks — the tick body then runs entirely on the
+        The order of a plain decode tick (``spec_k == 0``): ONE decode step
+        stays in flight across the tick boundary. Tick k does its host work
+        (expire, admit, operands) and dispatches step k while step k-1
+        still runs, and only then fetches, emits and retires step k-1: in
+        the steady state the gap between two tokens is the device's step,
+        not step + host. A slot therefore has two counters, steps
+        DISPATCHED (``_Slot.steps_done``: context, the sampler's index, the
+        stop by length) and steps RETIRED (``steps_retired``; what
+        ``req.tokens`` and ``_is_terminal`` have seen), and retirement walks
+        the (slot, request) pairs of ITS dispatch: a pair whose request
+        finished since (end-of-text found one step late, a deadline, a
+        cancel) is skipped and counted (``discarded_slot_steps``), its id
+        never emitted. The engine retires FIRST and then goes on where it
+        can see that it must — no option says so: a weight swap is pending
+        (below); a prefill is about to block the tick (an admission, a
+        prompt's last chunk); no slot is active, so the tick only retires
+        (``has_work()`` stays true until the last ids are out);
+        ``cancel_all`` drops the step whole. A fetch that fails gives every
+        dispatched, unretired slot-step back (``_give_back_dispatched``):
+        the slots are dispatched again from their last emitted token.
+        ``spec_k > 0`` keeps its own synchronous path (``_verify_tick``
+        drafts from emitted ids) and never leaves a step in flight.
+
+        Swap protocol: a queued ``request_swap`` is installed at the top
+        of the tick body, after the step in flight — which ran on the OLD
+        weights — is retired, so a failure of that step is not blamed on
+        the new version; everything the tick dispatches then runs on the
         new weights (never torn across versions). The swap stays in its
         trial window until the body completes: a clean tick commits it
         (previous params released), a failing tick rolls back to the old
@@ -2318,18 +2392,6 @@ class DecodeEngine:
         are the explicit operand ``device_put`` and the token-id
         ``device_get``.
         """
-        with self._swap_lock:
-            pending, self._pending_swap = self._pending_swap, None
-        if pending is not None:
-            params, version, ticket, variant = pending
-            try:
-                self.swap_params(params, version, ticket, variant=variant)
-            except Exception as e:  # pragma: no cover - validated at request
-                if ticket is not None:
-                    ticket.resolve(
-                        False, error=f"{type(e).__name__}: {e}",
-                        stage="apply",
-                    )
         try:
             # tick-wide watchdog guard (nests over the inner prefill/decode
             # guards): a hang ANYWHERE in the tick body — including the
@@ -2351,15 +2413,39 @@ class DecodeEngine:
             self._commit_swap()
         return worked
 
+    def _install_pending_swap(self) -> None:
+        """Take the queued swap, if any, and open its trial window."""
+        with self._swap_lock:
+            pending, self._pending_swap = self._pending_swap, None
+        if pending is None:
+            return
+        params, version, ticket, variant = pending
+        try:
+            self.swap_params(params, version, ticket, variant=variant)
+        except Exception as e:  # pragma: no cover - validated at request
+            if ticket is not None:
+                ticket.resolve(
+                    False, error=f"{type(e).__name__}: {e}",
+                    stage="apply",
+                )
+
     def _tick_body(self) -> bool:
         """One tick as a ``serve_tick`` phase whose children (``expire``,
         ``admit`` and ``prefill`` per admission, ``chunks``, ``operands``,
         ``dispatch``, ``decode_wait``, ``emit``, ``publish``) tile it.
-        Every program a tick puts on the device sits inside a ``prefill``
-        (to the end of its ``prefill_wait``) or between a ``dispatch`` and
-        the end of the ``decode_wait`` after it: the rest is the host's.
-        A busy tick is written out once, after its last phase, as ONE
-        ``serve_tick`` record — where a sink is attached."""
+        A ``prefill`` holds its program to the end of its ``prefill_wait``.
+        A ``dispatch`` only queues step k; the ``decode_wait`` after it is
+        the wait for what is LEFT of step k-1 (the step in flight since the
+        tick before), and ``emit`` hands out step k-1's ids: so dispatch
+        start to that wait's end is "dispatch plus the rest of step k-1",
+        and the host's other phases ran under step k-1. Where the tick
+        retired first (``tick``), ``decode_wait`` and ``emit`` come before
+        the ``dispatch`` (inside ``chunks`` where a prompt's last chunk
+        asked for them) or stand alone. The record's ``overlapped`` is 1
+        where the tick dispatched before it retired, ``discarded`` counts
+        the slot-steps it skipped at retirement. A busy tick is written
+        out once, after its last phase, as ONE ``serve_tick`` record —
+        where a sink is attached."""
         self._tick_phases = []
         with Phase("serve_tick", ident=self.ticks + 1) as tick:
             worked = self._run_tick(tick)
@@ -2387,8 +2473,18 @@ class DecodeEngine:
         worked = False
         admitted0, prefill_tokens0 = self.admitted, self.prefill_tokens
         chunks0, cached0 = self.prefill_chunks, self.prefix_cached_tokens
-        moe_attrs = {}
         live_tokens = 0     # the active slots' contexts at dispatch, summed
+        # what ``_retire`` adds to the tick's record: the slot-steps it
+        # skipped, a routed model's counts; 1 where the tick dispatched
+        # BEFORE it retired the step in flight
+        attrs = {"overlapped": 0, "discarded": 0}
+
+        if self._pending_swap is not None:
+            # the step in flight ran on the OLD weights: retired before
+            # the trial window opens, so its failure is not the new
+            # version's
+            worked = self._retire(attrs)
+            self._install_pending_swap()
 
         with self._phase("expire"):
             for req in self._queue.expire_overdue():
@@ -2417,6 +2513,7 @@ class DecodeEngine:
         # boundary even when cold prefills stay monolithic)
         chunked = self.config.prefill_chunk > 0
         streaming = chunked or self._prefix is not None
+        speculative = self.config.spec_k > 0
         while True:
             req = None
             try:
@@ -2448,6 +2545,9 @@ class DecodeEngine:
                         self._reserve(req, slot)
                         monolithic = True
                 if monolithic:
+                    # the prefill blocks this tick: hand out the step in
+                    # flight first
+                    self._retire(attrs)
                     self._admit(req, slot)
             except Exception:
                 if req is not None:
@@ -2470,17 +2570,28 @@ class DecodeEngine:
         # scatter could reach them)
         with self._phase("chunks"):
             if streaming:
-                worked = self._advance_prefills() or worked
+                worked = self._advance_prefills(attrs) or worked
             active = [
                 i for i, s in enumerate(self._slots)
                 if s is not None and s.phase == "decode"
             ]
-        if active and self.config.spec_k > 0:
+            if not speculative:
+                # a slot whose LAST step is dispatched (the prefill gave
+                # the first of its ``max_new_tokens``) waits for its
+                # retirement and is in no further dispatch: the stop by
+                # length wastes nothing
+                active = [
+                    i for i in active
+                    if 1 + self._slots[i].steps_done
+                    < self._slots[i].request.max_new_tokens
+                ]
+        if active and speculative:
             self._verify_tick(active)
             worked = True
         elif active:
             with self._phase("operands"):
                 S = self.config.num_slots
+                fresh = np.zeros((S,), np.bool_)
                 tokens = np.zeros((S,), np.int32)
                 ctx = np.zeros((S,), np.int32)
                 seeds = np.zeros((S,), np.int32)
@@ -2490,54 +2601,51 @@ class DecodeEngine:
                 for i in active:
                     s = self._slots[i]
                     r = s.request
-                    tokens[i] = s.pending_token
+                    if s.steps_done == s.steps_retired:
+                        # no step of its own in flight: the host has its
+                        # token (the others' is on the device)
+                        fresh[i] = True
+                        tokens[i] = s.pending_token
                     ctx[i] = r.prompt_len + s.steps_done
                     seeds[i] = np.int32(r.seed)
                     steps[i] = s.steps_done + 1   # == len(r.tokens) at sample
                     temps[i] = r.temperature
                     top_ks[i] = min(r.top_k, np.iinfo(np.int32).max)
                 live_tokens = int(ctx.sum())
-                if streaming:
-                    # mid-prefill slots hold real pages but are not in
-                    # this dispatch — null their rows so the decode
-                    # scatter can't stomp a streaming prompt's K/V
-                    bt = np.zeros_like(self._pages.block_table)
-                    for i in active:
-                        bt[i] = self._pages.block_table[i]
-                else:
-                    bt = self._pages.block_table
+                # a COPY: the allocator's table changes under the step in
+                # flight (a retirement frees rows). Slots that hold pages
+                # and are not in this dispatch (mid-prefill; waiting for
+                # their last retirement: context 0 here, which no live
+                # slot has) get null rows, so the decode scatter can't
+                # stomp their K/V
+                bt = self._pages.block_table.copy()
+                bt[ctx == 0] = 0
                 ops = self._put(
-                    (tokens, bt, ctx, seeds, steps, temps, top_ks))
-            with watchdog_guard("serve_decode"):
-                with self._phase("dispatch"):
-                    out, self._cache = self._decode_step_fn()(
-                        self._params, self._cache, *ops
-                    )
-                # the tick's single D2H: [slots] int32 ids
-                with self._phase("decode_wait"):
-                    sampled = jax.device_get(out)
-            with self._phase("emit"):
-                # the device operands and the output are done with: freed
-                # here, inside a phase, not at the tick's return
-                del ops, out
-                if self._routed:
-                    # (ids, (tokens a held expert, pairs to absent ones))
-                    sampled, (held, absent) = sampled
-                    moe_attrs = self._count_routing(held, int(absent))
-                for i in active:
-                    s = self._slots[i]
+                    (fresh, tokens, bt, ctx, seeds, steps, temps, top_ks))
+            with watchdog_guard("serve_decode"), self._phase("dispatch"):
+                out, self._cache = self._decode_step_fn()(
+                    self._params, self._cache, self._prev_ids, *ops
+                )
+                # the operands are the device's now: freed here, inside
+                # a phase, not at the tick's return
+                del ops
+                self._prev_ids = out[0] if self._routed else out
+                pairs = [(i, self._slots[i]) for i in active]
+                for _, s in pairs:
                     s.steps_done += 1
-                    s.request.decode_ticks += 1
-                    token = int(sampled[i])
-                    self._emit_token(s.request, token)
-                    if self._is_terminal(s.request, token):
-                        self._evict(i)      # slot + pages free for reuse
-                    else:
-                        s.pending_token = token
                 self.decode_dispatches += 1
-                self.decode_tokens += len(active)
-                self._registry.gauge("serve/tokens_per_dispatch", 1.0)
+            attrs["overlapped"] = int(self._inflight is not None)
+            self.overlapped_ticks += attrs["overlapped"]
+            # step k is queued behind step k-1: only now fetch, emit and
+            # retire step k-1, under step k
+            self._retire(attrs, then=_InFlight(out, pairs))
+            if self._trial is not None:
+                # a trial tick sees its own ids before the swap commits
+                self._retire(attrs)
             worked = True
+        else:
+            # no slot to dispatch: a tick that only retires has worked
+            worked = self._retire(attrs) or worked
 
         with self._phase("publish") as publish:
             self.ticks += 1
@@ -2548,7 +2656,7 @@ class DecodeEngine:
                 "cached_tokens": self.prefix_cached_tokens - cached0,
                 "chunks": self.prefill_chunks - chunks0,
                 "live_tokens": live_tokens,
-                **moe_attrs,
+                **attrs,
             }
             depth = self._queue.depth()
             self._registry.gauge("serve/queue_depth", depth)
@@ -2650,16 +2758,86 @@ class DecodeEngine:
             self.last_tick_t = time.monotonic()
         return worked
 
+    def _retire(self, attrs: dict, then: Optional[_InFlight] = None) -> bool:
+        """Fetch, emit and retire the decode step in flight, leaving
+        ``then`` (the step just dispatched, or none) in flight; True where
+        there was one. Walks the pairs of ITS dispatch: a pair whose slot
+        was given up since (end-of-text one step late, a deadline) is
+        skipped and counted, its id never emitted. ``attrs``, the tick
+        record's, gains the count and a routed model's counts."""
+        step, self._inflight = self._inflight, then
+        if step is None:
+            return False
+        try:
+            # the tick's single D2H: [slots] int32 ids
+            with watchdog_guard("serve_decode"), self._phase("decode_wait"):
+                sampled = jax.device_get(step.out)
+        except Exception:
+            self._give_back_dispatched()
+            raise
+        with self._phase("emit"):
+            if self._routed:
+                # (ids, (tokens a held expert, pairs to absent ones))
+                sampled, (held, absent) = sampled
+                attrs.update(self._count_routing(held, int(absent)))
+            discarded = 0
+            for i, s in step.pairs:
+                if self._slots[i] is not s:
+                    discarded += 1
+                    continue
+                token = int(sampled[i])
+                s.steps_retired += 1
+                s.request.decode_ticks += 1
+                self._emit_token(s.request, token)
+                if self._is_terminal(s.request, token):
+                    self._evict(i)      # slot + pages free for reuse
+                else:
+                    s.pending_token = token
+            attrs["discarded"] += discarded
+            self.discarded_slot_steps += discarded
+            self.decode_tokens += len(step.pairs) - discarded
+            self._registry.gauge("serve/tokens_per_dispatch", 1.0)
+            # the step's device output is done with: freed here, inside a
+            # phase, not at the return
+            del step
+        return True
+
+    def _give_back_dispatched(self) -> None:
+        """A step's fetch failed: nothing stays in flight (the step after
+        it read its ids) and every slot is as its last retirement left it,
+        ``fresh``, so the next tick dispatches it again from
+        ``pending_token`` into the same positions — after a failed trial
+        tick on the weights rolled back to, as an engine that dispatched
+        and fetched within one tick would. The slot-steps given up are
+        counted as discarded. No slot reads the failed ids, but they must
+        not stay an operand of the next step."""
+        self._inflight = None
+        self._prev_ids = self._put(
+            np.zeros((self.config.num_slots,), np.int32))
+        for s in self._slots:
+            if s is not None:
+                self.discarded_slot_steps += s.steps_done - s.steps_retired
+                s.steps_done = s.steps_retired
+
     # -------------------------------------------------------------- shutdown
 
     def has_work(self) -> bool:
-        return any(s is not None for s in self._slots) or bool(
-            self._queue.depth()
+        """Slots, queued requests, or a decode step whose ids are not out
+        yet: a drain ticks until the last retirement."""
+        return (
+            any(s is not None for s in self._slots)
+            or bool(self._queue.depth())
+            or self._inflight is not None
         )
 
     def cancel_all(self) -> None:
         """Terminate every in-flight and queued request (non-drain shutdown);
-        partial outputs stay on the request."""
+        partial outputs stay on the request. The decode step in flight is
+        dropped whole, unfetched: every one of its slot-steps is counted
+        as discarded."""
+        step, self._inflight = self._inflight, None
+        if step is not None:
+            self.discarded_slot_steps += len(step.pairs)
         for i, s in enumerate(self._slots):
             if s is not None:
                 self._evict(i)
@@ -2741,6 +2919,16 @@ class DecodeEngine:
             "busy_ticks": self.busy_ticks,
             "admitted": self.admitted,
             "finished": self.finished,
+            # plain decode ticks that dispatched step k BEFORE they retired
+            # step k-1, over all of them (None under speculation, whose
+            # ticks are synchronous); slot-steps dispatched and never
+            # emitted (end-of-text found a step late, a deadline, a cancel)
+            "decode_overlap_share": (
+                self.overlapped_ticks / self.decode_dispatches
+                if self.decode_dispatches and self.config.spec_k == 0
+                else None
+            ),
+            "discarded_slot_steps": self.discarded_slot_steps,
             "kv_pool_relayout_ops": self._kv_pool_relayout_ops(),
             # how many of those only stage a pool through on-chip memory
             # and back, in one layout (analysis/spmd/hlo.count_space_moves)

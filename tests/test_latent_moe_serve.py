@@ -126,10 +126,15 @@ def test_every_engine_variant_serves_the_models_own_greedy_tokens(world, engine)
     # the warmed engine audits its decode program: one gather of latent
     # rows a selection group (full, shared | full, shared), not one a layer
     assert stats["latent_row_gathers"] == (2 if engine.get("warmup") else None)
-    ticks = [r for r in records if r.get("record") == "serve_tick"
-             and r.get("decode_active")]
-    assert ticks and all(
-        t["expert_tokens_max"] >= t["expert_tokens_mean"] >= 0 for t in ticks)
+    # a step's routing counts ride the record of the tick that RETIRED it
+    # (one tick after its dispatch, where a step stays in flight)
+    ticks = [r for r in records if r.get("record") == "serve_tick"]
+    dispatched = [t for t in ticks if t["decode_active"]]
+    retired = [t for t in ticks if "expert_tokens_max" in t]
+    assert len(retired) == len(dispatched) == stats["moe"]["steps"] > 0
+    assert all(
+        t["expert_tokens_max"] >= t["expert_tokens_mean"] >= 0
+        for t in retired)
     requests = [r for r in records if r.get("record") == "serve_request"]
     if engine.get("prefix_cache"):
         # 22 shared tokens = 5 whole pages and 2 lanes of a sixth: the

@@ -110,7 +110,10 @@ def _program(engine, name):
     S, W = engine.config.num_slots, engine.config.pages_per_slot
     i32, f32 = np.int32, np.float32
     if name == "decode":
-        ops = (np.zeros((S,), i32), np.zeros((S, W), i32), np.zeros((S,), i32),
+        # (previous ids, fresh mask, tokens, block table, context, seeds,
+        # steps, temperatures, top-ks)
+        ops = (np.zeros((S,), i32), np.ones((S,), np.bool_),
+               np.zeros((S,), i32), np.zeros((S, W), i32), np.zeros((S,), i32),
                np.zeros((S,), i32), np.zeros((S,), i32), np.zeros((S,), f32),
                np.zeros((S,), i32))
         return engine._decode_step_fn(), (engine._params, engine._cache, *ops)
