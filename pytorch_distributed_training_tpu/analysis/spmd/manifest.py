@@ -69,6 +69,10 @@ class CommManifest:
     # counts the program's gathers of such rows under ``sparse_attn.gather``
     # (``latent_row_gathers``: one a selection group in a decode step)
     latent_row: int = 0
+    # values of one cached row of a window group (0: the program has none):
+    # the gathers of such rows under ``window_attn`` (``window_row_gathers``:
+    # one a window group in a decode step)
+    window_row: int = 0
 
     def __post_init__(self):
         for kind in tuple(self.allowed) + tuple(self.required):
@@ -295,6 +299,9 @@ def comm_audit(
     if manifest.latent_row:
         record["latent_row_gathers"] = count_row_gathers(
             text, "sparse_attn.gather", manifest.latent_row)
+    if manifest.window_row:
+        record["window_row_gathers"] = count_row_gathers(
+            text, "window_attn", manifest.window_row)
     registry.emit(record)
     if manifest.trace_scopes:
         registry.emit({

@@ -28,8 +28,10 @@ with ``scripts/summarize_metrics.py``.
 
 Latent expert presets (``--model glm-5.2-share16``: latent attention with
 a learned sparse indexer, routed experts of which this chip holds a share;
-``models/latent_moe.py``) serve through the same server, tick, allocator,
-prefix cache and sampling. They need, at the published widths,
+``--model dots3-note-share8``: full layers that each choose with their own
+indexer beside window layers of another latent width, headwise attention
+gates; ``models/latent_moe.py``) serve through the same server, tick,
+allocator, prefix cache and sampling. They need, at the published widths,
 ``--weights-dtype bfloat16``; ``--prefill-chunk`` and ``--prefix-cache``
 work over their pools. They refuse, at start-up and by the flag's name:
 ``--tp``, ``--spec-k``, ``--weights-dtype int8`` and ``--kv-dtype int8``.

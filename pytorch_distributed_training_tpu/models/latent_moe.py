@@ -17,6 +17,15 @@ The block the GPT-2 family lacks, by mechanism:
   layer typed ``shared`` has no indexer weights and no indexer pool: it is
   handed the selection of the nearest ``full`` layer before it, a value
   passed from layer to layer inside the compiled step.
+- Window layers (typed ``window``): latent attention of their own sizes
+  (the ``swa_*`` fields: heads, low ranks, head dims, rotary base) over the
+  latest ``sliding_window_size`` positions, the query's own included, and
+  no indexer. A run of them is a selection group whose selection is the
+  window itself.
+- An optional headwise gate on the attention output (``attention_gate_type``
+  / ``swa_attention_gate_type`` ``"headwise"``): ``o_h <- sigmoid(x . g_h)
+  o_h`` before the output projection, ``x`` the layer's normed input
+  (arXiv:2505.06708).
 - Expert layers (``ops/moe.py``) that are told which experts they hold:
   the router scores all ``n_routed_experts``, the chip computes its own
   experts' part plus the shared expert.
@@ -30,18 +39,24 @@ cache node ``latents_<g>``): layer ``j`` of the group owns columns ``[j *
 640, (j + 1) * 640)``, a lane-dense row ``[c_kv 512 | k_rope 64 | 0]``.
 The ``full`` layers keep an ``index_pages`` pool ``[pages, page_size,
 128]`` of their own; one block table addresses all, so a prefix-cache hit
-maps them together and copy-on-write copies all. Every step writes first
-and reads after. The decode step (one token a sequence, seen from the
-input's shape) gathers a group's chosen rows ONCE, in its choosing layer,
-and hands them on with the ``Selection``; a bucket prefill and a prefill
-chunk gather a layer's own columns layer by layer, because their tokens
-attend to rows their own chunk writes.
+maps them together and copy-on-write copies all. A window group's pool has
+rows of its own width (``window_row``: its latent plus rotary key, lane
+padded) under the same block table: its rows live in pages, so a prefix
+hit at any page boundary hands a window layer its last window of rows with
+nothing to snapshot. Every step writes first and reads after. The decode
+step (one token a sequence, seen from the input's shape) gathers a group's
+chosen rows ONCE, in its first layer, and hands them on with the
+``Selection``; a bucket prefill and a prefill chunk gather a layer's own
+columns layer by layer, because their tokens attend to rows their own
+chunk writes (a window layer: the contiguous span its block of queries
+reaches, attended expanded under the band mask).
 """
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
-from typing import Any, Optional
+from typing import Any, NamedTuple, Optional
 
 import flax.linen as nn
 import jax
@@ -49,6 +64,9 @@ import jax.numpy as jnp
 
 from pytorch_distributed_training_tpu.ops import latent_attention as la
 from pytorch_distributed_training_tpu.ops import moe
+
+#: the scope of a window group's look-up, gather, attention and gate
+WINDOW_SCOPE = "window_attn"
 
 
 @dataclasses.dataclass
@@ -74,11 +92,28 @@ class LatentMoEConfig:
     index_head_dim: int
     index_topk: int
     mlp_layer_types: tuple          # "dense" | "sparse", one a layer
-    indexer_types: tuple            # "full" | "shared", one a layer
+    # "full" | "shared" | "window", one a layer: a full layer chooses with
+    # its indexer, a shared one takes the selection of the full layer
+    # before it, a window layer attends to its latest sliding_window_size
+    # positions with the swa_* sizes
+    indexer_types: tuple
     max_position_embeddings: int
     rope_theta: float = 10000.0
     rms_norm_eps: float = 1e-5
     index_norm_eps: float = 1e-6
+    # "none" | "headwise": a sigmoid gate a head on the attention output of
+    # the full and shared layers, and of the window layers
+    attention_gate_type: str = "none"
+    swa_attention_gate_type: str = "none"
+    # the window layers' own sizes (unused without a "window" layer)
+    sliding_window_size: int = 0
+    swa_num_attention_heads: int = 0
+    swa_q_lora_rank: int = 0
+    swa_kv_lora_rank: int = 0
+    swa_qk_nope_head_dim: int = 0
+    swa_qk_rope_head_dim: int = 0
+    swa_v_head_dim: int = 0
+    swa_rope_theta: float = 10000.0
     # the experts THIS chip holds: (first, count) of the routed experts
     experts_held: tuple = (0, 0)
     # held experts stacked to one parameter leaf
@@ -108,10 +143,20 @@ class LatentMoEConfig:
         self.experts_held = tuple(int(v) for v in self.experts_held)
         if len(self.mlp_layer_types) != len(self.indexer_types):
             raise ValueError("mlp_layer_types and indexer_types differ in length")
-        if self.indexer_types[0] != "full":
-            raise ValueError(
-                "the first layer must be typed 'full': a 'shared' layer "
-                "takes its selection from a 'full' layer before it")
+        for i, kind in enumerate(self.indexer_types):
+            before = self.indexer_types[i - 1] if i else None
+            if kind not in ("full", "shared", "window"):
+                raise ValueError(f"unknown indexer type {kind!r}")
+            if kind == "shared" and before not in ("full", "shared"):
+                raise ValueError(
+                    f"layer {i} is typed 'shared' and follows {before!r}: a "
+                    "'shared' layer takes its selection from a 'full' layer "
+                    "before it")
+        if "window" in self.indexer_types and self.sliding_window_size < 1:
+            raise ValueError("window layers need sliding_window_size >= 1")
+        for gate in (self.attention_gate_type, self.swa_attention_gate_type):
+            if gate not in ("none", "headwise"):
+                raise ValueError(f"unknown attention gate type {gate!r}")
         first, held = self.experts_held
         if "sparse" in self.mlp_layer_types:
             if held < 1 or first < 0 or first + held > self.n_routed_experts:
@@ -133,25 +178,67 @@ class LatentMoEConfig:
     def selection_groups(self) -> tuple:
         """(group, place in it) of every layer, and the groups' sizes: a
         group is a ``full`` layer and the ``shared`` ones that reuse its
-        selection."""
+        selection, or a run of ``window`` layers."""
         places, sizes = [], []
+        before = None
         for kind in self.indexer_types:
-            if kind == "full":
+            if kind == "full" or (kind == "window" and before != "window"):
                 sizes.append(0)
             places.append((len(sizes) - 1, sizes[-1]))
             sizes[-1] += 1
+            before = kind
         return tuple(places), tuple(sizes)
 
     @property
+    def group_windows(self) -> tuple:
+        """Whether each selection group is a run of window layers."""
+        places, sizes = self.selection_groups
+        first = [self.indexer_types[places.index((g, 0))]
+                 for g in range(len(sizes))]
+        return tuple(kind == "window" for kind in first)
+
+    def attention_sizes(self, window: bool) -> AttentionSizes:
+        """The sizes of a window layer's attention, or of a full or
+        shared layer's."""
+        if window:
+            return AttentionSizes(
+                self.swa_num_attention_heads, self.swa_q_lora_rank,
+                self.swa_kv_lora_rank, self.swa_qk_nope_head_dim,
+                self.swa_qk_rope_head_dim, self.swa_v_head_dim,
+                self.swa_rope_theta, self.swa_attention_gate_type)
+        return AttentionSizes(
+            self.num_attention_heads, self.q_lora_rank, self.kv_lora_rank,
+            self.qk_nope_head_dim, self.qk_rope_head_dim, self.v_head_dim,
+            self.rope_theta, self.attention_gate_type)
+
+    @property
     def latent_row(self) -> int:
-        """Values of one cached latent row: latent plus rotary key, padded
-        to whole lane tiles."""
+        """Values of one cached latent row of a full or shared layer:
+        latent plus rotary key, padded to whole lane tiles."""
         return la.lane_pad(self.kv_lora_rank + self.qk_rope_head_dim)
+
+    @property
+    def window_row(self) -> int:
+        """Values of one cached latent row of a window layer (0 where the
+        model has none)."""
+        if "window" not in self.indexer_types:
+            return 0
+        return la.lane_pad(self.swa_kv_lora_rank + self.swa_qk_rope_head_dim)
+
+    @property
+    def window_rows_per_slot(self) -> Optional[int]:
+        """Rows a decode step reads a sequence for its window group (None
+        where the model has no window layer)."""
+        if "window" not in self.indexer_types:
+            return None
+        return self.sliding_window_size
 
     def cache_values_per_token(self) -> int:
         """Resident pool values one token occupies over all layers."""
         full = sum(1 for t in self.indexer_types if t == "full")
-        return self.num_layers * self.latent_row + full * self.index_head_dim
+        window = sum(1 for t in self.indexer_types if t == "window")
+        return ((self.num_layers - window) * self.latent_row
+                + window * self.window_row + full * self.index_head_dim)
 
     def check_serving(self, engine) -> None:
         """Refuse, by the flag's name, what this family's serving path does
@@ -173,6 +260,20 @@ class LatentMoEConfig:
                 "pass --weights-dtype bfloat16")
         if getattr(engine, "kv_dtype", "float32") == "int8":
             bad("--kv-dtype int8", "the latent pools have no scale pools")
+
+
+class AttentionSizes(NamedTuple):
+    """One kind of layer's attention sizes (``LatentMoEConfig.
+    attention_sizes``)."""
+
+    heads: int
+    q_lora_rank: int
+    kv_lora_rank: int
+    nope: int
+    rope: int
+    v: int
+    theta: float
+    gate: str
 
 
 def _cdt(cfg):
@@ -278,13 +379,15 @@ class ExpertLayer(nn.Module):
 
 class LatentPool(nn.Module):
     """One selection group's latent rows: the cache variables
-    ``latent_pages`` [pages, page_size, layers * latent_row], the group's
-    layers side by side in a token's row, and the block table every pool
-    of the group is read through. Made by the model, which hands the two
-    variables to the group's layers."""
+    ``latent_pages`` [pages, page_size, layers * row] (``row``: the
+    ``latent_row``, or a window group's ``window_row``), the group's layers
+    side by side in a token's row, and the block table every pool of the
+    group is read through. Made by the model, which hands the two variables
+    to the group's layers."""
 
     config: LatentMoEConfig
     layers: int
+    window: bool = False
 
     @nn.compact
     def __call__(self):
@@ -293,8 +396,8 @@ class LatentPool(nn.Module):
             raise ValueError(
                 "paged serving needs kv_num_pages >= 2 (page 0 is the "
                 f"reserved null page), got {cfg.kv_num_pages}")
-        shape = (cfg.kv_num_pages, cfg.kv_page_size,
-                 self.layers * cfg.latent_row)
+        row = cfg.window_row if self.window else cfg.latent_row
+        shape = (cfg.kv_num_pages, cfg.kv_page_size, self.layers * row)
         pages = self.variable(
             "cache", "latent_pages", lambda: jnp.zeros(shape, _cdt(cfg)))
         # a placeholder: the engine supplies it per call (with_tables)
@@ -305,25 +408,29 @@ class LatentPool(nn.Module):
 
 class LatentAttention(nn.Module):
     """MLA with an optional indexer (``indexer=True``: a layer typed
-    ``full``) at ``place`` in its selection group, whose ``pool`` (the
-    variables of a ``LatentPool``) it is handed when serving. Returns
-    (output, selection): the selection is this layer's own where it has an
-    indexer, the one it was handed otherwise."""
+    ``full``), or over a window (``window=True``: a layer typed
+    ``window``, of its own sizes), at ``place`` in its selection group,
+    whose ``pool`` (the variables of a ``LatentPool``) it is handed when
+    serving. Returns (output, selection): the selection is this layer's own
+    where it has an indexer or is the first of a window group, the one it
+    was handed otherwise."""
 
     config: LatentMoEConfig
     indexer: bool
     place: int = 0
+    window: bool = False
 
     @nn.compact
     def __call__(self, x, positions, selection, pool=None):
         cfg = self.config
         dt = _cdt(cfg)
-        h, heads = cfg.hidden_size, cfg.num_attention_heads
-        dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
-        rank = cfg.kv_lora_rank
+        sizes = cfg.attention_sizes(self.window)
+        h, heads = cfg.hidden_size, sizes.heads
+        dn, dr, dv = sizes.nope, sizes.rope, sizes.v
+        rank = sizes.kv_lora_rank
         init, pdt = _init(cfg), _pdt(cfg)
-        q_a = self.param("q_a", init, (h, cfg.q_lora_rank), pdt)
-        q_b = self.param("q_b", init, (cfg.q_lora_rank, heads, dn + dr), pdt)
+        q_a = self.param("q_a", init, (h, sizes.q_lora_rank), pdt)
+        q_b = self.param("q_b", init, (sizes.q_lora_rank, heads, dn + dr), pdt)
         # the latent and the rotary key are two projections of x (one
         # leaf each: they are drawn, loaded and sharded apart)
         kv_a_latent = self.param("kv_a_latent", init, (h, rank), pdt)
@@ -331,13 +438,16 @@ class LatentAttention(nn.Module):
         kv_b_k = self.param("kv_b_k", init, (rank, heads, dn), pdt)
         kv_b_v = self.param("kv_b_v", init, (rank, heads, dv), pdt)
         o_w = self.param("o", init, (heads, dv, h), pdt)
+        gate = None
+        if sizes.gate == "headwise":
+            gate = self.param("gate", init, (h, heads), pdt)
 
         cq = RMSNorm(cfg.rms_norm_eps, pdt, name="q_a_norm")(
             _mm(x, q_a).astype(dt))
         q = _mm(cq, q_b).astype(dt)                       # [b, t, heads, dn+dr]
         ckv = RMSNorm(cfg.rms_norm_eps, pdt, name="kv_a_norm")(
             _mm(x, kv_a_latent).astype(dt))
-        cos, sin = la.rope_angles(positions, dr, cfg.rope_theta)
+        cos, sin = la.rope_angles(positions, dr, sizes.theta)
         k_rope = la.apply_rope(_mm(x, kv_a_rope).astype(dt), cos, sin)
         q_nope = q[..., :dn]
         q_rope = la.apply_rope(q[..., dn:], cos[:, :, None], sin[:, :, None])
@@ -346,7 +456,7 @@ class LatentAttention(nn.Module):
         qi = ki = wi = None
         if self.indexer:
             ih, idim = cfg.index_n_heads, cfg.index_head_dim
-            iq_w = self.param("index_q", init, (cfg.q_lora_rank, ih, idim), pdt)
+            iq_w = self.param("index_q", init, (sizes.q_lora_rank, ih, idim), pdt)
             ik_w = self.param("index_k", init, (h, idim), pdt)
             iw_w = self.param("index_w", init, (h, ih), pdt)
             qi = _mm(cq, iq_w).astype(dt)
@@ -366,9 +476,14 @@ class LatentAttention(nn.Module):
             # the decode step, a prefill chunk and a bucket prefill alike
             # (a bucket is one chunk at context 0)
             row, index = self._write(pool, ckv, k_rope, ki, positions)
-            ctx, selection = self._paged(
-                pool, index, row, q_nope, q_rope, qi, wi, positions,
-                selection, kv_b_k, kv_b_v, scale)
+            if self.window:
+                ctx, selection = self._window_paged(
+                    pool, row, q_nope, q_rope, positions, selection,
+                    kv_b_k, kv_b_v, scale)
+            else:
+                ctx, selection = self._paged(
+                    pool, index, row, q_nope, q_rope, qi, wi, positions,
+                    selection, kv_b_k, kv_b_v, scale)
         else:
             # no cache (training, evaluation, the tests' comparisons, and
             # declaring the cache's shapes): the sequence's own latents are
@@ -378,6 +493,12 @@ class LatentAttention(nn.Module):
                 selection, kv_b_k, kv_b_v, scale)
             if cfg.decode:
                 self._index_pool()   # initializing: declare the cache's shapes
+        if gate is not None:
+            # the headwise gate, from the layer's normed input; a window
+            # layer's under its attention's scope
+            with (jax.named_scope(WINDOW_SCOPE) if self.window
+                  else contextlib.nullcontext()):
+                ctx = ctx * jax.nn.sigmoid(_mm(x, gate))[..., None]
         out = jnp.einsum("bqhv,hvd->bqd", ctx.astype(dt), o_w.astype(dt),
                          preferred_element_type=jnp.float32)
         if self.is_mutable_collection("selection"):
@@ -393,20 +514,33 @@ class LatentAttention(nn.Module):
         blocked over queries (the causal square is whole): for sequences
         of the tests' and a trainer's lengths, never the server's."""
         cfg = self.config
-        dt = ckv.dtype
         seq = ckv.shape[1]
+        if self.window:
+            return self._expanded(
+                q_nope, q_rope, ckv, k_rope, kv_b_k, kv_b_v,
+                la.window_mask(positions, positions, cfg.sliding_window_size),
+                scale), la.window_selection(positions, cfg.sliding_window_size)
         if self.indexer:
             selection = la.select_topk(
                 la.fresh_index_scores(qi, wi, ki, positions), cfg.index_topk,
                 positions)
+        ctx = self._expanded(
+            q_nope, q_rope, ckv, k_rope, kv_b_k, kv_b_v,
+            la.select_mask(selection, seq), scale)
+        return ctx, selection
+
+    def _expanded(self, q_nope, q_rope, ckv, k_rope, kv_b_k, kv_b_v, mask,
+                  scale):
+        """Per-head keys and values from latents ``ckv`` [b, s, rank] and
+        attention under ``mask`` [b, q, s]."""
+        dt = ckv.dtype
         k_nope = jnp.einsum("bsc,chd->bshd", ckv, kv_b_k.astype(dt),
                             preferred_element_type=jnp.float32).astype(dt)
         v = jnp.einsum("bsc,chv->bshv", ckv, kv_b_v.astype(dt),
                        preferred_element_type=jnp.float32).astype(dt)
-        ctx = la.expanded_attention(
-            q_nope, q_rope, k_nope, k_rope, v,
-            la.select_mask(selection, seq), scale)
-        return ctx, selection
+        return la.expanded_attention(
+            q_nope, q_rope, k_nope, k_rope, v, mask, scale,
+            **({"scope": WINDOW_SCOPE} if self.window else {}))
 
     # ---------------------------------------------------------- paged path
 
@@ -426,20 +560,92 @@ class LatentAttention(nn.Module):
         latent row ``[c_kv | k_rope | 0]`` into this layer's columns of
         the group's pool and, with an indexer, its key. Returns the rows
         and the indexer pool."""
-        cfg = self.config
         dt = ckv.dtype
         index = self._index_pool()
         batch, chunk = positions.shape
-        pad = cfg.latent_row - cfg.kv_lora_rank - cfg.qk_rope_head_dim
+        width = self._row()
+        pad = width - ckv.shape[-1] - k_rope.shape[-1]
         rows = jnp.concatenate(
             [ckv, k_rope, jnp.zeros((batch, chunk, pad), dt)], axis=-1)
         latent, bt = pool
         latent.value = la.write_rows(
-            latent.value, bt.value, positions, rows,
-            self.place * cfg.latent_row)
+            latent.value, bt.value, positions, rows, self.place * width)
         if self.indexer:
             index.value = la.write_rows(index.value, bt.value, positions, ki)
         return rows, index
+
+    def _row(self) -> int:
+        cfg = self.config
+        return cfg.window_row if self.window else cfg.latent_row
+
+    def _absorbed_query(self, q_nope, q_rope, kv_b_k):
+        """The query with the key up-projection absorbed, laid out as a
+        pool row: ``[q_nope . W_uk | q_rope | 0]``."""
+        dt = q_nope.dtype
+        batch, chunk, heads = q_rope.shape[:3]
+        pad = self._row() - kv_b_k.shape[0] - q_rope.shape[-1]
+        q_lat = jnp.einsum("bqhd,chd->bqhc", q_nope, kv_b_k.astype(dt),
+                           preferred_element_type=jnp.float32).astype(dt)
+        return jnp.concatenate([
+            q_lat, q_rope, jnp.zeros((batch, chunk, heads, pad), dt)], axis=-1)
+
+    def _values(self, ctx, kv_b_v):
+        """The probability-weighted latent ``ctx`` [b, q, heads, row], up
+        to per-head values [b, q, heads, v] (float32)."""
+        dt = _cdt(self.config)
+        return jnp.einsum(
+            "bqhc,chv->bqhv", ctx[..., :kv_b_v.shape[0]].astype(dt),
+            kv_b_v.astype(dt), preferred_element_type=jnp.float32)
+
+    def _window_paged(self, pool, row, q_nope, q_rope, positions, selection,
+                      kv_b_k, kv_b_v, scale):
+        """A window layer through the block table, after the step's own
+        rows are written. The decode step: the group's first layer looks
+        up and gathers the window's rows of the whole group ONCE (one wide
+        row a position) and every layer attends in the latent (ABSORB),
+        the later layers with the row they have just written in place of
+        the gathered one. A multi-token step: each block of queries fetches
+        the span it reaches, this layer's columns of it, and attends
+        EXPANDED under the band mask."""
+        cfg = self.config
+        window = cfg.sliding_window_size
+        latent, bt = pool
+        chunk = positions.shape[1]
+        width, rank = self._row(), kv_b_k.shape[0]
+        column = self.place * width
+        scope = {"scope": WINDOW_SCOPE}
+        if chunk == 1:
+            q_row = self._absorbed_query(q_nope, q_rope, kv_b_k)
+            if self.place == 0:
+                selection = la.look_up_rows(
+                    la.window_selection(positions, window), bt.value,
+                    cfg.kv_page_size, **scope)
+                selection = selection._replace(
+                    group_rows=la.gather_rows(latent.value, selection, **scope))
+                rows = la.group_slice(selection, column, width, **scope)
+            else:
+                rows = la.group_slice(
+                    selection, column, width, row, positions, **scope)
+            ctx = la.latent_attention(
+                q_row, rows, selection.valid, scale, **scope)
+            return self._values(ctx, kv_b_v), selection
+
+        def block(q_nope, q_rope, positions):
+            span = la.window_span(positions, window)
+            sel = la.look_up_rows(
+                la.Selection(jnp.maximum(span, 0)[:, None], (span >= 0)[:, None]),
+                bt.value, cfg.kv_page_size, **scope)
+            kept = la.gather_rows(latent.value, sel, **scope)[
+                :, 0, :, column:column + width]
+            dr = q_rope.shape[-1]
+            return self._expanded(
+                q_nope, q_rope, kept[..., :rank],
+                kept[..., rank:rank + dr], kv_b_k, kv_b_v,
+                la.window_mask(positions, span, window), scale)
+
+        ctx = _in_query_blocks(
+            block, (q_nope, q_rope, positions), cfg.attention_query_block)
+        return ctx, la.window_selection(positions, window)
 
     def _paged(self, pool, index, row, q_nope, q_rope, qi, wi, positions,
                selection, kv_b_k, kv_b_v, scale):
@@ -449,23 +655,13 @@ class LatentAttention(nn.Module):
         choosing layer; a prefill chunk at a nonzero context and a bucket
         prefill at context 0 gather this layer's columns."""
         cfg = self.config
-        dt = q_nope.dtype
         latent, bt = pool
-        batch, chunk = positions.shape
+        chunk = positions.shape[1]
         column = self.place * cfg.latent_row
-        pad = cfg.latent_row - cfg.kv_lora_rank - cfg.qk_rope_head_dim
-        # the absorbed query, laid out as a pool row
-        q_lat = jnp.einsum("bqhd,chd->bqhc", q_nope, kv_b_k.astype(dt),
-                           preferred_element_type=jnp.float32).astype(dt)
-        q_row = jnp.concatenate([
-            q_lat, q_rope,
-            jnp.zeros((batch, chunk, q_rope.shape[2], pad), dt)], axis=-1)
+        q_row = self._absorbed_query(q_nope, q_rope, kv_b_k)
 
         def values(ctx):
-            # the probability-weighted latent, up to per-head values
-            return jnp.einsum(
-                "bqhc,chv->bqhv", ctx[..., :cfg.kv_lora_rank].astype(dt),
-                kv_b_v.astype(dt), preferred_element_type=jnp.float32)
+            return self._values(ctx, kv_b_v)
 
         def select(qi, wi, positions):
             return la.look_up_rows(la.select_topk(
@@ -498,31 +694,37 @@ class LatentAttention(nn.Module):
             ctx = la.latent_attention(q_row, rows, selection.valid, scale)
             return values(ctx), selection
 
-        qb = cfg.attention_query_block
-        if chunk <= qb:
-            return block(q_row, qi, wi, positions, selection)
-        # a prefill, in blocks of queries: the indexer's [queries, heads,
-        # context] float32 scores and the gathered rows are bounded by the
-        # block, not by the chunk or the bucket. A last block that is not
-        # whole is filled with copies of the last query and cut off again.
-        n = -(-chunk // qb)
+        # the indexer's [queries, heads, context] float32 scores and the
+        # gathered rows are bounded by the block, not by the chunk or the
+        # bucket
+        return _in_query_blocks(
+            block, (q_row, qi, wi, positions,
+                    None if self.indexer else selection),
+            cfg.attention_query_block)
 
-        def split(t):
-            if t is None:
-                return None
-            t = jnp.pad(t, [(0, 0), (0, n * qb - chunk)]
-                        + [(0, 0)] * (t.ndim - 2), mode="edge")
-            return jnp.moveaxis(t.reshape(batch, n, qb, *t.shape[2:]), 1, 0)
 
-        def merge(t):
-            return jnp.moveaxis(t, 0, 1).reshape(
-                batch, n * qb, *t.shape[3:])[:, :chunk]
+def _in_query_blocks(fn, args, block: int):
+    """``fn(*args)`` over blocks of ``block`` queries: every array of
+    ``args`` (a tree; None passes) has the queries on axis 1, and so has
+    every array ``fn`` returns. One call where the queries fit one block;
+    else a ``lax.map`` over blocks, a last block that is not whole filled
+    with copies of the last query and cut off again."""
+    batch, chunk = jax.tree.leaves(args)[0].shape[:2]
+    if chunk <= block:
+        return fn(*args)
+    n = -(-chunk // block)
 
-        ctx, sel = jax.lax.map(
-            lambda a: block(*a),
-            (split(q_row), split(qi), split(wi), split(positions),
-             None if self.indexer else jax.tree.map(split, selection)))
-        return merge(ctx), jax.tree.map(merge, sel)
+    def split(t):
+        t = jnp.pad(t, [(0, 0), (0, n * block - chunk)]
+                    + [(0, 0)] * (t.ndim - 2), mode="edge")
+        return jnp.moveaxis(t.reshape(batch, n, block, *t.shape[2:]), 1, 0)
+
+    def merge(t):
+        return jnp.moveaxis(t, 0, 1).reshape(
+            batch, n * block, *t.shape[3:])[:, :chunk]
+
+    out = jax.lax.map(lambda a: fn(*a), jax.tree.map(split, args))
+    return jax.tree.map(merge, out)
 
 
 class DecoderLayer(nn.Module):
@@ -530,6 +732,7 @@ class DecoderLayer(nn.Module):
     sparse: bool
     indexer: bool
     place: int = 0
+    window: bool = False
 
     @nn.compact
     def __call__(self, x, positions, selection, token_mask, pool=None):
@@ -537,7 +740,7 @@ class DecoderLayer(nn.Module):
         pdt = _pdt(cfg)
         h = RMSNorm(cfg.rms_norm_eps, pdt, name="attention_norm")(x)
         a, selection = LatentAttention(
-            cfg, self.indexer, self.place, name="attention")(
+            cfg, self.indexer, self.place, self.window, name="attention")(
             h, positions, selection, pool)
         x = x + a
         h = RMSNorm(cfg.rms_norm_eps, pdt, name="mlp_norm")(x)
@@ -556,12 +759,17 @@ class LatentMoELM(nn.Module):
     marks the tokens the routing counts take (all where None)."""
 
     config: LatentMoEConfig
-    #: the ``jax.named_scope`` names a device trace is read by
-    #: (``analysis/spmd/hlo.scope_instructions``; ops/latent_attention.py,
-    #: ops/moe.py)
-    trace_scopes = (
-        "sparse_attn.index_scores", "sparse_attn.topk", "sparse_attn.gather",
-        "sparse_attn.attend", "moe")
+
+    @property
+    def trace_scopes(self) -> tuple:
+        """The ``jax.named_scope`` names a device trace is read by
+        (``analysis/spmd/hlo.scope_instructions``; ops/latent_attention.py,
+        ops/moe.py), ``window_attn`` where the model has window layers."""
+        scopes = ("sparse_attn.index_scores", "sparse_attn.topk",
+                  "sparse_attn.gather", "sparse_attn.attend", "moe")
+        if "window" in self.config.indexer_types:
+            scopes += (WINDOW_SCOPE,)
+        return scopes
 
     @nn.compact
     def __call__(self, input_ids, attention_mask=None, token_type_ids=None,
@@ -585,13 +793,14 @@ class LatentMoELM(nn.Module):
         x = embed[input_ids].astype(dt)
         selection: Optional[la.Selection] = None
         places, sizes = cfg.selection_groups
-        pools = [LatentPool(cfg, size, name=f"latents_{g}")()
-                 for g, size in enumerate(sizes)] if cfg.decode else None
+        pools = [LatentPool(cfg, size, window, name=f"latents_{g}")()
+                 for g, (size, window) in enumerate(
+                     zip(sizes, cfg.group_windows))] if cfg.decode else None
         for i, (mlp, idx) in enumerate(
                 zip(cfg.mlp_layer_types, cfg.indexer_types)):
             group, place = places[i]
             x, selection = DecoderLayer(
-                cfg, mlp == "sparse", idx == "full", place,
+                cfg, mlp == "sparse", idx == "full", place, idx == "window",
                 name=f"layer_{i}")(
                 x, position_ids, selection, token_mask,
                 pools[group] if pools else None)
@@ -619,6 +828,29 @@ PRESETS: dict[str, dict[str, Any]] = {
         max_position_embeddings=1048576, rope_theta=8e6, rms_norm_eps=1e-5,
         experts_held=(0, 16), expert_block=8,
     ),
+    # https://huggingface.co/dots-studio/dots3-note-prev/blob/main/config.json,
+    # cut as benchmarks/configs/dots3_share8.json states: 8 chips share each
+    # layer; the published layers 0..4 of 46 (full/dense, full, window x3:
+    # the leading dense layer and one whole period), experts 0..31 of 256,
+    # an eighth of the vocabulary; no vision or audio tower, no MTP block
+    "dots3-note-share8": dict(
+        vocab_size=19008, hidden_size=5120, num_attention_heads=128,
+        q_lora_rank=1024, kv_lora_rank=512, qk_nope_head_dim=128,
+        qk_rope_head_dim=64, v_head_dim=128, intermediate_size=13824,
+        moe_intermediate_size=1536, n_routed_experts=256,
+        num_experts_per_tok=8, n_shared_experts=1,
+        routed_scaling_factor=1.0, index_n_heads=64, index_head_dim=128,
+        index_topk=2048,
+        mlp_layer_types=("dense",) + ("sparse",) * 4,
+        indexer_types=("full", "full", "window", "window", "window"),
+        max_position_embeddings=524288, rope_theta=8e7, rms_norm_eps=1e-5,
+        attention_gate_type="headwise", swa_attention_gate_type="headwise",
+        sliding_window_size=513, swa_num_attention_heads=64,
+        swa_q_lora_rank=1024, swa_kv_lora_rank=1024,
+        swa_qk_nope_head_dim=192, swa_qk_rope_head_dim=64, swa_v_head_dim=128,
+        swa_rope_theta=5e4,
+        experts_held=(0, 32), expert_block=8,
+    ),
     # the CPU tests' size: every mechanism, contexts past index_topk
     "latent-moe-tiny": dict(
         vocab_size=512, hidden_size=64, num_attention_heads=4,
@@ -631,6 +863,29 @@ PRESETS: dict[str, dict[str, Any]] = {
         mlp_layer_types=("dense", "sparse", "sparse", "sparse"),
         indexer_types=("full", "shared", "full", "shared"),
         max_position_embeddings=4096, rope_theta=8e6, rms_norm_eps=1e-5,
+        experts_held=(0, 2), expert_block=1, moe_dense_tokens=4,
+        attention_query_block=4, compute_dtype="float32",
+        param_dtype="float32",
+    ),
+    # the CPU tests' size of dots3-note-share8's pattern: two latent widths
+    # (rows of 128 and 256 lanes), two head counts, gates, a window of 9
+    # (2 pages of 4 and the query, as 513 is 32 pages of 16 and the query);
+    # contexts run past the window and past index_topk
+    "dots3-tiny": dict(
+        vocab_size=512, hidden_size=64, num_attention_heads=4,
+        q_lora_rank=32, kv_lora_rank=32, qk_nope_head_dim=16,
+        qk_rope_head_dim=8, v_head_dim=16, intermediate_size=128,
+        moe_intermediate_size=32, n_routed_experts=8,
+        num_experts_per_tok=2, n_shared_experts=1,
+        routed_scaling_factor=1.0, index_n_heads=4, index_head_dim=16,
+        index_topk=8,
+        mlp_layer_types=("dense",) + ("sparse",) * 4,
+        indexer_types=("full", "full", "window", "window", "window"),
+        max_position_embeddings=4096, rope_theta=8e7, rms_norm_eps=1e-5,
+        attention_gate_type="headwise", swa_attention_gate_type="headwise",
+        sliding_window_size=9, swa_num_attention_heads=2,
+        swa_q_lora_rank=32, swa_kv_lora_rank=128, swa_qk_nope_head_dim=24,
+        swa_qk_rope_head_dim=8, swa_v_head_dim=16, swa_rope_theta=5e4,
         experts_held=(0, 2), expert_block=1, moe_dense_tokens=4,
         attention_query_block=4, compute_dtype="float32",
         param_dtype="float32",

@@ -25,6 +25,16 @@ The chosen positions are a VALUE (``Selection``): a layer without an
 indexer is handed the selection of the nearest choosing layer before it,
 inside the one compiled step.
 
+A WINDOW layer chooses nothing: its selection is the latest ``window``
+positions up to the query's own (``window_selection``), and a run of window
+layers is a group like any other, its rows side by side in one pool and
+gathered once a decode step. The helpers take the scope their work runs
+under: a window group's look-up, gather and attention run under
+``window_attn``, the choosing layers' under the ``sparse_attn.*`` names
+above. A multi-token step (a prefill chunk, a bucket prefill) of a window
+layer fetches the contiguous span its queries reach (``window_span``) and
+attends EXPANDED under the band mask (``window_mask``).
+
 A forward without a cache (training, evaluation, the tests' comparisons)
 takes the EXPANDED path instead: per-head keys and values are made from
 the sequence's own latents and the selection is a mask over the causal
@@ -228,11 +238,40 @@ def select_topk(scores, k: int, q_positions) -> Selection:
         return Selection(positions, positions <= q_positions[..., None])
 
 
-def look_up_rows(sel: Selection, block_table, page_size: int) -> Selection:
+def window_selection(q_positions, window: int) -> Selection:
+    """The latest ``window`` positions up to and including each query's own
+    (``q_positions`` [batch, q]), ascending: [batch, q, window]. Positions
+    before the sequence's start are not valid (and read position 0)."""
+    back = jnp.arange(1 - window, 1, dtype=jnp.int32)
+    positions = q_positions[..., None] + back
+    return Selection(jnp.maximum(positions, 0), positions >= 0)
+
+
+def window_mask(q_positions, k_positions, window: int):
+    """[batch, q, s]: key position ``k_positions`` [batch, s] lies in the
+    window of query position ``q_positions`` [batch, q]: at most ``window
+    - 1`` before it, not after it, not before the sequence's start."""
+    q = q_positions[:, :, None]
+    k = k_positions[:, None, :]
+    return (k <= q) & (k > q - window) & (k >= 0)
+
+
+def window_span(q_positions, window: int):
+    """The positions [batch, q + window - 1] a block of consecutive queries
+    ``q_positions`` [batch, q] reaches back to in a window of ``window``:
+    from ``window - 1`` before the first query to the last one. (A block's
+    tail may repeat its last query: the span still covers it.)"""
+    q = q_positions.shape[1]
+    return q_positions[:, :1] + jnp.arange(
+        1 - window, q, dtype=jnp.int32)[None]
+
+
+def look_up_rows(sel: Selection, block_table, page_size: int,
+                 scope: str = "sparse_attn.gather") -> Selection:
     """``sel`` with its tokens' pool rows looked up through the block
     table (a layer that is handed the selection reuses them)."""
     batch, q, k = sel.positions.shape
-    with jax.named_scope("sparse_attn.gather"):
+    with jax.named_scope(scope):
         pages, offs = page_slots(
             block_table, sel.positions.reshape(batch, q * k), page_size)
         return sel._replace(
@@ -248,26 +287,27 @@ def layer_pool(latent_pages, column: int, width: int):
     return latent_pages[:, :, column:column + width]
 
 
-def gather_rows(latent_pages, sel: Selection):
+def gather_rows(latent_pages, sel: Selection,
+                scope: str = "sparse_attn.gather"):
     """The selected tokens' rows [batch, q, k, width], token by token from
     the pool (``sel.rows``: ``look_up_rows``). Handed a group's pool whole
     (the decode step, once a group: ``Selection.group_rows``) the rows are
     those of every layer of the group, side by side; a layer reads its own
     columns with ``group_slice``."""
     pages, page_size, width = latent_pages.shape
-    with jax.named_scope("sparse_attn.gather"):
+    with jax.named_scope(scope):
         return latent_pages.reshape(pages * page_size, width)[sel.rows]
 
 
 def group_slice(sel: Selection, column: int, width: int, fresh=None,
-                positions=None):
+                positions=None, scope: str = "sparse_attn.attend"):
     """One layer's rows [batch, 1, k, width] out of ``sel.group_rows``,
     columns ``[column, column + width)``. The group's gather ran before a
     later layer of the group wrote its row of the CURRENT token
     (``positions`` [batch, 1]): where that token is among the chosen, such
     a layer's row is ``fresh`` [batch, 1, width], the row this step
     writes, not the pool's."""
-    with jax.named_scope("sparse_attn.attend"):
+    with jax.named_scope(scope):
         rows = sel.group_rows[..., column:column + width]
         if fresh is None:
             return rows
@@ -276,13 +316,14 @@ def group_slice(sel: Selection, column: int, width: int, fresh=None,
             current[..., None], fresh[:, :, None, :].astype(rows.dtype), rows)
 
 
-def latent_attention(q_latent, rows, valid, scale: float):
+def latent_attention(q_latent, rows, valid, scale: float,
+                     scope: str = "sparse_attn.attend"):
     """Attention in the latent over gathered rows: ``q_latent`` [batch, q,
     heads, width] (the absorbed query laid out as a pool row: ``[q_nope .
     W_uk | q_rope | 0]``), ``rows`` [batch, q, k, width]. Returns the
     probability-weighted rows [batch, q, heads, width]: the caller keeps
     the latent span and applies the value up-projection."""
-    with jax.named_scope("sparse_attn.attend"):
+    with jax.named_scope(scope):
         scores = jnp.einsum(
             "bqhw,bqkw->bqhk", q_latent, rows,
             preferred_element_type=jnp.float32) * scale
@@ -304,12 +345,13 @@ def select_mask(sel: Selection, context: int):
     return mask.at[b, r, sel.positions].set(sel.valid)
 
 
-def expanded_attention(q_nope, q_rope, k_nope, k_rope, v, mask, scale: float):
+def expanded_attention(q_nope, q_rope, k_nope, k_rope, v, mask, scale: float,
+                       scope: str = "sparse_attn.attend"):
     """Per-head attention of a fresh sequence over itself, restricted to
     ``mask`` [batch, q, s]: ``q_nope`` [b, q, h, dn], ``q_rope`` [b, q, h,
     dr], ``k_nope`` [b, s, h, dn], ``k_rope`` [b, s, dr] (one rotary key
     for all heads), ``v`` [b, s, h, dv]. Returns [b, q, h, dv]."""
-    with jax.named_scope("sparse_attn.attend"):
+    with jax.named_scope(scope):
         scores = (
             jnp.einsum("bqhd,bshd->bhqs", q_nope, k_nope,
                        preferred_element_type=jnp.float32)

@@ -934,9 +934,11 @@ class DecodeEngine:
                 for leaf in jax.tree.leaves(self._cache)
             })),
             # the model's own named scopes (``program_scopes`` record) and
-            # a latent model's row width (``latent_row_gathers``)
+            # a latent model's row widths (``latent_row_gathers``,
+            # ``window_row_gathers``)
             trace_scopes=getattr(self._decode_model, "trace_scopes", ()),
             latent_row=getattr(self._decode_model.config, "latent_row", 0),
+            window_row=getattr(self._decode_model.config, "window_row", 0),
         )
 
     def _hot_program(self) -> str:
@@ -2938,6 +2940,12 @@ class DecodeEngine:
             # model without a latent pool): one a selection group
             "latent_row_gathers": self._hot_audit().get(
                 "latent_row_gathers"),
+            # the same of a window group's rows (one a group), and the rows
+            # a decode step reads a slot for it (None without windows)
+            "window_row_gathers": self._hot_audit().get(
+                "window_row_gathers"),
+            "window_rows_per_slot": getattr(
+                self._decode_model.config, "window_rows_per_slot", None),
             # what a slot keeps beside its pages, and the pool's readers
             **self._slot_memory_stats(),
             # share of the busy ticks that held a prefill chunk (a token gap

@@ -1,0 +1,9 @@
+"""`dots3_notes_decode`'s decode step compiled for a described v5e:2x2
+(`dots3_lowering.py`): fits the chip, re-lays no pool out, gathers each
+selection group's rows once."""
+
+import dots3_lowering
+
+
+def test_dots3_decode_program_compiles_for_v5e():
+    dots3_lowering.check("decode")
