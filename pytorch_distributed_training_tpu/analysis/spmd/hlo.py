@@ -249,6 +249,27 @@ def count_row_gathers(hlo_text: str, scope: str, row_width: int) -> int:
     return count
 
 
+# `%row_fetch.1 = bf16[48,2048,2560]{...} custom-call(...),
+# custom_call_target="tpu_custom_call", ..., metadata={op_name=
+# ".../sparse_attn.gather/jit(_row_fetch)/row_fetch/pallas_call" ...}`
+_KERNEL_CALL_RE = re.compile(
+    r"^\s*(?:ROOT\s+)?%?(?P<name>[\w-]+)(?:\.\d+)*\s*=\s*.*\scustom-call\(.*"
+    r"metadata=\{[^}]*op_name=\"(?P<op>[^\"]*)\"")
+
+
+def count_kernel_calls(hlo_text: str, scope: str, kernel: str) -> int:
+    """How many Mosaic calls of a compiled program are named ``kernel``
+    (the ``name`` of its ``pallas_call``) and run under
+    ``jax.named_scope(scope)``: whether a kernel took the place of the XLA
+    operations ``count_row_gathers`` counts."""
+    count = 0
+    for line in hlo_text.splitlines():
+        m = _KERNEL_CALL_RE.match(line)
+        count += bool(m) and m.group("name") == kernel and (
+            scope in m.group("op").split("/"))
+    return count
+
+
 def count_relayouts(hlo_text: str, element_counts) -> int:
     """How many ``copy``/``transpose``/``convert`` instructions of a
     compiled program produce a buffer of one of ``element_counts``

@@ -27,6 +27,7 @@ from typing import Optional
 from pytorch_distributed_training_tpu.analysis.spmd.hlo import (
     COLLECTIVE_KINDS,
     CostModel,
+    count_kernel_calls,
     count_relayouts,
     count_row_gathers,
     count_space_moves,
@@ -67,11 +68,13 @@ class CommManifest:
     trace_scopes: tuple = ()
     # values of one cached latent row (0: the program has none): the audit
     # counts the program's gathers of such rows under ``sparse_attn.gather``
-    # (``latent_row_gathers``: one a selection group in a decode step)
+    # (``latent_row_gathers``) and the ``row_fetch`` kernel's calls there
+    # (``latent_row_fetches``): one of the two a selection group in a
+    # decode step
     latent_row: int = 0
     # values of one cached row of a window group (0: the program has none):
-    # the gathers of such rows under ``window_attn`` (``window_row_gathers``:
-    # one a window group in a decode step)
+    # the same under ``window_attn`` (``window_row_gathers``,
+    # ``window_row_fetches``: one a window group in a decode step)
     window_row: int = 0
 
     def __post_init__(self):
@@ -299,9 +302,13 @@ def comm_audit(
     if manifest.latent_row:
         record["latent_row_gathers"] = count_row_gathers(
             text, "sparse_attn.gather", manifest.latent_row)
+        record["latent_row_fetches"] = count_kernel_calls(
+            text, "sparse_attn.gather", "row_fetch")
     if manifest.window_row:
         record["window_row_gathers"] = count_row_gathers(
             text, "window_attn", manifest.window_row)
+        record["window_row_fetches"] = count_kernel_calls(
+            text, "window_attn", "row_fetch")
     registry.emit(record)
     if manifest.trace_scopes:
         registry.emit({

@@ -600,9 +600,9 @@ class LatentAttention(nn.Module):
     def _window_paged(self, pool, row, q_nope, q_rope, positions, selection,
                       kv_b_k, kv_b_v, scale):
         """A window layer through the block table, after the step's own
-        rows are written. The decode step: the group's first layer looks
-        up and gathers the window's rows of the whole group ONCE (one wide
-        row a position) and every layer attends in the latent (ABSORB),
+        rows are written. The decode step: the group's first layer fetches
+        the window's rows of the whole group ONCE (one wide row a position,
+        ``fetch_group_rows``) and every layer attends in the latent (ABSORB),
         the later layers with the row they have just written in place of
         the gathered one. A multi-token step: each block of queries fetches
         the span it reaches, this layer's columns of it, and attends
@@ -617,11 +617,9 @@ class LatentAttention(nn.Module):
         if chunk == 1:
             q_row = self._absorbed_query(q_nope, q_rope, kv_b_k)
             if self.place == 0:
-                selection = la.look_up_rows(
-                    la.window_selection(positions, window), bt.value,
-                    cfg.kv_page_size, **scope)
-                selection = selection._replace(
-                    group_rows=la.gather_rows(latent.value, selection, **scope))
+                selection = la.fetch_group_rows(
+                    latent.value, bt.value,
+                    la.window_selection(positions, window), **scope)
                 rows = la.group_slice(selection, column, width, **scope)
             else:
                 rows = la.group_slice(
@@ -651,7 +649,7 @@ class LatentAttention(nn.Module):
                selection, kv_b_k, kv_b_v, scale):
         """Select, gather and attend in the latent through the block
         table, after the step's own rows are written: ABSORB. The decode
-        step (one token a sequence) gathers the group's rows once, in the
+        step (one token a sequence) fetches the group's rows once, in the
         choosing layer; a prefill chunk at a nonzero context and a bucket
         prefill at context 0 gather this layer's columns."""
         cfg = self.config
@@ -663,18 +661,17 @@ class LatentAttention(nn.Module):
         def values(ctx):
             return self._values(ctx, kv_b_v)
 
-        def select(qi, wi, positions):
-            return la.look_up_rows(la.select_topk(
+        def choose(qi, wi, positions):
+            return la.select_topk(
                 la.index_scores(qi, wi, index.value, bt.value, positions),
-                cfg.index_topk, positions), bt.value, cfg.kv_page_size)
+                cfg.index_topk, positions)
 
         if chunk == 1:
             # every later layer of the group finds its rows in the choosing
-            # layer's one gather, all but the row it has just written
+            # layer's one fetch, all but the row it has just written
             if self.indexer:
-                selection = select(qi, wi, positions)
-                selection = selection._replace(
-                    group_rows=la.gather_rows(latent.value, selection))
+                selection = la.fetch_group_rows(
+                    latent.value, bt.value, choose(qi, wi, positions))
                 rows = la.group_slice(selection, column, cfg.latent_row)
             else:
                 rows = la.group_slice(
@@ -689,7 +686,8 @@ class LatentAttention(nn.Module):
 
         def block(q_row, qi, wi, positions, selection):
             if self.indexer:
-                selection = select(qi, wi, positions)
+                selection = la.look_up_rows(
+                    choose(qi, wi, positions), bt.value, cfg.kv_page_size)
             rows = la.gather_rows(own, selection)
             ctx = la.latent_attention(q_row, rows, selection.valid, scale)
             return values(ctx), selection

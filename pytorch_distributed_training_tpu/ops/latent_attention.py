@@ -15,8 +15,11 @@ apart:
   equal scores the lower position first (``select_topk``: exact, without
   sorting the context);
 - ``sparse_attn.gather``: those positions' latent rows, fetched through the
-  block table token by token: ONE gather a selection group in the decode
-  step (``Selection.group_rows``), one a layer in a multi-token step;
+  block table token by token: ONE fetch a selection group in the decode
+  step (``fetch_group_rows``: on one chip the ``row_fetch`` kernel, which
+  looks the pages up itself and reads only what valid entries need; XLA's
+  look-up and row gather elsewhere), one gather a layer in a multi-token
+  step;
 - ``sparse_attn.attend``: attention over the gathered rows only, in the
   latent (the key up-projection absorbed into the query, the value
   up-projection applied to the output).
@@ -66,6 +69,9 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
+from pytorch_distributed_training_tpu.ops import dispatch
+from pytorch_distributed_training_tpu.ops import row_fetch as rf
+
 LANES = 128
 _NEG_INF = float("-inf")
 
@@ -77,10 +83,11 @@ class Selection(NamedTuple):
     positions exist yet) and, on the paged path, ``rows`` [batch, q, k]
     int32: where those tokens live in a pool viewed as [pages * page_size,
     width], looked up through the block table ONCE by the choosing layer
-    and handed on with the positions. In the decode step it also carries
+    and handed on with the positions (not built where the decode step's
+    kernel looks the pages up itself). In the decode step it also carries
     ``group_rows`` [batch, 1, k, G * width]: the chosen tokens' rows of
     every layer of the group, side by side as the group's pool holds them,
-    gathered once by the choosing layer (``gather_rows``)."""
+    fetched once by the choosing layer (``fetch_group_rows``)."""
 
     positions: jax.Array
     valid: jax.Array
@@ -297,6 +304,25 @@ def gather_rows(latent_pages, sel: Selection,
     pages, page_size, width = latent_pages.shape
     with jax.named_scope(scope):
         return latent_pages.reshape(pages * page_size, width)[sel.rows]
+
+
+def fetch_group_rows(latent_pages, block_table, sel: Selection,
+                     scope: str = "sparse_attn.gather") -> Selection:
+    """``sel`` with its ``group_rows``: the decode step's one fetch of a
+    group's rows. Where the gate says one chip (or the interpreter) runs
+    kernels and the pool is 16-bit in whole tiles, the ``row_fetch``
+    kernel looks the pages up and copies what valid entries need (invalid
+    entries read zeros, which attention weighs by 0 as it did the pool's
+    rows); elsewhere XLA's look-up and row gather. Counted as
+    ``row_fetch:direct`` / ``row_fetch:xla``."""
+    kernel = dispatch.mode() == "direct" and rf.fits(latent_pages)
+    dispatch.note_path("row_fetch", "direct" if kernel else "xla")
+    if kernel:
+        with jax.named_scope(scope):
+            return sel._replace(group_rows=rf.row_fetch(
+                latent_pages, block_table, sel.positions, sel.valid))
+    sel = look_up_rows(sel, block_table, latent_pages.shape[1], scope)
+    return sel._replace(group_rows=gather_rows(latent_pages, sel, scope))
 
 
 def group_slice(sel: Selection, column: int, width: int, fresh=None,

@@ -935,7 +935,7 @@ class DecodeEngine:
             })),
             # the model's own named scopes (``program_scopes`` record) and
             # a latent model's row widths (``latent_row_gathers``,
-            # ``window_row_gathers``)
+            # ``window_row_gathers`` and the kernel's ``*_row_fetches``)
             trace_scopes=getattr(self._decode_model, "trace_scopes", ()),
             latent_row=getattr(self._decode_model.config, "latent_row", 0),
             window_row=getattr(self._decode_model.config, "window_row", 0),
@@ -2936,14 +2936,19 @@ class DecodeEngine:
             # and back, in one layout (analysis/spmd/hlo.count_space_moves)
             "kv_pool_space_moves": self._hot_audit().get(
                 "kv_pool_space_moves"),
-            # gathers of cached latent rows in the hot program (None for a
-            # model without a latent pool): one a selection group
+            # XLA gathers of cached latent rows in the hot program and the
+            # row_fetch kernel's calls (None for a model without a latent
+            # pool): one of the two a selection group
             "latent_row_gathers": self._hot_audit().get(
                 "latent_row_gathers"),
+            "latent_row_fetches": self._hot_audit().get(
+                "latent_row_fetches"),
             # the same of a window group's rows (one a group), and the rows
             # a decode step reads a slot for it (None without windows)
             "window_row_gathers": self._hot_audit().get(
                 "window_row_gathers"),
+            "window_row_fetches": self._hot_audit().get(
+                "window_row_fetches"),
             "window_rows_per_slot": getattr(
                 self._decode_model.config, "window_rows_per_slot", None),
             # what a slot keeps beside its pages, and the pool's readers

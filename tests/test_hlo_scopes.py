@@ -8,7 +8,9 @@ import jax
 import jax.numpy as jnp
 
 from pytorch_distributed_training_tpu.analysis.spmd.hlo import (
+    count_kernel_calls,
     count_relayouts,
+    count_row_gathers,
     count_space_moves,
     scope_instructions,
 )
@@ -34,6 +36,16 @@ ENTRY %main.107 (pool.1: bf16[11777,16,128]) -> bf16[8,128] {
 }
 """  # noqa: E501 - lines as the compiler writes them
 POOL = 11777 * 16 * 128
+# a decode program's row fetches: two Mosaic calls of the kernel, one of
+# another kernel whose name starts the same, and an XLA gather of rows left
+KERNEL_TEXT = """
+ENTRY %main.9 (pool.1: bf16[11777,16,2560]) -> bf16[8,128] {
+  %row_fetch = bf16[48,2048,2560]{2,1,0:T(8,128)(2,1)} custom-call(%c, %w, %t, %pool), custom_call_target="tpu_custom_call", metadata={op_name="jit(f)/M/layer_0/sparse_attn.gather/jit(_row_fetch)/row_fetch/pallas_call"}, backend_config={"x":1}
+  %row_fetch.3 = bf16[64,513,3456]{2,1,0:T(8,128)(2,1)} custom-call(%c, %w, %t, %pool), custom_call_target="tpu_custom_call", metadata={op_name="jit(f)/M/layer_2/window_attn/jit(_row_fetch)/row_fetch/pallas_call"}
+  %row_fetcher.1 = bf16[8,128]{1,0} custom-call(%x), custom_call_target="tpu_custom_call", metadata={op_name="jit(f)/M/layer_0/sparse_attn.gather/row_fetcher"}
+  %gather.4 = bf16[48,1,2048,2560]{3,2,1,0} gather(%pool, %rows), offset_dims={3}, slice_sizes={1,2560}, metadata={op_name="jit(f)/M/layer_4/sparse_attn.gather/gather"}
+}
+"""  # noqa: E501 - lines as the compiler writes them
 
 
 def test_scope_instructions_lists_entry_instructions_by_scope():
@@ -51,6 +63,18 @@ def test_copies_between_memory_spaces_in_one_layout_are_a_part_of_the_count():
     assert count_relayouts(TEXT, {POOL}) == 3
     assert count_space_moves(TEXT, {POOL}) == 1        # copy-start.10
     assert count_space_moves(TEXT, {5}) == 0 and count_space_moves(TEXT, ()) == 0
+
+
+def test_kernel_calls_are_counted_by_name_and_scope_beside_the_gathers():
+    """`latent_row_fetches` / `window_row_fetches`: the Mosaic calls named
+    `row_fetch` under a scope (not a kernel whose name merely starts so),
+    beside `count_row_gathers`' XLA gathers of rows left."""
+    assert count_kernel_calls(KERNEL_TEXT, "sparse_attn.gather", "row_fetch") == 1
+    assert count_kernel_calls(KERNEL_TEXT, "window_attn", "row_fetch") == 1
+    assert count_kernel_calls(KERNEL_TEXT, "sparse_attn.topk", "row_fetch") == 0
+    assert count_kernel_calls(TEXT, "sparse_attn.gather", "row_fetch") == 0
+    assert count_row_gathers(KERNEL_TEXT, "sparse_attn.gather", 640) == 1
+    assert count_row_gathers(KERNEL_TEXT, "window_attn", 1152) == 0
 
 
 def test_the_audit_writes_the_scopes_of_a_real_program():

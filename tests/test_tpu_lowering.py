@@ -31,7 +31,11 @@ import jax
 import jax.numpy as jnp
 import pytest
 
+from pytorch_distributed_training_tpu.analysis.spmd.hlo import (
+    count_kernel_calls,
+)
 from pytorch_distributed_training_tpu.ops import dispatch
+from pytorch_distributed_training_tpu.ops import latent_attention as la
 from pytorch_distributed_training_tpu.ops import paged_attention as pa
 from pytorch_distributed_training_tpu.ops.dropout import raw_dropout
 from pytorch_distributed_training_tpu.ops.flash_attention import (
@@ -256,3 +260,35 @@ def test_paged_attn_rows_lowers_at_the_reasoning_cells_geometry(one_chip, memory
 
         lower_for_tpu(f, q, ring, ring, S((slots,), jnp.int32))
     assert took_kernel("paged_attn_rows")
+
+
+@pytest.mark.parametrize("slots,k,width,pages,scope", [
+    (48, 2048, 2560, 11777, "sparse_attn.gather"),
+    (48, 2048, 1280, 11777, "sparse_attn.gather"),
+    (64, 2048, 640, 14337, "sparse_attn.gather"),
+    (64, 513, 3456, 14337, "window_attn"),
+], ids=["glm_group0", "glm_group1", "dots3_full", "dots3_window"])
+def test_row_fetch_lowers_at_the_latent_cells_geometry(one_chip, slots, k, width,
+                                                       pages, scope):
+    """The decode step's group row fetch as the latent cells run it: a
+    top-k's 2,048 or a window's 513 entries a slot, over 1,184-page block
+    tables, from pools of 16-token pages of the groups' widths. The gate
+    answers as one chip does and takes the kernel, which compiles for the
+    chip as ONE Mosaic call under the group's scope (the audit's
+    `*_row_fetches`) and takes the pool as it lies: no copy of it."""
+    def f(pool, table, positions, valid):
+        sel = la.fetch_group_rows(
+            pool, table, la.Selection(positions, valid), scope=scope)
+        return sel.group_rows
+
+    specs = (S((pages, 16, width), BF16), S((slots, 1184), jnp.int32),
+             S((slots, 1, k), jnp.int32), S((slots, 1, k), jnp.bool_))
+    lower_for_tpu(f, *specs)
+    assert took_kernel("row_fetch")
+    on_tpu, _ = _compile_only_tpu()
+    if on_tpu is not None:
+        text = jax.jit(f).lower(*(
+            S(x.shape, x.dtype, sharding=on_tpu) for x in specs)).compile().as_text()
+        assert count_kernel_calls(text, scope, "row_fetch") == 1
+        assert not [line for line in text.splitlines()
+                    if " copy(" in line and f"[{pages},16,{width}]" in line]
