@@ -1,0 +1,11 @@
+"""Device time per run of the compiled train step in the optimizer update
+and the gradient norm: the instructions the step lists under its
+`train.optimizer` scope, averaged over the runs in the traced window
+(`harness/train_phases.py`). Nothing to read where the program keeps no
+scopes record or the trace holds no run of the step."""
+
+from harness import train_phases
+
+
+def read(obs):
+    return train_phases.read(obs, "train.optimizer")

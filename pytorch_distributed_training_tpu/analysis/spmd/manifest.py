@@ -21,6 +21,7 @@ will consciously relax, kind by kind, instead of silently breaking.
 
 from __future__ import annotations
 
+import collections
 import dataclasses
 from typing import Optional
 
@@ -35,6 +36,12 @@ from pytorch_distributed_training_tpu.analysis.spmd.hlo import (
     scope_instructions,
     summarize_collectives,
 )
+
+#: every ``program_scopes`` record the audits wrote, oldest first: what a
+#: reader in the same process has where no sink is attached (the
+#: benchmark's training harness attaches none). Bounded: a process warms a
+#: handful of programs, a test suite many.
+PROGRAM_SCOPES: collections.deque = collections.deque(maxlen=16)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -146,10 +153,17 @@ def train_manifest(mesh, *, max_bytes: Optional[int] = None,
     policy actually shards params over it) additionally REQUIRES an
     all-gather: sharded params must be gathered somewhere, so a step
     with none means everything silently ended up replicated — the
-    de-sharding regression this manifest exists to catch."""
+    de-sharding regression this manifest exists to catch.
+
+    Every train step's manifest lists the step's own named scopes
+    (``train/step.py::TRAIN_SCOPES``) as its ``trace_scopes``: the audit
+    of the compiled step then also writes its ``program_scopes`` record."""
+    from pytorch_distributed_training_tpu.train.step import TRAIN_SCOPES
+
     shape = dict(mesh.shape)
     if max(shape.values(), default=1) <= 1:
-        return CommManifest(name, allowed=(), max_bytes=max_bytes)
+        return CommManifest(name, allowed=(), max_bytes=max_bytes,
+                            trace_scopes=TRAIN_SCOPES)
     allowed = ["all-reduce"]
     required = []
     if shape.get("fsdp", 1) > 1 or shape.get("model", 1) > 1:
@@ -162,7 +176,7 @@ def train_manifest(mesh, *, max_bytes: Optional[int] = None,
         allowed += ["collective-permute"]
     return CommManifest(
         name, allowed=tuple(allowed), required=tuple(required),
-        max_bytes=max_bytes,
+        max_bytes=max_bytes, trace_scopes=TRAIN_SCOPES,
     )
 
 
@@ -311,10 +325,12 @@ def comm_audit(
             text, "window_attn", "row_fetch")
     registry.emit(record)
     if manifest.trace_scopes:
-        registry.emit({
+        scopes = {
             "record": "program_scopes", "name": name,
             "scopes": scope_instructions(text, manifest.trace_scopes),
-        })
+        }
+        PROGRAM_SCOPES.append(scopes)
+        registry.emit(scopes)
     if deviations:
         registry.inc("guards/comm_deviations", len(deviations))
         if mode == "strict":

@@ -124,6 +124,8 @@ class Trainer:
         self.metrics_sink = None
         self._first_step_done = False
         self._log_pending = None  # (step, device loss) awaiting a non-blocking fetch
+        # (step record, device loss): emitted once the next step is dispatched
+        self._step_pending = None
         if train_config.metrics_dir:
             self.metrics_sink = JsonlSink(train_config.metrics_dir)
             self.registry.attach_sink(self.metrics_sink)
@@ -660,11 +662,13 @@ class Trainer:
         raise Preempted(signum, step=step_no)
 
     def _run_epochs(self, cfg, n_chips, start_epoch, skip_in_first_epoch):
-        # Per-step telemetry (metrics_dir set) synchronizes on each step's
-        # loss so data-wait / dispatch / device-block attribution is honest;
-        # without it the loop keeps today's fully-async dispatch and only
-        # wall-clock step times (backpressure-accurate in steady state) are
-        # collected for the epoch-boundary straggler gather.
+        # Per-step telemetry (metrics_dir set) emits each step's record one
+        # step late: step k's record goes out once step k+1 is dispatched,
+        # its loss read then (computed, or computing with step k+1 queued
+        # behind it), so the loop never drains the device to write it. The
+        # device's own time per step is a profile's (the train.* scopes of
+        # train/step.py); without metrics only wall-clock step times are
+        # collected, for the epoch-boundary straggler gather.
         per_step = bool(cfg.metrics_dir)
         reg = self.registry
         with maybe_profile(cfg.profile_dir):
@@ -689,18 +693,19 @@ class Trainer:
                         f"chain configuration?"
                     )
                 buf = []
-                t_prev = time.perf_counter()
+                t_prev = t_last_dispatch = time.perf_counter()
                 for i, batch in enumerate(self.train_loader.epoch(epoch)):
                     if (
                         self._shutdown is not None
                         and self._shutdown.requested is not None
                     ):
+                        self._flush_step_record()
                         self._preempt_exit(self._shutdown.requested, step_no)
                     t_batch = time.perf_counter()
                     data_wait = t_batch - t_prev
                     if i < skip:
                         step_no += 1
-                        t_prev = time.perf_counter()
+                        t_prev = t_last_dispatch = time.perf_counter()
                         continue
                     if chain > 1:
                         # ONE dispatch per chain_steps updates: stack the
@@ -716,10 +721,11 @@ class Trainer:
                         )
                         buf.clear()
                     compile_inclusive = not self._first_step_done
-                    # watchdog arms over dispatch + (per_step) device block:
-                    # a hung collective inside the step surfaces here. The
-                    # compile-inclusive first step is exempt — tracing+XLA
-                    # time is unbounded-ish and is not a hang
+                    # watchdog arms over dispatch + (per_step) the previous
+                    # step's loss read: a hung collective inside a step
+                    # surfaces here. The compile-inclusive first step is
+                    # exempt — tracing+XLA time is unbounded-ish and is not
+                    # a hang
                     guard = (
                         self.watchdog.guard("train_step", step=step_no + chain)
                         if self.watchdog is not None and not compile_inclusive
@@ -729,10 +735,7 @@ class Trainer:
                         self.state, metrics = self.train_step(self.state, batch)
                         self._first_step_done = True
                         t_dispatched = time.perf_counter()
-                        if per_step:
-                            # join this step so device_block_s is real device
-                            # time, not queue depth
-                            jax.block_until_ready(metrics["loss"])
+                        self._flush_step_record()
                     t_done = time.perf_counter()
                     samples += cfg.global_batch_size * chain
                     losses.append(metrics["loss"])
@@ -740,21 +743,19 @@ class Trainer:
                     step_times.append(t_done - t_prev)
                     data_waits.append(data_wait)
                     reg.observe("train/data_wait_s", data_wait)
-                    loss_host = None  # fetched at most once per step
                     if per_step:
+                        step_s = t_dispatched - t_last_dispatch
                         reg.observe("train/dispatch_s", t_dispatched - t_batch)
-                        reg.observe("train/device_block_s", t_done - t_dispatched)
-                        reg.observe("train/step_s", t_done - t_prev)
-                        loss_host = float(jax.device_get(metrics["loss"]))
+                        reg.observe("train/step_s", step_s)
                         step_rec = {
                             "record": "step",
                             "epoch": epoch,
                             "step": step_no,
                             "data_wait_s": data_wait,
                             "dispatch_s": t_dispatched - t_batch,
-                            "device_block_s": t_done - t_dispatched,
-                            "step_s": t_done - t_prev,
-                            "loss": loss_host,
+                            # dispatch to dispatch: the device's step once
+                            # the loop runs a step ahead of it
+                            "step_s": step_s,
                             "compile_inclusive": compile_inclusive,
                         }
                         occ = getattr(
@@ -762,25 +763,18 @@ class Trainer:
                         )
                         if occ is not None:  # prefetch pipeline active
                             step_rec["prefetch_occupancy"] = occ
-                        reg.emit(step_rec)
+                        self._step_pending = (step_rec, metrics["loss"])
+                    t_last_dispatch = t_dispatched
                     if cfg.log_every and (
                         step_no // cfg.log_every
                         > (step_no - chain) // cfg.log_every
                     ):
-                        if loss_host is not None:
-                            # reuse the loss already synced for the step
-                            # record — no second host round-trip
-                            log0(
-                                f"step {step_no}: loss={loss_host:.4f} "
-                                f"lr={float(self.schedule(step_no)):.2e}"
-                            )
-                        else:
-                            # non-blocking: fetch the PREVIOUS logged step's
-                            # loss (long since computed) and queue this one —
-                            # a device_get of the current step's loss here
-                            # would stall the async dispatch stream
-                            self._flush_pending_log()
-                            self._log_pending = (step_no, metrics["loss"])
+                        # non-blocking: fetch the PREVIOUS logged step's
+                        # loss (long since computed) and queue this one —
+                        # a device_get of the current step's loss here
+                        # would stall the async dispatch stream
+                        self._flush_pending_log()
+                        self._log_pending = (step_no, metrics["loss"])
                     if (
                         self.checkpointer
                         and cfg.checkpoint_every_steps
@@ -797,6 +791,7 @@ class Trainer:
                         import os as _os
 
                         jax.block_until_ready(self.state.params)
+                        self._flush_step_record()
                         if self.checkpointer:
                             # join async saves: the injected fault models a
                             # crash AFTER the last periodic checkpoint
@@ -814,17 +809,24 @@ class Trainer:
                     # InjectedCrash (supervisor-retryable), self-SIGTERM
                     # (preemption path) or hang (watchdog path) right after
                     # completing this update
-                    get_plan().fire_step_fault(step_no)
+                    plan = get_plan()
+                    if plan.specs:
+                        # an armed plan may end the process here: this
+                        # step's record goes out first
+                        self._flush_step_record()
+                    plan.fire_step_fault(step_no)
                     t_prev = time.perf_counter()
                 with (
                     self.watchdog.guard("epoch_block", step=step_no)
                     if self.watchdog is not None
                     else contextlib.nullcontext()
                 ):
-                    # with per-step sync off this join is where a wedged
-                    # device/collective actually surfaces
+                    # the loop never drains the device per step: this join
+                    # is where a wedged device/collective actually surfaces
                     jax.block_until_ready(self.state.params)
-                # the last queued log line (everything is ready post-join)
+                # the last step record and queued log line (everything is
+                # ready post-join)
+                self._flush_step_record()
                 self._flush_pending_log()
                 train_time = time.perf_counter() - epoch_t0
                 # every host contributes its step-time stats; process 0's
@@ -856,6 +858,17 @@ class Trainer:
                     "straggler": straggler,
                     "telemetry": reg.snapshot(reset=True),
                 })
+
+    def _flush_step_record(self) -> None:
+        """Emit the step record that waits for its loss (``--metrics-dir``
+        runs): called once the next step is dispatched, so the read waits
+        at most for a step the device is already running."""
+        if self._step_pending is None:
+            return
+        rec, loss = self._step_pending
+        self._step_pending = None
+        rec["loss"] = float(jax.device_get(loss))
+        self.registry.emit(rec)
 
     def _flush_pending_log(self) -> None:
         """Emit the queued --log-every line (its loss is ready by now)."""

@@ -268,6 +268,19 @@ def _fsdp_gather(state_shardings):
     return lambda params: jax.lax.with_sharding_constraint(params, gathered)
 
 
+#: the train step's own ``jax.named_scope``s, each opened OUTSIDE any
+#: transformation so that its name survives differentiation as a plain
+#: segment of every instruction's ``op_name`` (inside ``value_and_grad`` it
+#: would come out as ``jvp(train.grad)``): one micro-batch's forward and
+#: backward, the fp32 carry add, and the optimizer update with the gradient
+#: norm. ``train_manifest`` lists them as its ``trace_scopes``, so the
+#: warm start's audit writes which compiled instructions fall under each.
+GRAD_SCOPE = "train.grad"
+ACCUMULATE_SCOPE = "train.accumulate"
+OPTIMIZER_SCOPE = "train.optimizer"
+TRAIN_SCOPES = (GRAD_SCOPE, ACCUMULATE_SCOPE, OPTIMIZER_SCOPE)
+
+
 def make_train_step(
     *,
     grad_accum_steps: int,
@@ -331,9 +344,10 @@ def make_train_step(
                     )
                     return loss * inv_accum, new_quant
 
-                (loss, new_quant), (grads, sink_grads) = jax.value_and_grad(
-                    loss_fn, argnums=(0, 1), has_aux=True
-                )(state.params, sinks0)
+                with jax.named_scope(GRAD_SCOPE):
+                    (loss, new_quant), (grads, sink_grads) = jax.value_and_grad(
+                        loss_fn, argnums=(0, 1), has_aux=True
+                    )(state.params, sinks0)
                 new_quant = _merge_dy_amaxes(new_quant, sink_grads)
             else:
 
@@ -343,12 +357,14 @@ def make_train_step(
                     )
                     return loss * inv_accum, new_quant
 
-                (loss, new_quant), grads = jax.value_and_grad(
-                    loss_fn, has_aux=True
-                )(state.params)
-            grads = jax.tree.map(
-                lambda a, g: a + g.astype(acc_dtype), grads_acc, grads
-            )
+                with jax.named_scope(GRAD_SCOPE):
+                    (loss, new_quant), grads = jax.value_and_grad(
+                        loss_fn, has_aux=True
+                    )(state.params)
+            with jax.named_scope(ACCUMULATE_SCOPE):
+                grads = jax.tree.map(
+                    lambda a, g: a + g.astype(acc_dtype), grads_acc, grads
+                )
             return (
                 (grads, (loss_acc[0] + loss, loss_acc[1] + 1.0), new_quant),
                 None,
@@ -385,18 +401,19 @@ def make_train_step(
         # only materialize a full fp32 copy of every gradient (~3 ms/step
         # on bert-large with a bf16 carry). Optimizer math is fp32 either
         # way (train/fused_adamw.py).
-        new_state = state.apply_gradients(grads).replace(quant=final_quant)
-        metrics = {
-            "loss": loss_sum,  # sum of 1/accum-scaled losses == mean loss
-        }
-        if log_grad_norm:
-            # one extra read of every gradient leaf (~0.7 GB on bert-large)
-            metrics["grad_norm"] = jnp.sqrt(
-                sum(
-                    jnp.sum(jnp.square(g.astype(jnp.float32)))
-                    for g in jax.tree.leaves(grads)
+        with jax.named_scope(OPTIMIZER_SCOPE):
+            new_state = state.apply_gradients(grads).replace(quant=final_quant)
+            metrics = {
+                "loss": loss_sum,  # sum of 1/accum-scaled losses == mean loss
+            }
+            if log_grad_norm:
+                # one extra read of every gradient leaf (~0.7 GB on bert-large)
+                metrics["grad_norm"] = jnp.sqrt(
+                    sum(
+                        jnp.sum(jnp.square(g.astype(jnp.float32)))
+                        for g in jax.tree.leaves(grads)
+                    )
                 )
-            )
         return new_state, metrics
 
     if chain_steps > 1:
